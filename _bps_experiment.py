@@ -1,8 +1,8 @@
 """Device experiment: settle the 50 GiB/s BLAKE2b question with DATA.
 
-VERDICT round-4 #2: the ceiling analysis ("Mosaic scheduling of long
-dependent chains binds at ~45% issue efficiency") rests on elimination
-— 16 variants within noise — not observation.  This script runs the two
+The ceiling analysis ("Mosaic scheduling of long dependent chains binds
+at ~45% issue efficiency", ROADMAP S6) rests on elimination — 16
+variants within noise — not observation.  This script runs the two
 prescribed observations on an uncontended chip:
 
 1. **Chain-length roofline sweep** (``--observe``): constant 2 GiB per
@@ -18,12 +18,11 @@ prescribed observations on an uncontended chip:
 2. **blocks_per_step amortization** at the best sweep point (1/2/4):
    whether per-block prologue/epilogue overhead is a material term.
 
-Every rep is pipeline-fenced (depth 2) per the round-4 methodology;
-the chip flock guarantees no concurrent diagnostic contaminates it
-(round 4's one driver-shaped capture was polluted exactly that way).
+Every rep is pipeline-fenced (depth 2); the chip flock guarantees no
+concurrent diagnostic contaminates it.  One process per chip: run it
+alone (``python _bps_experiment.py`` through the chip tool).
 
-Output: one JSON line per measurement plus a final summary JSON line
-(the watch script commits stdout into artifacts/r05_watch/).
+Output: one JSON line per measurement plus a final summary JSON line.
 """
 import json
 import statistics
@@ -123,7 +122,7 @@ def observe():
 
 
 if __name__ == "__main__":
-    enable_compile_cache("bench", env_var="BENCH_COMPILE_CACHE")
+    enable_compile_cache()
     # never run concurrently with a bench capture: block until the chip
     # is free (diagnostics have no deadline; captures do)
     with chip_lock() as lease:

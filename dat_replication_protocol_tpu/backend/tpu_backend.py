@@ -14,8 +14,11 @@ reference's drain-before-finalize discipline, reference: decode.js:124-142).
 
 The hash engine is pluggable: :class:`DigestPipeline` talks to a callable
 ``hash_batch(payloads) -> list[bytes]``; by default it uses the batched
-device BLAKE2b from :mod:`..ops.blake2b` when JAX is importable and falls
-back to ``hashlib.blake2b`` otherwise, so the API works on any host.
+device BLAKE2b from :mod:`..ops.blake2b` where an accelerator backs jax
+and the native host engine where the routing layer observes a CPU
+platform (:func:`..utils.routing.host_reason`).  A device engine that
+fails to initialise, import, compile or run raises — it never yields
+the host engine in its place.
 """
 
 from __future__ import annotations
@@ -43,6 +46,9 @@ _M_ENC_DIGESTS = _counter("encoder.digests")
 _M_SUBMIT_ITEMS = _counter("device.submit.items")
 _M_SUBMIT_BYTES = _counter("device.submit.bytes")
 _M_DISPATCHES = _counter("device.dispatch.batches")
+# bytes of over-threshold blob streams: hashed on the host by design
+# (see _make_stream), so these never reach the device
+_M_HOST_STREAM_BYTES = _counter("device.host.stream.bytes")
 
 OnDigest = Callable[[str, int, bytes], None]  # (kind, seq, digest)
 
@@ -77,27 +83,47 @@ def _host_hash_batch(payloads: list[bytes]) -> list[bytes]:
     ]
 
 
-def _device_hash_begin_factory():
+def resolve_digest_engine():
     """Pick the batch engine by what actually backs jax, not by whether
     jax imports: on a CPU-only host the XLA scan loses to hashlib's C
     loop ~10x (measured 0.031 vs 0.33 GiB/s, round-3 verdict weak #4) —
     "batch or stay home" (DESIGN.md §2 rule 0) applies to the host too.
     ``DAT_DEVICE_HASH=1`` forces the device path (tests / experiments),
-    ``=0`` forces the host engine."""
-    import os  # noqa: PLC0415
+    ``=0`` forces the host engine.
 
-    from ..utils.routing import prefer_host  # noqa: PLC0415
+    Returns ``(record, hash_begin)``.  ``record`` says what was resolved
+    and why — ``engine``, ``reason``, and the device as jax reports it
+    (``platform``, ``device_kind``, ``device_count``); the sidecar prints
+    it at start-up and carries it in every ``--stats-fd`` snapshot.
+    ``hash_begin`` is None ONLY for host routing the code observed
+    (:func:`..utils.routing.host_reason`).  With a device in play, a
+    backend that cannot initialise or a device engine that cannot
+    import raises: there is no host answer to hide a lost chip behind.
+    An explicit host override or a jax-less host names no device and
+    initialises none."""
+    from ..utils import routing  # noqa: PLC0415
 
-    if prefer_host("DAT_DEVICE_HASH"):
-        return None
-    try:
-        from ..ops.blake2b import blake2b_batch_begin  # noqa: PLC0415
+    reason = routing.host_reason("DAT_DEVICE_HASH")
+    if reason is not None:
+        rec = {"engine": "host", "reason": reason, "platform": None,
+               "device_kind": None, "device_count": 0}
+        if reason in (routing.CPU_CONFIGURED, routing.CPU_BACKEND):
+            rec.update(routing.describe_device())
+        return rec, None
+    from ..ops.blake2b import blake2b_batch_begin  # noqa: PLC0415
 
-        if _OBS.on:
-            _note_engine("digest.hash", "device-batch")
-        return blake2b_batch_begin
-    except Exception:
-        return None
+    rec = {"engine": "device-batch", "reason": None,
+           **routing.describe_device()}
+    return rec, blake2b_batch_begin
+
+
+def _device_hash_begin_factory():
+    """:func:`resolve_digest_engine`'s engine alone (None = the host
+    engine, by observed routing)."""
+    hash_begin = resolve_digest_engine()[1]
+    if hash_begin is not None and _OBS.on:
+        _note_engine("digest.hash", "device-batch")
+    return hash_begin
 
 
 # blobs at least this long hash incrementally instead of being joined in
@@ -216,6 +242,8 @@ class DigestPipeline:
             # a blob-heavy session carries its dominant byte volume
             # through streams — the bytes counter must say so
             _M_SUBMIT_BYTES.inc(int(getattr(stream, "length", 0)))
+            if isinstance(stream, _HostStream):
+                _M_HOST_STREAM_BYTES.inc(stream.length)
         self._entries.append(("stream", stream, on_digest, tag))
         if len(self._entries) >= self._max_batch:
             self.dispatch()

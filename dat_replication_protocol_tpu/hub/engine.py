@@ -269,32 +269,38 @@ class HubSession:
 def _mesh_hash_begin_factory(n_devices: Optional[int] = None):
     """The cross-session mesh engine: shard the coalesced hash batch
     over the device mesh with batch-dim ``NamedSharding`` (SNIPPETS.md
-    idiom; 8 devices in MULTICHIP_r05.json).  Returns None — fall back
-    to the pipeline's default engine — on host-routed or single-device
-    backends."""
+    idiom).  ``n_devices=None`` takes the largest power of two of the
+    visible devices (the mesh layer's constraint).
+
+    Returns ``(devices, hash_begin)``, or None only where the routing
+    layer observes a host platform
+    (:func:`..utils.routing.host_reason`) — the pipeline's host engine
+    then serves, as for any hub.  With a device in play, a mesh that
+    was asked for and cannot be built raises: fewer than two devices,
+    or a pinned count the mesh layer refuses."""
     from ..utils.routing import prefer_host
 
     if prefer_host("DAT_DEVICE_HASH"):
         return None
-    try:
-        import jax  # noqa: PLC0415
+    import jax  # noqa: PLC0415
 
-        from ..parallel import mesh as pmesh  # noqa: PLC0415
+    from ..parallel import mesh as pmesh  # noqa: PLC0415
 
-        n_avail = len(jax.devices())
-        n = n_devices if n_devices is not None else n_avail
+    n = n_devices
+    if n is None:
+        n = len(jax.devices())
         while n & (n - 1):
-            n -= 1  # largest power of two the mesh layer accepts
-        if n < 2:
-            return None
-        m = pmesh.make_mesh(n)
-        if _OBS.on:
-            from ..obs.device import note_engine as _note_engine
+            n -= 1
+    if n < 2:
+        raise ValueError(
+            f"hub mesh needs at least two devices; "
+            f"{len(jax.devices())} visible, {n_devices or 'auto'} asked")
+    m = pmesh.make_mesh(n)
+    if _OBS.on:
+        from ..obs.device import note_engine as _note_engine
 
-            _note_engine("digest.hash", "mesh-sharded", devices=n)
-        return lambda payloads: pmesh.sharded_hash_begin(m, payloads)
-    except Exception:
-        return None
+        _note_engine("digest.hash", "mesh-sharded", devices=n)
+    return n, lambda payloads: pmesh.sharded_hash_begin(m, payloads)
 
 
 class ReplicationHub:
@@ -302,9 +308,10 @@ class ReplicationHub:
     and go via :meth:`register` / :meth:`HubSession.close`.
 
     ``mesh="auto"`` shards cross-session batches over every local device
-    (falling back to the pipeline's default engine on host/single-chip
-    backends); an int pins the device count; ``None`` (default) keeps
-    the single-device engine.
+    (the host engine serves where the routing layer observes a CPU
+    platform; a device backend with fewer than two chips raises); an
+    int pins the device count; ``None`` (default) keeps the
+    single-device engine.
     """
 
     def __init__(
@@ -322,13 +329,18 @@ class ReplicationHub:
         linger_s: float = 0.002,
         latency_shed_s: Optional[float] = None,
     ):
+        # devices the cross-session batch is sharded over (0 = the
+        # single-device or host engine)
+        self.mesh_devices = 0
         if pipeline is None:
             from ..backend.tpu_backend import DigestPipeline
 
             hash_begin = None
             if mesh is not None and hash_batch is None:
-                hash_begin = _mesh_hash_begin_factory(
+                meshed = _mesh_hash_begin_factory(
                     None if mesh == "auto" else int(mesh))
+                if meshed is not None:
+                    self.mesh_devices, hash_begin = meshed
             # the hub owns batching: the inner pipeline's item cap is
             # effectively ours (we dispatch explicitly per composed
             # batch), its inflight bound stays the readback pipeline

@@ -56,12 +56,15 @@ from . import tracing as _tracing
 
 __all__ = [
     "SENTINEL",
+    "BUCKETS",
+    "BucketTable",
     "JitSentinel",
     "RecompileBudget",
     "BackendInitWatchdog",
     "jit_site",
     "note_engine",
     "sample_device_gauges",
+    "watch_compile_events",
     "DEFAULT_RECOMPILE_BUDGET",
 ]
 
@@ -70,6 +73,14 @@ _M_JIT_CALLS = _counter("device.jit.calls")
 _M_JIT_TRACES = _counter("device.jit.traces")
 _G_LIVE_BUFFERS = _gauge("device.mem.live_buffers")
 _G_BYTES_IN_USE = _gauge("device.mem.bytes_in_use")
+_G_PEAK_BYTES = _gauge("device.mem.peak_bytes_in_use")
+# jax's own compile accounting, mirrored by watch_compile_events():
+# persistent-cache traffic and seconds spent tracing/lowering/compiling
+_M_CACHE_REQUESTS = _counter("device.compile.cache.requests")
+_M_CACHE_HITS = _counter("device.compile.cache.hits")
+_M_CACHE_MISSES = _counter("device.compile.cache.misses")
+_G_TRACE_SECONDS = _gauge("device.compile.trace_seconds")
+_G_BACKEND_SECONDS = _gauge("device.compile.backend_seconds")
 
 # traces per site before the sentinel flags it: generous enough for the
 # legitimate power-of-two bucket ladder (a handful of (B, nblocks)
@@ -217,22 +228,18 @@ def _outside_jax_trace() -> bool:
     and never per execution, so counting them would report
     calls == traces — the exact pathology signature the sentinel
     exists to flag — for perfectly healthy inner sites.  Bound lazily:
-    jax is never imported here, only observed if already loaded."""
+    jax is never imported here, only observed if already loaded.  The
+    binding is jax 0.9.0's (``jax._src.core.trace_state_clean``); a jax
+    without it raises here rather than miscounting every inner site."""
     global _trace_state_clean
     fn = _trace_state_clean
     if fn is None:
-        jax = sys.modules.get("jax")
-        if jax is None:
+        if "jax" not in sys.modules:
             return True  # no jax in the process: nothing can be tracing
-        try:
-            fn = jax.core.trace_state_clean
-        except Exception:
-            fn = lambda: True  # noqa: E731 — no introspection available
-        _trace_state_clean = fn
-    try:
-        return fn()
-    except Exception:
-        return True
+        from jax._src import core as _jax_core  # noqa: PLC0415
+
+        fn = _trace_state_clean = _jax_core.trace_state_clean
+    return fn()
 
 
 class _JitSite:
@@ -370,30 +377,125 @@ def reset_engine_notes() -> None:
         _engine_last.clear()
 
 
+# -- blake2b batch-edge bucket accounting -------------------------------------
+
+
+class BucketTable:
+    """Process-global traffic of the blake2b batch edge per (engine,
+    block-count bucket): dispatches, real items, and items after
+    padding.  The engine is chosen per bucket (``pallas`` from 512
+    items on a TPU, ``xla-scan`` below), so this table is what says
+    which kernel a traffic mix actually reached and what its padding
+    cost — bounded cardinality (two engines x power-of-two block
+    counts).  Call sites guard with ``if _OBS.on:``."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._rows: dict[tuple[str, int], list[int]] = {}
+
+    def note(self, engine: str, nblocks: int, items: int,
+             padded_items: int) -> None:
+        with self._lock:
+            row = self._rows.setdefault((engine, nblocks), [0, 0, 0])
+            row[0] += 1
+            row[1] += items
+            row[2] += padded_items
+
+    def snapshot(self) -> dict:
+        """``{"<engine>:<nblocks>": {"dispatches", "items",
+        "padded_items"}}`` (JSON-able)."""
+        with self._lock:
+            rows = sorted(self._rows.items())
+        return {f"{engine}:{nb}": {"dispatches": d, "items": i,
+                                   "padded_items": p}
+                for (engine, nb), (d, i, p) in rows}
+
+    def reset_for_tests(self) -> None:
+        with self._lock:
+            self._rows.clear()
+
+
+BUCKETS = BucketTable()
+
+
 # -- device memory gauges -----------------------------------------------------
 
 
 def sample_device_gauges() -> bool:
     """Update ``device.mem.live_buffers`` / ``device.mem.bytes_in_use``
     from an ALREADY-initialized jax backend; returns True when a sample
-    was taken.  Never initializes a backend itself: on a wedged device
-    tunnel that first init is exactly the hang the watchdog exists to
-    attribute, so an uninitialized process samples nothing."""
+    was taken.  Never initializes a backend itself: the sampler runs
+    inside the init watchdog, whose job is to attribute a slow first
+    init, not to cause it — an uninitialized process samples nothing."""
     if not _OBS.on:
         return False
-    xb = sys.modules.get("jax._src.xla_bridge")
-    if xb is None or not getattr(xb, "_backends", None):
+    if "jax" not in sys.modules:
         return False
-    try:
-        import jax  # noqa: PLC0415 — guaranteed imported already
+    # jax is loaded or being loaded: this import joins an import in
+    # flight on another thread (never a half-initialised module) and
+    # starts none
+    import jax  # noqa: PLC0415
+    from jax._src import xla_bridge  # noqa: PLC0415
 
-        _G_LIVE_BUFFERS.set(float(len(jax.live_arrays())))
-        stats = jax.local_devices()[0].memory_stats() or {}
-        if "bytes_in_use" in stats:
-            _G_BYTES_IN_USE.set(float(stats["bytes_in_use"]))
-        return True
-    except Exception:
+    if not xla_bridge.backends_are_initialized():
         return False
+    _G_LIVE_BUFFERS.set(float(len(jax.live_arrays())))
+    # memory_stats() is None on backends that keep no allocator stats
+    # (the CPU client): the live-buffer gauge is the whole sample there
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if "bytes_in_use" in stats:
+        _G_BYTES_IN_USE.set(float(stats["bytes_in_use"]))
+    if "peak_bytes_in_use" in stats:
+        _G_PEAK_BYTES.set(float(stats["peak_bytes_in_use"]))
+    return True
+
+
+# -- compile accounting -------------------------------------------------------
+
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": _M_CACHE_REQUESTS,
+    "/jax/compilation_cache/cache_hits": _M_CACHE_HITS,
+    # jax records a miss when it WRITES an entry: a program under the
+    # cache's compile-time floor is a request that is neither
+    "/jax/compilation_cache/cache_misses": _M_CACHE_MISSES,
+}
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": _G_TRACE_SECONDS,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": _G_TRACE_SECONDS,
+    # on a persistent-cache hit this is the retrieval time
+    "/jax/core/compile/backend_compile_duration": _G_BACKEND_SECONDS,
+}
+_watching_compiles = False
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    m = _CACHE_EVENTS.get(event)
+    if m is not None and _OBS.on:
+        m.inc()
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    g = _DURATION_EVENTS.get(event)
+    if g is not None and _OBS.on:
+        g.inc(duration)
+
+
+def watch_compile_events() -> None:
+    """Mirror jax's compile accounting (``jax.monitoring``) into the
+    registry: persistent-cache requests / hits / misses as
+    ``device.compile.cache.*`` and the seconds jax spent tracing,
+    lowering (``device.compile.trace_seconds``) and compiling or
+    fetching executables (``device.compile.backend_seconds``) — set-up
+    time a snapshot can tell apart from run time.  Imports jax; call
+    from entry points that are about to use it.  Idempotent."""
+    global _watching_compiles
+    if _watching_compiles:
+        return
+    import jax.monitoring  # noqa: PLC0415
+
+    jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _watching_compiles = True
 
 
 # -- backend-init watchdog ----------------------------------------------------

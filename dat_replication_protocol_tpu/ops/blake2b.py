@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.device import BUCKETS as _BUCKETS
 from ..obs.device import jit_site as _jit_site
 from ..obs.device import note_engine as _note_engine
 from ..obs.metrics import OBS as _OBS
@@ -503,8 +504,7 @@ class Blake2bStream:
         # bounded async dispatch: without a periodic barrier the host can
         # outrun the device and queue every segment's message arrays in
         # RAM — the O(chunk) discipline would silently become O(blob).
-        # Fetching a (tiny) counter word is the completion barrier that
-        # works on platforms where block_until_ready returns early.  The
+        # Fetching a (tiny) counter word is the completion barrier.  The
         # fence targets the OLDEST in-flight segment, not the newest:
         # waiting on the newest would drain the whole pipeline and stall
         # the next segment's upload behind it (round-3 verdict weak #5).
@@ -626,6 +626,11 @@ def blake2b_batch_begin(
         # payloads are valid; their digests are dropped in collect().
         batch = [payloads[i] for i in idxs]
         Bp = _bucket_nblocks(len(batch))
+        if _OBS.on:
+            # the Pallas wrapper pads on to whole 1024-item tiles
+            _BUCKETS.note("pallas" if pallas_bucket else "xla-scan", nb,
+                          len(batch),
+                          -(-Bp // 1024) * 1024 if pallas_bucket else Bp)
         batch += [b""] * (Bp - len(batch))
         mh, ml, lengths = pack_payloads(batch, nblocks=nb)
         if _OBS.on:
@@ -649,10 +654,8 @@ def blake2b_batch_begin(
         # NEWER batch is dispatched so deliver never serializes a cold
         # D2H behind the next submit (ISSUE 7 part 3).
         for _, hh, hl in handles:
-            for arr in (hh, hl):
-                copy_async = getattr(arr, "copy_to_host_async", None)
-                if copy_async is not None:
-                    copy_async()
+            hh.copy_to_host_async()
+            hl.copy_to_host_async()
 
     def collect() -> list[bytes]:
         out: list[bytes | None] = [None] * len(payloads)

@@ -37,8 +37,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.jax_compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 from .blake2b import _IV_HI, _IV_LO, DIGEST_SIZE, compress_soa
 from ..obs.device import jit_site as _jit_site
 from .u64 import U32
@@ -314,7 +312,7 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
             if vmem_state
             else []
         ),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -42,8 +42,6 @@ from ..obs.metrics import counter as _counter
 from .rabin import GROUP, PACK, _gear_step, _popcount32
 from .u64 import U32
 
-from ..utils.jax_compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 _SUBLANE = 8
 _LANE = 128
 _SENT_OFF = 1 << 30  # empty-window sentinel (rabin_pallas convention)
@@ -211,7 +209,7 @@ def gear_window_first_checked_native(words, avg_bits: int, thin_bits: int,
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.jax_compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 from .rabin import GROUP, NO_HIT, PACK, _gear_step, _popcount32
 from .u64 import U32
 from ..obs.device import jit_site as _jit_site
@@ -151,7 +149,7 @@ def gear_candidates_native(words, avg_bits: int = 13,
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -327,7 +325,7 @@ def gear_window_first_native(words, avg_bits: int, thin_bits: int,
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -434,7 +432,7 @@ def gear_first_native(words, avg_bits: int = 13, block_tiles: int = 8192,
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
             pltpu.VMEM((1, _SUBLANE, btl), jnp.uint32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

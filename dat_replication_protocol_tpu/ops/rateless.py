@@ -315,7 +315,7 @@ class CodedSymbols:
     * ``'numpy'`` — the pure-numpy reference build (the parity oracle).
     * ``'device'`` — the jitted JAX gather + scatter-add over digest
       columns, for pipelines whose digests are already device columns
-      (``_when_tpu_returns.sh`` leg 7 captures this at 1M+1M).
+      (``chip_smoke.py`` checks it against ``'host'`` at 1M digests).
     * ``'auto'`` (default) — ``'host'`` when the native library is
       available, else ``'numpy'``.
 

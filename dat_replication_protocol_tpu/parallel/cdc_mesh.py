@@ -30,7 +30,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..obs.device import jit_site as _jit_site
@@ -67,7 +66,7 @@ def _scan_program(mesh: Mesh, avg_bits: int, use_pallas: bool):
     return _jit_site(
         "parallel.cdc_mesh.scan",
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(P(DATA_AXIS), P()),

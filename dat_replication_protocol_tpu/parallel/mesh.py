@@ -29,13 +29,19 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..obs.device import jit_site as _jit_site
+from ..obs.metrics import OBS as _OBS
+from ..obs.metrics import counter as _counter
 from ..ops import merkle
 from ..ops.blake2b import blake2b_packed
 from ..ops.u64 import U32
 
-from ..utils.jax_compat import shard_map
 
 DATA_AXIS = "data"
+
+# the mesh engine's share of the device-transfer counters the
+# single-device batch edge keeps (ops/blake2b.py)
+_M_H2D = _counter("device.h2d.bytes")
+_M_D2H = _counter("device.d2h.bytes")
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
@@ -136,7 +142,7 @@ def _digest_root_program(mesh: Mesh):
     return _jit_site(
         "parallel.mesh.digest_root",
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(sharded, sharded, sharded),
@@ -185,7 +191,7 @@ def _sharded_hash_program(mesh: Mesh):
     return _jit_site(
         "parallel.mesh.sharded_hash",
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(sharded, sharded, sharded),
@@ -228,6 +234,8 @@ def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
         Bp = n * next_pow2(-(-len(batch) // n))
         batch += [b""] * (Bp - len(batch))
         mh, ml, lengths = pack_payloads(batch, nblocks=nb)
+        if _OBS.on:
+            _M_H2D.inc(mh.nbytes + ml.nbytes + lengths.nbytes)
         mh_d = jax.device_put(mh, spec)
         ml_d = jax.device_put(ml, spec)
         len_d = jax.device_put(lengths, spec)
@@ -236,14 +244,14 @@ def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
 
     def start_d2h() -> None:
         for _, hh, hl in handles:
-            for arr in (hh, hl):
-                copy_async = getattr(arr, "copy_to_host_async", None)
-                if copy_async is not None:
-                    copy_async()
+            hh.copy_to_host_async()
+            hl.copy_to_host_async()
 
     def collect() -> list[bytes]:
         out: list[bytes | None] = [None] * len(payloads)
         for idxs, hh, hl in handles:
+            if _OBS.on:
+                _M_D2H.inc(64 * len(idxs))  # two (B, 8) u32 halves
             for i, d in zip(idxs, digests_to_bytes(hh, hl, digest_size)):
                 out[i] = d
         return out  # type: ignore[return-value]
@@ -269,7 +277,7 @@ def _sharded_diff_program(mesh: Mesh):
     return _jit_site(
         "parallel.mesh.sharded_diff",
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(sharded, sharded, sharded, sharded),
@@ -299,7 +307,7 @@ def _sharded_sketch_program(mesh: Mesh, log2_slots: int):
     return _jit_site(
         "parallel.mesh.sharded_sketch",
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),

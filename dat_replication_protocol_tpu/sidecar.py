@@ -90,6 +90,19 @@ _ACTIVE_GOSSIP = None
 _ACTIVE_EDGE = None
 
 
+# what main() resolved for digest batches (backend.tpu_backend.
+# resolve_digest_engine): engine + the device as jax reports it — one
+# stderr line at start-up, and the ``device`` record of every snapshot
+_DEVICE_RECORD = None
+
+
+def set_device_record(rec) -> None:
+    """Install the resolved-engine record ``--stats-fd`` snapshots carry
+    (None detaches)."""
+    global _DEVICE_RECORD
+    _DEVICE_RECORD = rec
+
+
 def set_active_edge(loop) -> None:
     """Install the :class:`~.edge.EdgeLoop` whose session-table
     aggregate ``--stats-fd`` snapshots carry (None detaches)."""
@@ -1048,18 +1061,28 @@ def snapshot_stats() -> dict:
     breakdown and the hub's aggregate state, keyed by session — the
     supervisor-visible answer to "which peer is parking bytes".
     JSON-able as-is."""
+    # device memory gauges are sampled per snapshot (never initialises a
+    # backend): peak bytes_in_use is what says a batch cap fits HBM
+    obs_device.sample_device_gauges()
     out = {
         "ts": time.time(),
         "monotonic": time.monotonic(),
         "metrics": obs_metrics.snapshot(),
         "events_dropped": obs_events.EVENTS.dropped,
         "jit_sites": obs_device.SENTINEL.snapshot(),
+        # which blake2b kernel each block-count bucket reached, and
+        # what its padding cost (engine is chosen per bucket)
+        "blake2b_buckets": obs_device.BUCKETS.snapshot(),
         # the fleet plane's join input (ISSUE 11): per-link wire
         # cursors + append marks — the SAME dict /snapshot serves
         "watermarks": _WATERMARKS.snapshot(),
         # the active wire-pump route + syscall tier (ISSUE 14): which
         # byte mover this daemon's sessions actually ride
         "pump": session_pump.probe_caps(),
+        # which engine hashes digest batches here and on what device
+        # (platform / device_kind / device_count as jax reports them):
+        # a sidecar that lost its chip must be visible from outside
+        "device": _DEVICE_RECORD,
     }
     if _ACTIVE_HUB is not None:
         out["hub"] = _ACTIVE_HUB.snapshot()
@@ -1320,6 +1343,27 @@ def main(argv=None) -> int:
                            max_retries=args.max_retries)
     emitter = None
     trace_sink = None
+    if args.backend == "host":
+        os.environ["DAT_DEVICE_HASH"] = "0"  # routing-layer override:
+        # force the host digest engine for this daemon's lifetime
+    if os.environ.get("DAT_DEVICE_HASH") != "0":
+        # a device program is reachable: place the persistent compile
+        # cache before the first one compiles (a cold sidecar compiles
+        # one program per (batch, block-count) bucket it meets)
+        from .utils.cache import enable_compile_cache
+
+        enable_compile_cache()
+    # resolve the digest engine NOW, before listening: a backend that
+    # cannot initialise or a device engine that cannot import raises
+    # out of main (non-zero exit, the cause on stderr) instead of a
+    # daemon that answers from the host
+    from .backend.tpu_backend import resolve_digest_engine
+
+    device_rec, _ = resolve_digest_engine()
+    if device_rec["platform"] is not None:
+        # jax is in play: mirror its compile-cache and compile-seconds
+        # accounting into the registry (dark unless telemetry is on)
+        obs_device.watch_compile_events()
     if args.flight_dir:
         # arming enables telemetry: a dark ring has nothing to dump
         obs_flight.FLIGHT.arm(args.flight_dir)
@@ -1331,9 +1375,6 @@ def main(argv=None) -> int:
         emitter = StatsEmitter(args.stats_fd, args.stats_interval,
                                fmt=args.stats_format).start()
         _install_sigusr1(emitter)
-    if args.backend == "host":
-        os.environ["DAT_DEVICE_HASH"] = "0"  # routing-layer override:
-        # force the host digest engine for this daemon's lifetime
     if args.snapshot and (args.hub or args.reconcile):
         p.error("--snapshot cannot combine with --hub/--reconcile "
                 "(it composes with --fanout, where it answers the "
@@ -1367,6 +1408,13 @@ def main(argv=None) -> int:
                              max_sessions=args.hub_max_sessions,
                              parked_budget=args.hub_parked_budget)
         set_active_hub(hub)
+        if hub.mesh_devices:
+            device_rec = dict(device_rec, engine="mesh-sharded",
+                              mesh_devices=hub.mesh_devices)
+    set_device_record(device_rec)
+    print("sidecar: device " + " ".join(
+        f"{k}={json.dumps(v)}" for k, v in device_rec.items()
+        if v is not None), file=sys.stderr, flush=True)
     fanout = None
     if args.fanout:
         if args.stdio:

@@ -1,77 +1,50 @@
-"""Persistent XLA compile-cache setup (one owner for all entry points).
+"""Persistent XLA compile-cache placement (one owner for all entry points).
 
-The scanned-BLAKE2b / tree programs take minutes to compile cold on the
-CPU backend and tens of seconds on TPU; a persistent cache turns reruns
-(tests, bench, examples, driver re-runs) into cache hits.  Scope rules:
+``ops/blake2b.py`` compiles one program per (power-of-two batch,
+power-of-two block count) bucket, seconds each on the chip and longer
+on the CPU backend's scanned programs; a persistent cache turns a second
+start into cache hits.  The directory is part of jax's cache key, so it
+must not move between runs:
 
-* keyed by platform + processor + jax version: AOT artifacts from a
-  host with different CPU features can SIGILL when loaded;
-* per-user path under the system temp dir: a predictable world-shared
-  path would let another local user pre-seed attacker-controlled
-  compiled artifacts (deserialized XLA programs execute).
+* ``JAX_COMPILATION_CACHE_DIR`` in the environment places the cache from
+  outside.  jax reads the variable itself; this module then sets nothing
+  in code, so the operator's placement is never overridden.
+* otherwise the cache lives in ONE fixed, git-ignored directory at the
+  root of the checkout — the same path from any pid or working
+  directory.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
-import tempfile
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+# <checkout>/.jax_cache — three levels up from this file
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def enable_compile_cache(tag: str, env_var: str | None = None) -> None:
-    """Point jax at a persistent, scoped compile-cache directory.
+def enable_compile_cache() -> str:
+    """Make sure jax keeps a persistent compile cache; return its path.
 
-    One shared directory serves every entry point (XLA keys entries per
-    program, so tests warming the cache speeds up bench and vice versa);
-    ``tag`` only labels the fallback log line.  ``env_var`` optionally
-    names an environment variable that overrides the path.  Never
-    raises: the cache is an optimization — but a disabled cache IS
-    logged, because silently losing it costs minutes per cold compile.
+    Call before the first device program compiles (jax latches the
+    cache at first use).  Every entry point that can reach a device
+    program goes through here: the sidecar, ``bench.py``,
+    ``chip_smoke.py``, ``__graft_entry__.py``, the examples and
+    ``tests/conftest.py``.
     """
-    try:
-        import jax
+    placed = os.environ.get(CACHE_ENV)
+    if placed:
+        return placed
+    import jax
 
-        override = os.environ.get(env_var) if env_var else None
-        if override:
-            path = override
-        else:
-            scope = hashlib.blake2b(
-                f"{platform.platform()}-{platform.processor()}-"
-                f"{jax.__version__}".encode(),
-                digest_size=6,
-            ).hexdigest()
-            user = f"u{os.getuid()}" if hasattr(os, "getuid") else "u0"
-            path = os.path.join(
-                tempfile.gettempdir(),
-                f"dat_jax_cache-{user}-{scope}",
-            )
-        # create 0700 and verify ownership: a predictable path that
-        # accepted a pre-existing foreign directory would let another
-        # local user feed us attacker-controlled compiled artifacts.
-        # lstat + symlink rejection: st_uid of the *target* passes the
-        # ownership test when an attacker plants a symlink to a dir the
-        # victim owns, redirecting cache reads/writes wherever they chose.
-        # The hardening applies only to the *derived* (predictable)
-        # default path — an operator-chosen override is trusted as given
-        # (shared group caches and symlinked scratch disks are legitimate
-        # there, and the planted-path attack needs a predictable target)
-        os.makedirs(path, mode=0o700, exist_ok=True)
-        if not override:
-            st = os.lstat(path)
-            import stat as stat_mod
-
-            if stat_mod.S_ISLNK(st.st_mode):
-                raise PermissionError(f"{path} is a symlink")
-            if hasattr(os, "getuid"):  # POSIX-only: Windows fakes 0o777
-                if st.st_uid != os.getuid():
-                    raise PermissionError(f"{path} owned by another user")
-                if st.st_mode & 0o022:
-                    raise PermissionError(f"{path} group/world-writable")
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception as e:
-        import sys
-
-        print(f"{tag}: compile cache disabled ({e}); cold compiles ahead",
-              file=sys.stderr)
+    os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    # the XLA-scan buckets compile in a few seconds on the chip; the
+    # default 1 s floor would skip the small ones a sidecar meets most
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return DEFAULT_CACHE_DIR

@@ -19,9 +19,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import dat_replication_protocol_tpu as protocol  # noqa: E402
 from dat_replication_protocol_tpu import sidecar  # noqa: E402
+from dat_replication_protocol_tpu.utils.cache import (  # noqa: E402
+    enable_compile_cache,
+)
 
 
 def main() -> None:
+    enable_compile_cache()  # serve_tcp in-process skips sidecar.main()
     ready = threading.Event()
     port = {}
     threading.Thread(

@@ -15,12 +15,11 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-import os  # noqa: E402
+from dat_replication_protocol_tpu.utils.cache import (  # noqa: E402
+    enable_compile_cache,
+)
 
-import jax  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+enable_compile_cache()
 
 from dat_replication_protocol_tpu.ops import merkle  # noqa: E402
 from dat_replication_protocol_tpu.runtime import (  # noqa: E402

@@ -78,8 +78,7 @@ def test_state_loads_variants_byte_exact():
     slow-marked (tier-1 runtime audit, ISSUE 12): ~30 s of interpret
     COMPILE for a non-default experiment variant no production route
     sets — the default-path parity stays tier-1 in the fast tests, the
-    variant parity runs in the slow tier and on-device via
-    _when_tpu_returns.sh."""
+    variant parity runs in the slow tier."""
     import hashlib
 
     import jax.numpy as jnp
@@ -107,9 +106,8 @@ def test_state_loads_variants_byte_exact():
     # in ~1 min, while the pure-value unrolled graph that state_loads
     # alone produces compiles pathologically (>20 min measured).  The
     # {vmem_state: False, state_loads: True} composition is covered on
-    # the real chip: _when_tpu_returns.sh cross-checks it against the
-    # baseline with mixed lengths, and bench.py's calibration refuses
-    # any variant whose digests differ from the baseline's.
+    # the real chip: bench.py's calibration refuses any variant whose
+    # digests differ from the baseline's.
     kw = {"vmem_state": True, "state_loads": True}
     hh, hl = blake2b_native(mh_n, ml_n, len_n, interpret=True,
                             msg_loads=True, **kw)

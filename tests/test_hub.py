@@ -453,12 +453,13 @@ def test_labeled_collector_entries_render_as_prom_labels(obs_enabled):
     hub.close()
 
 
-def test_mesh_sharded_hub_engine_matches_hashlib(monkeypatch):
+def test_mesh_sharded_hub_engine_matches_hashlib(monkeypatch, obs_enabled):
     """The cross-session batch sharded over the 8-device virtual mesh
     (batch-dim NamedSharding): digests must be byte-identical to
     hashlib, routed back to the right sessions."""
     monkeypatch.setenv("DAT_DEVICE_HASH", "1")  # opt into the device path
     hub = ReplicationHub(mesh="auto", linger_s=0.01)
+    assert hub.mesh_devices == 8
     a = hub.register("ma")
     b = hub.register("mb")
     got_a, got_b = [], []
@@ -472,6 +473,10 @@ def test_mesh_sharded_hub_engine_matches_hashlib(monkeypatch):
     b.flush()
     assert got_a == [_h(p) for p in payloads_a]
     assert got_b == [_h(p) for p in payloads_b]
+    # the mesh engine counts its transfers like the single-device one
+    counters = obs_enabled.snapshot()["counters"]
+    assert counters["device.h2d.bytes"] > 0
+    assert counters["device.d2h.bytes"] == 64 * 17
     a.close()
     b.close()
     hub.close()

@@ -534,23 +534,46 @@ def test_perf_check_host_only_on_live_quick_bench(tmp_path, monkeypatch):
 # -- bench backend_error structure (ISSUE 5 satellite) ------------------------
 
 
-def test_probe_failure_carries_stage_and_elapsed():
-    stdout = "STAGE platform_probe\nSTAGE first_device_call\n"
-    err = bench._probe_failure("backend init hung (> 87s)", stdout, 87.3)
-    assert err == {"message": "backend init hung (> 87s)",
-                   "stage": "first_device_call", "elapsed_s": 87.3}
-    assert bench._probe_stage("") is None
-    assert bench._probe_stage(None) is None
+def test_backend_error_carries_stage_and_elapsed(obs_enabled, monkeypatch):
+    """The in-process init's failure record: message always, and —
+    with telemetry on — the last init stage entered, its elapsed time
+    and the device telemetry subset."""
+    monkeypatch.setitem(bench._METRICS, "on", True)
+    obs_events.emit("backend.init.stage", stage="first_device_call",
+                    elapsed_s=87.3)
+    err = bench._backend_error(RuntimeError("Unable to initialize backend"))
+    assert err["message"] == "RuntimeError: Unable to initialize backend"
+    assert err["stage"] == "first_device_call"
+    assert err["elapsed_s"] == 87.3
+    assert set(err["telemetry"]) == {"counters", "gauges", "histograms"}
+    monkeypatch.setitem(bench._METRICS, "on", False)
+    dark = bench._backend_error(RuntimeError("x"))
+    assert dark == {"message": "RuntimeError: x", "stage": None,
+                    "elapsed_s": None}
 
 
-def test_probe_backend_reports_stage_on_real_failure():
-    """A probe forced onto a nonexistent platform must fail (fast) with
-    a structured record whose stage is from the real ladder."""
-    backend, err = bench._probe_backend("no_such_platform", timeout=120)
-    assert backend is None
-    assert isinstance(err, dict)
-    assert set(err) >= {"message", "stage", "elapsed_s"}
-    assert err["stage"] in (None,) + obs_device.INIT_STAGES
+def test_backend_init_failure_reports_stage_and_exits_nonzero():
+    """A run forced onto a nonexistent platform fails (fast) in the ONE
+    process that runs the configs: non-zero exit, a structured
+    backend_error whose stage is from the real ladder, every requested
+    config an error — and no child process to fall back to."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, BENCH_PLATFORM="no_such_platform",
+               BENCH_CONFIGS="3", BENCH_DEADLINE="120")
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--quick",
+         "--metrics"],
+        capture_output=True, text=True, timeout=150, env=env, cwd=REPO)
+    assert r.returncode == 1, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    err = out["backend_error"]
+    assert "no_such_platform" in err["message"]
+    assert err["stage"] in obs_device.INIT_STAGES
+    assert out["backend"] is None and out["device"] is None
+    assert "error" in out["configs"]["hash"]
+    assert out["value"] is None  # no figure under the device metric's name
 
 
 def test_emit_carries_structured_backend_error(monkeypatch, capsys):
@@ -583,6 +606,9 @@ def test_digest_pipeline_counts_stream_bytes(obs_enabled):
     assert len(got) == 2
     assert obs_metrics.REGISTRY.counter("device.submit.bytes").value == 1010
     assert obs_metrics.REGISTRY.counter("device.submit.items").value == 2
+    # a stream's bytes are hashed on the host and never reach the device
+    assert obs_metrics.REGISTRY.counter(
+        "device.host.stream.bytes").value == 1000
 
 
 def test_bench_trace_export_resets_engine_memo(tmp_path, obs_enabled):

@@ -1029,11 +1029,19 @@ def test_sidecar_obs_http_flag_serves_session_watermarks(obs_enabled):
         while conn.recv(4096):
             pass
     t.join(timeout=10)
-    snap = json.loads(_get(srv.url + "/snapshot")[1])
+    # the session closed: its link must be GONE from the board.  The
+    # client's EOF races the session thread's own epilogue (serve_tcp
+    # returns once the connection is handed off), so poll briefly.
+    deadline = time.monotonic() + 10
+    while True:
+        snap = json.loads(_get(srv.url + "/snapshot")[1])
+        gone = not any(k.startswith("c1:")
+                       for k in snap["watermarks"]["links"])
+        if gone or time.monotonic() > deadline:
+            break
+        time.sleep(0.05)
     srv.close()
-    # the session closed: its link must be GONE from the board
-    assert not any(k.startswith("c1:")
-                   for k in snap["watermarks"]["links"])
+    assert gone
 
 
 # -- mesh convergence SLO plumbing (ISSUE 19) --------------------------------

@@ -429,8 +429,10 @@ def test_open_connection_with_retry_dials_until_server_appears():
 
         async def start_server_later():
             await asyncio.sleep(0.1)
+            # close the accepted writer: Python 3.12's wait_closed()
+            # waits for every connection the server still holds
             server_box["srv"] = await asyncio.start_server(
-                lambda r, w: None, "127.0.0.1", port)
+                lambda r, w: w.close(), "127.0.0.1", port)
 
         starter = asyncio.ensure_future(start_server_later())
         policy = BackoffPolicy(base=0.05, cap=0.1, max_retries=20, seed=3)
