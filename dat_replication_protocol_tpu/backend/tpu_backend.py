@@ -24,11 +24,13 @@ the host engine in its place.
 from __future__ import annotations
 
 import hashlib
+import threading
+from time import monotonic as _monotonic
 from typing import Callable, Optional
 
 from ..obs.device import note_engine as _note_engine
-from ..obs.metrics import OBS as _OBS, counter as _counter
-from ..obs.tracing import trace_span as _trace_span
+from ..obs.metrics import OBS as _OBS, counter as _counter, \
+    histogram as _histogram
 from ..session.decoder import BlobReader, Decoder
 from ..session.encoder import Encoder
 from ..utils.trace import span
@@ -39,18 +41,50 @@ DIGEST_SIZE = 32  # BLAKE2b-256, dat's content-hash size
 _M_DEC_DIGESTS = _counter("decoder.digests")
 _M_ENC_DIGESTS = _counter("encoder.digests")
 # device-path pipeline traffic (OBSERVABILITY.md device-telemetry
-# catalog): payloads queued for hashing and batches dispatched.  Submit
-# accounting is counters, not per-item spans — the bulk decoder submits
-# per change, and the span story lives at the dispatch/deliver batch
-# boundaries (same run-granularity discipline as `decoder.changes`).
+# catalog): payloads queued for hashing and batches dispatched.  All
+# three are added once per DISPATCH from what dispatch() already holds
+# — the bulk decoder submits per change, and a locked increment per
+# item on 32 session threads was most of the lit run's cost (PERF.md).
 _M_SUBMIT_ITEMS = _counter("device.submit.items")
 _M_SUBMIT_BYTES = _counter("device.submit.bytes")
 _M_DISPATCHES = _counter("device.dispatch.batches")
+# the two queue clocks of a batch, one observation per batch each:
+# oldest item's submit -> dispatch start, and dispatch start -> the end
+# of that batch's digest.deliver
+_H_FILL = _histogram("digest.batch.fill_s")
+_H_RESIDENCE = _histogram("digest.batch.residence_s")
 # bytes of over-threshold blob streams: hashed on the host by design
 # (see _make_stream), so these never reach the device
 _M_HOST_STREAM_BYTES = _counter("device.host.stream.bytes")
 
 OnDigest = Callable[[str, int, bytes], None]  # (kind, seq, digest)
+
+class _Tally(threading.local):
+    """Lit digest deliveries not yet in ``decoder.digests`` /
+    ``encoder.digests``, per delivering thread: the session ends tally
+    here per item (no lock) and whoever ran the delivery loop folds the
+    run in ONE locked increment.  A run's callbacks and its fold share a
+    thread, so the counters are exact whenever no loop is mid-run."""
+
+    dec = 0
+    enc = 0
+
+
+_lit_tally = _Tally()
+
+
+def fold_digest_tallies() -> None:
+    """Lit, once per delivered run: this thread's tallies into the
+    registry counters.  Called by every delivery loop
+    (:meth:`DigestPipeline._deliver_oldest`, the hub's per-session
+    ``_deliver``) and at the session ends' finalize."""
+    tally = _lit_tally
+    if tally.dec:
+        n, tally.dec = tally.dec, 0
+        _M_DEC_DIGESTS.inc(n)
+    if tally.enc:
+        n, tally.enc = tally.enc, 0
+        _M_ENC_DIGESTS.inc(n)
 
 
 def _host_hash_batch(payloads: list[bytes]) -> list[bytes]:
@@ -211,7 +245,10 @@ class DigestPipeline:
         # and only finalize at delivery, preserving submit-order delivery
         self._entries: list[tuple] = []
         self._pending_bytes = 0
-        self._inflight: list[tuple[list[tuple], Callable[[], list[bytes]]]] = []
+        # (entries, collect, batch ordinal, lit dispatch-start time)
+        self._inflight: list[tuple] = []
+        # lit: submit time of the oldest queued item (None: none yet)
+        self._fill_t0: Optional[float] = None
         self.dispatches = 0
         self.hashed_bytes = 0
 
@@ -221,9 +258,8 @@ class DigestPipeline:
         ``on_digest(tag, digest)`` — a shared bound method + tag costs no
         per-item closure, which matters at the bulk decoder's change
         rates (a lambda per change was ~20% of the digest path)."""
-        if _OBS.on:
-            _M_SUBMIT_ITEMS.inc()
-            _M_SUBMIT_BYTES.inc(len(payload))
+        if _OBS.on and self._fill_t0 is None:
+            self._fill_t0 = _monotonic()
         self._entries.append(("payload", payload, on_digest, tag))
         self._pending_bytes += len(payload)
         if (
@@ -238,15 +274,25 @@ class DigestPipeline:
         Blake2bStream`-shaped: ``.digest()``/``.length``) for in-order
         digest delivery alongside batched payloads."""
         if _OBS.on:
-            _M_SUBMIT_ITEMS.inc()
+            if self._fill_t0 is None:
+                self._fill_t0 = _monotonic()
             # a blob-heavy session carries its dominant byte volume
-            # through streams — the bytes counter must say so
+            # through streams — the bytes counter must say so (a stream
+            # is one over-threshold blob: per stream is per megabytes)
             _M_SUBMIT_BYTES.inc(int(getattr(stream, "length", 0)))
             if isinstance(stream, _HostStream):
                 _M_HOST_STREAM_BYTES.inc(stream.length)
         self._entries.append(("stream", stream, on_digest, tag))
         if len(self._entries) >= self._max_batch:
             self.dispatch()
+
+    def mark_fill(self, oldest_t: float) -> None:
+        """Lit: a feeder that queues items ahead of this pipeline (the
+        hub: the wait is in its sessions' queues) names the submit time
+        of the oldest item it is about to hand over, so the batch's
+        ``digest.batch.fill_s`` is observed once, here, from there."""
+        if self._fill_t0 is None or oldest_t < self._fill_t0:
+            self._fill_t0 = oldest_t
 
     @property
     def inflight(self) -> int:
@@ -273,47 +319,64 @@ class DigestPipeline:
         pending = self._pending_bytes
         self._pending_bytes = 0
         self.dispatches += 1
-        if _OBS.on:
-            _M_DISPATCHES.inc()
-        payloads = [e[1] for e in entries if e[0] == "payload"]
-        with _trace_span("device.dispatch", items=len(entries),
-                         bytes=pending), span("digest.dispatch"):
+        batch = self.dispatches
+        t0 = self._lit_dispatch(len(entries), pending) if _OBS.on else None
+        with span("digest.dispatch", batch=batch, items=len(entries),
+                  bytes=pending):
+            payloads = [e[1] for e in entries if e[0] == "payload"]
             collect = self._hash_begin(payloads) if payloads else (lambda: [])
         self._prefetch_inflight()  # older batches' D2H rides under this
         # batch's compute (idempotent per closure)
-        self._inflight.append((entries, collect))
+        self._inflight.append((entries, collect, batch, t0))
         while len(self._inflight) > self._max_inflight:
             self._deliver_oldest()
 
+    def _lit_dispatch(self, items: int, nbytes: int) -> float:
+        """The lit half of a dispatch: the per-batch counters and the
+        fill clock.  Returns the dispatch-start time the batch's
+        residence is measured from."""
+        now = _monotonic()
+        _M_DISPATCHES.inc()
+        _M_SUBMIT_ITEMS.inc(items)
+        _M_SUBMIT_BYTES.inc(nbytes)
+        if self._fill_t0 is not None:
+            _H_FILL.observe(now - self._fill_t0)
+            self._fill_t0 = None
+        return now
+
     def _prefetch_inflight(self) -> None:
-        for _, collect in self._inflight:
+        for _, collect, _, _ in self._inflight:
             start = getattr(collect, "start_d2h", None)
             if start is not None:
                 start()
 
     def _deliver_oldest(self) -> None:
-        entries, collect = self._inflight.pop(0)
-        payload_count = sum(1 for e in entries if e[0] == "payload")
-        with _trace_span("device.deliver", items=len(entries)), \
-                span("digest.collect"):
+        entries, collect, batch, t0 = self._inflight.pop(0)
+        with span("digest.collect", batch=batch, items=len(entries)):
             digest_list = collect()
+        payload_count = sum(1 for e in entries if e[0] == "payload")
         if len(digest_list) != payload_count:
             raise RuntimeError(
                 f"hash backend returned {len(digest_list)} digests for "
                 f"{payload_count} payloads"
             )
-        digests = iter(digest_list)
-        for kind, item, cb, tag in entries:
-            if kind == "payload":
-                self.hashed_bytes += len(item)
-                d = bytes(next(digests))
-            else:
-                self.hashed_bytes += item.length
-                d = item.digest()
-            if tag is None:
-                cb(d)
-            else:
-                cb(tag, d)
+        with span("digest.deliver", batch=batch, items=len(entries)):
+            digests = iter(digest_list)
+            for kind, item, cb, tag in entries:
+                if kind == "payload":
+                    self.hashed_bytes += len(item)
+                    d = bytes(next(digests))
+                else:
+                    self.hashed_bytes += item.length
+                    d = item.digest()
+                if tag is None:
+                    cb(d)
+                else:
+                    cb(tag, d)
+        if _OBS.on:
+            if t0 is not None:  # dispatched lit
+                _H_RESIDENCE.observe(_monotonic() - t0)
+            fold_digest_tallies()
 
     def flush(self) -> None:
         """Dispatch anything queued and deliver ALL outstanding digests in
@@ -370,7 +433,7 @@ class TpuDecoder(Decoder):
 
     def _emit_digest(self, kind: str, seq: int, digest: bytes) -> None:
         if _OBS.on:
-            _M_DEC_DIGESTS.inc()
+            _lit_tally.dec += 1
         for cb in self._digest_cbs:
             cb(kind, seq, digest)
 
@@ -492,6 +555,9 @@ class TpuDecoder(Decoder):
             and not self._stalled()
         ):
             self._pipeline.flush()
+            if _OBS.on:
+                # a pipeline of the caller's own may run no fold
+                fold_digest_tallies()
         super()._maybe_finalize()
 
 
@@ -521,7 +587,7 @@ class TpuEncoder(Encoder):
 
     def _emit_digest(self, kind: str, seq: int, digest: bytes) -> None:
         if _OBS.on:
-            _M_ENC_DIGESTS.inc()
+            _lit_tally.enc += 1
         for cb in self._digest_cbs:
             cb(kind, seq, digest)
 
@@ -601,4 +667,6 @@ class TpuEncoder(Encoder):
 
     def finalize(self, on_flush=None) -> None:
         self._pipeline.flush()  # flush-before-finalize
+        if _OBS.on:
+            fold_digest_tallies()
         super().finalize(on_flush)

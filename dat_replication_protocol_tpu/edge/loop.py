@@ -58,6 +58,10 @@ from ..session.pump import (
     send_step,
 )
 from ..sidecar import DEFAULT_DRAIN_TIMEOUT, _send_refusal
+# the lit twin's three work phases on the device trace's clock: the
+# profiler annotation alone, because their lit record is already the
+# LoopProfiler's (edge.turn spans, the edge.turn.*_s histograms)
+from ..utils.trace import annotation as _annotation
 from .machines import (
     hub_machine,
     reconcile_machine,
@@ -495,7 +499,8 @@ class EdgeLoop:
                     self._probe_subscriber(sess)
                 elif prof is not None:
                     t0 = time.monotonic()
-                    rx = self._read_turn(sess, now)
+                    with _annotation("edge.read"):
+                        rx = self._read_turn(sess, now)
                     prof.account("read", sess.key,
                                  time.monotonic() - t0, rx)
                 else:
@@ -503,7 +508,8 @@ class EdgeLoop:
             if mask & selectors.EVENT_WRITE and not sess.dead:
                 if prof is not None:
                     t0 = time.monotonic()
-                    tx = self._tx_turn(sess, now)
+                    with _annotation("edge.tx"):
+                        tx = self._tx_turn(sess, now)
                     prof.account("tx", sess.key,
                                  time.monotonic() - t0, tx)
                 else:
@@ -641,7 +647,8 @@ class EdgeLoop:
                 # flushed.wait ladder, event-driven
                 if prof is not None:
                     t0 = time.monotonic()
-                    polled = hs.poll()
+                    with _annotation("edge.hub_drain"):
+                        polled = hs.poll()
                     prof.account("hub-drain", sess.key,
                                  time.monotonic() - t0, 0)
                 else:
@@ -654,7 +661,8 @@ class EdgeLoop:
                 # for submitted work is encoded before the reply seals
                 if prof is not None:
                     t0 = time.monotonic()
-                    m.enc.finalize()
+                    with _annotation("edge.hub_drain"):
+                        m.enc.finalize()
                     prof.account("hub-drain", sess.key,
                                  time.monotonic() - t0, 0)
                 else:
@@ -663,7 +671,8 @@ class EdgeLoop:
         if sess.tx_ready and not sess.tx_blocked and not sess.tx_done:
             if prof is not None:
                 t0 = time.monotonic()
-                tx = self._tx_turn(sess, now)
+                with _annotation("edge.tx"):
+                    tx = self._tx_turn(sess, now)
                 prof.account("tx", sess.key, time.monotonic() - t0, tx)
             else:
                 self._tx_turn(sess, now)

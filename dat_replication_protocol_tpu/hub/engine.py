@@ -51,6 +51,7 @@ from ..obs.metrics import (
     gauge as _gauge,
     histogram as _histogram,
 )
+from ..utils.trace import span
 
 __all__ = [
     "ReplicationHub",
@@ -71,6 +72,10 @@ _M_ITEMS = _counter("hub.dispatch.items")
 _M_BYTES = _counter("hub.dispatch.bytes")
 _M_DROPPED = _counter("hub.completions.dropped")
 _H_LATENCY = _histogram("hub.dispatch.latency")
+# the dispatcher's condition wait, lit: busy (hub.dispatch.latency)
+# plus wait can be held against wall time.  Waits are counted, never
+# bracketed by a stage span (OBSERVABILITY.md)
+_H_WAIT = _histogram("hub.dispatch.wait_s")
 
 # dispatcher/waiter guarded-fallback period: wakeups are event-driven
 # (condition notifies); the bound only matters if one is ever lost
@@ -124,7 +129,7 @@ class _SessionState:
         "out_items", "out_bytes", "comp", "comp_items", "comp_bytes",
         "submitted", "submitted_bytes", "delivered", "delivered_bytes",
         "dispatches", "shed", "shed_parked", "gone", "flush_goal",
-        "nowait",
+        "nowait", "marks",
     )
 
     def __init__(self, key: str, weight: float, lock: threading.Lock,
@@ -134,6 +139,9 @@ class _SessionState:
         self.nowait = nowait
         self.cv = threading.Condition(lock)
         self.q: deque = deque()   # (kind, item, cb, tag, nbytes)
+        # lit: [submit time, items still queued] per submitted run,
+        # oldest first — the batch fill clock's view of this queue
+        self.marks: deque = deque()
         self.q_items = 0
         self.q_bytes = 0
         self.out_items = 0        # in the shared pipeline
@@ -372,6 +380,9 @@ class ReplicationHub:
         self._q_bytes = 0
         self._parked_bytes = 0       # global queued+outstanding+undelivered
         self._oldest_ts: Optional[float] = None
+        # lit: oldest submit time among the items of the batch last
+        # composed (None: no marked item in it); dispatcher-thread-local
+        self._batch_oldest: Optional[float] = None
         self._routed: list = []     # dispatcher-thread-local (see _route)
         # recent dispatch-turn latencies (dispatcher-thread-local ring):
         # the latency shed arm triggers on this window's p99, not on one
@@ -487,6 +498,7 @@ class ReplicationHub:
             self._q_bytes -= st.q_bytes
             self._parked_bytes -= st.q_bytes + st.comp_bytes
             st.q.clear()
+            st.marks.clear()
             st.q_items = st.q_bytes = 0
             st.comp.clear()
             st.comp_items = st.comp_bytes = 0
@@ -544,6 +556,7 @@ class ReplicationHub:
                     self._oldest_ts = time.monotonic()
                 if _OBS.on:
                     _M_PARKED.set(self._parked_bytes)
+                    st.marks.append([time.monotonic(), n])
                 self._maybe_shed_locked()
                 self._check_session_alive_locked(st)
                 if was_idle or self._q_items >= self._max_batch:
@@ -575,6 +588,7 @@ class ReplicationHub:
                             self._oldest_ts = time.monotonic()
                         if _OBS.on:
                             _M_PARKED.set(self._parked_bytes)
+                            st.marks.append([time.monotonic(), n])
                         self._maybe_shed_locked()
                         self._check_session_alive_locked(st)
                         # wake the dispatcher only on the transitions it
@@ -660,6 +674,11 @@ class ReplicationHub:
                 cb(digest)
             else:
                 cb(tag, digest)
+        if _OBS.on:
+            # the run's digests into decoder.digests, one increment
+            from ..backend.tpu_backend import fold_digest_tallies
+
+            fold_digest_tallies()
 
     def _check_alive_locked(self) -> None:
         if self._failed is not None:
@@ -683,22 +702,35 @@ class ReplicationHub:
                 with self._lock:
                     while not (self._closed or self._failed
                                or self._turn_ready_locked()):
-                        self._work.wait(self._wait_s_locked())
+                        self._idle_wait_locked()
                     if self._closed or self._failed:
                         return
-                    batch = self._compose_locked()
-                    engine_flush = self._flush_needed_locked()
+                # the turn's own lock hold, apart from the wait's: the
+                # stage span must not bracket the wait, and must close
+                # (a ring record, maybe a sink write) with no lock held
+                with span("hub.compose"):
+                    with self._lock:
+                        batch = self._compose_locked()
+                        engine_flush = self._flush_needed_locked()
                 t0 = time.monotonic()
                 turn_bytes = 0
-                for entry_st, kind, item, cb, tag, nbytes in batch:
-                    routed = (entry_st, cb, tag, nbytes)
-                    if kind == "payload":
-                        self._pipeline.submit(item, self._route, routed)
-                    else:
-                        self._pipeline.submit_stream(item, self._route,
-                                                     routed)
-                    turn_bytes += nbytes
                 if batch:
+                    if self._batch_oldest is not None:
+                        # lit: the batch's fill clock started in the
+                        # sessions' queues, not at the pipeline's door
+                        mark = getattr(self._pipeline, "mark_fill", None)
+                        if mark is not None:
+                            mark(self._batch_oldest)
+                    with span("hub.submit", items=len(batch)):
+                        for entry_st, kind, item, cb, tag, nbytes in batch:
+                            routed = (entry_st, cb, tag, nbytes)
+                            if kind == "payload":
+                                self._pipeline.submit(item, self._route,
+                                                      routed)
+                            else:
+                                self._pipeline.submit_stream(
+                                    item, self._route, routed)
+                            turn_bytes += nbytes
                     self._pipeline.dispatch()
                 with self._lock:
                     drain_idle = (self._q_items == 0
@@ -708,7 +740,9 @@ class ReplicationHub:
                     # barrier): drain the readback pipeline so windows
                     # free and flush barriers release promptly
                     self._pipeline.flush()
-                self._distribute_routed()
+                if self._routed:
+                    with span("hub.distribute", items=len(self._routed)):
+                        self._distribute_routed()
                 if batch or engine_flush:
                     latency = time.monotonic() - t0
                     self._lat_ring.append(latency)
@@ -733,6 +767,15 @@ class ReplicationHub:
                 for key in list(self._sessions):
                     self._session_state(key).cv.notify_all()
                 self._work.notify_all()
+
+    def _idle_wait_locked(self) -> None:
+        """One bounded wait for work.  Lit, its seconds are counted
+        (``hub.dispatch.wait_s``) — a wait is never a stage span."""
+        t0 = time.monotonic() if _OBS.on else None
+        self._work.wait(self._wait_s_locked())
+        if t0 is not None and _OBS.on:  # still lit: a gate that went
+            # dark during the wait gets no late observation
+            _H_WAIT.observe(time.monotonic() - t0)
 
     def _turn_ready_locked(self) -> bool:
         if self._flush_needed_locked():
@@ -778,6 +821,7 @@ class ReplicationHub:
         items_left = self._max_batch
         bytes_left = self._max_batch_bytes
         batch: list = []
+        self._batch_oldest = None
 
         def take(st: _SessionState, limit: int) -> int:
             nonlocal items_left, bytes_left
@@ -799,6 +843,8 @@ class ReplicationHub:
                 if kind == "payload":
                     bytes_left -= nbytes
                 n += 1
+            if n and st.marks:
+                self._take_marks(st, n)
             return n
 
         for st in order:  # quota pass: weight-proportional shares
@@ -811,6 +857,23 @@ class ReplicationHub:
             take(st, items_left)
         self._oldest_ts = time.monotonic() if self._q_items else None
         return batch
+
+    def _take_marks(self, st: _SessionState, n: int) -> None:
+        """Lit leftovers only (marks exist only for runs submitted
+        lit): ``n`` items left ``st``'s queue for the batch being
+        composed; pop their run marks, keeping the oldest submit time
+        seen for the batch's fill clock."""
+        marks = st.marks
+        while n > 0 and marks:
+            mark = marks[0]
+            if self._batch_oldest is None or mark[0] < self._batch_oldest:
+                self._batch_oldest = mark[0]
+            if mark[1] <= n:
+                n -= mark[1]
+                marks.popleft()
+            else:
+                mark[1] -= n
+                n = 0
 
     def _route(self, routed, digest: bytes) -> None:
         """Pipeline completion -> the dispatcher-local buffer.  ONLY the
@@ -874,6 +937,7 @@ class ReplicationHub:
         self._q_bytes -= st.q_bytes
         self._parked_bytes -= st.q_bytes + st.comp_bytes
         st.q.clear()
+        st.marks.clear()
         st.q_items = st.q_bytes = 0
         st.comp.clear()
         st.comp_items = st.comp_bytes = 0

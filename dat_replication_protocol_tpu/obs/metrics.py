@@ -55,25 +55,39 @@ __all__ = [
 
 
 class _Gate:
-    """The hoisted enable gate.  One mutable slot; instrumentation
-    sites read ``OBS.on`` and nothing else."""
+    """The hoisted enable gate.  Instrumentation sites read ``OBS.on``
+    and nothing else.
 
-    __slots__ = ("on",)
+    ``frames`` is the second slot, read only INSIDE an ``if OBS.on:``
+    block: it lights the per-frame wire-offset instants
+    (``decoder.frame`` / ``encoder.frame``), whose only readers are the
+    offline timeline CLI over ``--trace-jsonl`` files and the flight
+    recorder.  A supervisor that only scrapes snapshots
+    (``--stats-fd`` / ``--obs-http``) lights ``on`` alone and pays no
+    dict, lock and ring append per frame."""
+
+    __slots__ = ("on", "frames")
 
     def __init__(self) -> None:
         self.on = False
+        self.frames = False
 
 
 OBS = _Gate()
 
 
-def enable() -> None:
-    """Turn telemetry on process-wide (idempotent)."""
+def enable(frames: bool = True) -> None:
+    """Turn telemetry on process-wide (idempotent).  ``frames=False``
+    lights the gate without the per-frame instants, and leaves them as
+    they are if an earlier call lit them."""
     OBS.on = True
+    if frames:
+        OBS.frames = True
 
 
 def disable() -> None:
     OBS.on = False
+    OBS.frames = False
 
 
 def _seed_gate_from_env() -> None:
@@ -82,6 +96,7 @@ def _seed_gate_from_env() -> None:
     # hot paths never pay an environ read; see module docstring)
     if os.environ.get("DAT_OBS", "") not in ("", "0"):
         OBS.on = True
+        OBS.frames = True
 
 
 _seed_gate_from_env()

@@ -35,7 +35,9 @@ Span record shape (one JSON object per line on a sink)::
     {"seq": 12, "ts": 103.2, "dur": 0.0018, "span": "reconnect.attempt",
      "id": 7, "parent": 3, "tid": 139923, "fields": {"offset": 4711}}
 
-Frame instants use ``span`` names ``encoder.frame`` / ``decoder.frame``
+Frame instants (lit by the gate's second slot, ``OBS.frames``: a
+snapshot scraper does not pay for them) use ``span`` names
+``encoder.frame`` / ``decoder.frame``
 (and ``decoder.frame.run`` for a native bulk-dispatch run) with fields
 ``offset`` (wire offset of the frame's first header byte), ``wire_len``
 (header + payload bytes), ``kind`` (``change``/``blob``) and, for runs,
@@ -117,13 +119,16 @@ class trace_span:
     :func:`trace_instant` behind their own ``if _OBS.on:`` guard
     instead, keeping the disabled path at one attribute load."""
 
-    __slots__ = ("name", "fields", "_t0", "_id", "_parent", "_on")
+    __slots__ = ("name", "fields", "dur", "_t0", "_id", "_parent", "_on")
 
     def __init__(self, name: str, **fields):
         self.name = name
         self.fields = fields
 
     def __enter__(self) -> "trace_span":
+        # seconds, once a recorded span has ended (utils.trace.span
+        # feeds its histogram from it); None for a span that was dark
+        self.dur = None
         if not OBS.on:
             self._on = False
             return self
@@ -145,8 +150,8 @@ class trace_span:
                 # a span that ended by exception says so — post-mortem
                 # timelines need the failing phase, not just the error
                 fields = dict(fields, error=exc_type.__name__)
-            SPANS.record(self.name, self._t0,
-                         time.monotonic() - self._t0, self._id,
+            self.dur = time.monotonic() - self._t0
+            SPANS.record(self.name, self._t0, self.dur, self._id,
                          self._parent, threading.get_ident(), fields)
         return False
 

@@ -42,6 +42,7 @@ from ..obs.device import jit_site as _jit_site
 from ..obs.device import note_engine as _note_engine
 from ..obs.metrics import OBS as _OBS
 from ..obs.metrics import counter as _counter
+from ..utils.trace import span
 from .u64 import U32, add64, add64_3, ror64
 
 # device-transfer attribution (OBSERVABILITY.md device-telemetry
@@ -631,21 +632,25 @@ def blake2b_batch_begin(
             _BUCKETS.note("pallas" if pallas_bucket else "xla-scan", nb,
                           len(batch),
                           -(-Bp // 1024) * 1024 if pallas_bucket else Bp)
-        batch += [b""] * (Bp - len(batch))
-        mh, ml, lengths = pack_payloads(batch, nblocks=nb)
+        with span("digest.pack", items=len(idxs), nblocks=nb):
+            batch += [b""] * (Bp - len(batch))
+            mh, ml, lengths = pack_payloads(batch, nblocks=nb)
         if _OBS.on:
             _M_H2D.inc(mh.nbytes + ml.nbytes + lengths.nbytes)
         # stage explicitly (device_put returns immediately): the upload
         # streams while earlier batches compress, and — when donation is
         # supported — the staged buffers are DONATED to the dispatch, so
         # successive batches double-buffer through recycled staging HBM
-        # instead of growing the live set
-        mh_d = jax.device_put(mh)
-        ml_d = jax.device_put(ml)
-        hh, hl = packed_fn(
-            mh_d, ml_d, jnp.asarray(lengths), digest_size
-        )
-        handles.append((idxs, hh[: len(idxs)], hl[: len(idxs)]))
+        # instead of growing the live set.  The span is the HOST's time
+        # inside the two calls, not the link's.
+        with span("digest.h2d", items=len(idxs), nblocks=nb):
+            mh_d = jax.device_put(mh)
+            ml_d = jax.device_put(ml)
+        with span("digest.launch", items=len(idxs), nblocks=nb):
+            hh, hl = packed_fn(
+                mh_d, ml_d, jnp.asarray(lengths), digest_size
+            )
+            handles.append((idxs, hh[: len(idxs)], hl[: len(idxs)]))
 
     def start_d2h() -> None:
         # begin the digest readback WITHOUT blocking: by collect() time
@@ -663,8 +668,13 @@ def blake2b_batch_begin(
             if _OBS.on:
                 # two (B, 8) u32 halves fetched per bucket = 64 B/item
                 _M_D2H.inc(64 * len(idxs))
-            for i, d in zip(idxs, digests_to_bytes(hh, hl, digest_size)):
-                out[i] = d
+            # the one place the host legitimately waits for the device
+            with span("digest.d2h_wait", items=len(idxs)):
+                hh = np.asarray(hh)
+                hl = np.asarray(hl)
+            with span("digest.unpack", items=len(idxs)):
+                for i, d in zip(idxs, digests_to_bytes(hh, hl, digest_size)):
+                    out[i] = d
         return out  # type: ignore[return-value]
 
     collect.start_d2h = start_d2h  # type: ignore[attr-defined]

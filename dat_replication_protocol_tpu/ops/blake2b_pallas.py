@@ -316,6 +316,8 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the name the device trace shows this kernel under
+        name="blake2b_compress",
     )(*inputs)
     return outh, outl
 

@@ -213,6 +213,8 @@ def gear_window_first_checked_native(words, avg_bits: int, thin_bits: int,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the name the device trace shows this kernel under
+        name="fused_cdc_window_first",
     )(words)
 
 

@@ -102,6 +102,8 @@ def merkle_level_native(mh, ml, block_items: int = 1024,
             jax.ShapeDtypeStruct((4, _SUBLANE, pl_), jnp.uint32),
         ],
         interpret=interpret,
+        # the name the device trace shows this kernel under
+        name="merkle_level",
     )(*inputs)
     return outh, outl
 

@@ -153,6 +153,8 @@ def gear_candidates_native(words, avg_bits: int = 13,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the name the device trace shows this kernel under
+        name="gear_bits",
     )(words)
 
 
@@ -329,6 +331,8 @@ def gear_window_first_native(words, avg_bits: int, thin_bits: int,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the name the device trace shows this kernel under
+        name="gear_window_first",
     )(words)
 
 
@@ -436,6 +440,8 @@ def gear_first_native(words, avg_bits: int = 13, block_tiles: int = 8192,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the name the device trace shows this kernel under
+        name="gear_first",
     )(words)
 
 

@@ -1197,9 +1197,10 @@ class Decoder:
                         off0 = st["base"] + fs0 - _header_len(fl0)
                         end = st["base"] + st["starts"][last] \
                             + st["lens"][last]
-                        _trace_instant("decoder.frame.run", offset=off0,
-                                       kind="change", frames=k,
-                                       wire_len=end - off0)
+                        if _OBS.frames:
+                            _trace_instant("decoder.frame.run", offset=off0,
+                                           kind="change", frames=k,
+                                           wire_len=end - off0)
                         self._lit_cost_change_run(
                             end - off0, sum(st["lens"][f0:f0 + k]), k)
                 if use_tap:
@@ -1222,6 +1223,7 @@ class Decoder:
         on_change = self._on_change
         lock = self._ack_lock
         obs_on = _OBS.on  # hoisted: one load for the whole run
+        frames_on = obs_on and _OBS.frames  # the per-frame instants
         base = st["base"]
         mk = Change.__new__
         mka = _FastAck.__new__
@@ -1249,7 +1251,7 @@ class Decoder:
                 row += 1
                 f += 1
                 self.changes += 1
-                if obs_on:
+                if frames_on:
                     fl = flens[f - 1]
                     hl = _header_len(fl)
                     _trace_instant("decoder.frame",
@@ -1477,10 +1479,11 @@ class Decoder:
         self.changes += 1
         if _OBS.on:
             _M_DEC_CHANGES.inc()
-            _trace_instant("decoder.frame", offset=self._frame_start,
-                           kind="change",
-                           wire_len=_header_len(len(payload))
-                           + len(payload))
+            if _OBS.frames:
+                _trace_instant("decoder.frame", offset=self._frame_start,
+                               kind="change",
+                               wire_len=_header_len(len(payload))
+                               + len(payload))
             self._lit_cost_change(len(payload))
         self._state = TYPE_HEADER
         if self._on_change is not None:
@@ -1554,10 +1557,11 @@ class Decoder:
         n = len(cols.change)
         if _OBS.on:
             _M_DEC_BATCH_FRAMES.inc()
-            _trace_instant("decoder.frame", offset=self._frame_start,
-                           kind="change_batch", rows=n,
-                           wire_len=_header_len(len(payload))
-                           + len(payload))
+            if _OBS.frames:
+                _trace_instant("decoder.frame", offset=self._frame_start,
+                               kind="change_batch", rows=n,
+                               wire_len=_header_len(len(payload))
+                               + len(payload))
             self._lit_cost_batch(len(payload), cols, n)
         self._state = TYPE_HEADER
         # digest tap: the whole frame's rows are owed at acceptance (the
@@ -1690,10 +1694,11 @@ class Decoder:
             return
         if _OBS.on:
             _M_DEC_RC_FRAMES.inc()
-            _trace_instant("decoder.frame", offset=self._frame_start,
-                           kind="reconcile",
-                           wire_len=_header_len(len(payload))
-                           + len(payload))
+            if _OBS.frames:
+                _trace_instant("decoder.frame", offset=self._frame_start,
+                               kind="reconcile",
+                               wire_len=_header_len(len(payload))
+                               + len(payload))
             self._lit_cost_reconcile(len(payload))
         self._state = TYPE_HEADER
         # delivery consumes the frame BEFORE the handler can raise (the
@@ -1733,10 +1738,11 @@ class Decoder:
             return
         if _OBS.on:
             _M_DEC_SN_FRAMES.inc()
-            _trace_instant("decoder.frame", offset=self._frame_start,
-                           kind="snapshot",
-                           wire_len=_header_len(len(payload))
-                           + len(payload))
+            if _OBS.frames:
+                _trace_instant("decoder.frame", offset=self._frame_start,
+                               kind="snapshot",
+                               wire_len=_header_len(len(payload))
+                               + len(payload))
             self._lit_cost_snapshot(len(payload))
         self._state = TYPE_HEADER
         # delivery consumes the frame BEFORE the handler can raise (the
@@ -1771,10 +1777,11 @@ class Decoder:
         self.blobs += 1
         if _OBS.on:
             _M_DEC_BLOBS.inc()
-            _trace_instant("decoder.frame", offset=self._frame_start,
-                           kind="blob",
-                           wire_len=_header_len(self._missing)
-                           + self._missing)
+            if _OBS.frames:
+                _trace_instant("decoder.frame", offset=self._frame_start,
+                               kind="blob",
+                               wire_len=_header_len(self._missing)
+                               + self._missing)
             self._lit_cost_blob(self._missing)
         latch = {"ended": False, "acked": False}
         blob._pending_latch = latch

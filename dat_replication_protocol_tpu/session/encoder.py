@@ -212,9 +212,10 @@ class BlobWriter:
                 # the first parked chunk is this blob's header: the
                 # encoder's byte count right now IS the frame's wire
                 # start offset
-                _trace_instant("encoder.frame", offset=self._encoder.bytes,
-                               kind="blob",
-                               wire_len=frame_wire_len(self.length))
+                if _OBS.frames:
+                    _trace_instant("encoder.frame", offset=self._encoder.bytes,
+                                   kind="blob",
+                                   wire_len=frame_wire_len(self.length))
                 self._encoder._lit_cost_blob(self.length)
         for data, cb, t0 in self._parked:
             self._encoder._parked_bytes -= len(data)
@@ -408,10 +409,11 @@ class Encoder:
             payload = _encode_change_with(fp, rec)
             header = frame_header(len(payload), TYPE_CHANGE)
             if obs_on:
-                _trace_instant("encoder.frame",
-                               offset=self.bytes + len(out),
-                               kind="change",
-                               wire_len=len(header) + len(payload))
+                if _OBS.frames:
+                    _trace_instant("encoder.frame",
+                                   offset=self.bytes + len(out),
+                                   kind="change",
+                                   wire_len=len(header) + len(payload))
                 plen += len(payload)
             out += header
             out += payload
@@ -592,9 +594,10 @@ class Encoder:
             saved = est - (len(header) + len(payload))
             if saved > 0:
                 _M_BATCH_SAVED.inc(saved)
-            _trace_instant("encoder.frame", offset=self.bytes,
-                           kind="change_batch", rows=n,
-                           wire_len=len(header) + len(payload))
+            if _OBS.frames:
+                _trace_instant("encoder.frame", offset=self.bytes,
+                               kind="change_batch", rows=n,
+                               wire_len=len(header) + len(payload))
             self._lit_cost_batch(len(header), len(payload), int(saved))
         if len(cbs) > 1:
             def all_cbs(cbs=cbs):
@@ -613,9 +616,10 @@ class Encoder:
             # causal key: self.bytes BEFORE the header push is the wire
             # offset this frame starts at — the same number the peer's
             # decoder computes for the same frame (obs/tracing.py)
-            _trace_instant("encoder.frame", offset=self.bytes,
-                           kind="change",
-                           wire_len=len(header) + len(payload))
+            if _OBS.frames:
+                _trace_instant("encoder.frame", offset=self.bytes,
+                               kind="change",
+                               wire_len=len(header) + len(payload))
             self._lit_cost_change(len(header), len(payload))
         self._push(header, None)
         return self._push(payload, on_flush)
@@ -652,9 +656,10 @@ class Encoder:
         if _OBS.on:
             _M_RC_FRAMES.inc()
             _M_RC_WIRE.inc(len(header) + len(payload))
-            _trace_instant("encoder.frame", offset=self.bytes,
-                           kind="reconcile",
-                           wire_len=len(header) + len(payload))
+            if _OBS.frames:
+                _trace_instant("encoder.frame", offset=self.bytes,
+                               kind="reconcile",
+                               wire_len=len(header) + len(payload))
             self._lit_cost_reconcile(len(header), len(payload))
         return self._push(header + payload, on_flush)
 
@@ -688,9 +693,10 @@ class Encoder:
         if _OBS.on:
             _M_SN_FRAMES.inc()
             _M_SN_WIRE.inc(len(header) + len(payload))
-            _trace_instant("encoder.frame", offset=self.bytes,
-                           kind="snapshot",
-                           wire_len=len(header) + len(payload))
+            if _OBS.frames:
+                _trace_instant("encoder.frame", offset=self.bytes,
+                               kind="snapshot",
+                               wire_len=len(header) + len(payload))
             self._lit_cost_snapshot(len(header), len(payload))
         return self._push(header + payload, on_flush)
 
@@ -722,9 +728,10 @@ class Encoder:
             ws._park(header, None)
         else:
             if _OBS.on:
-                _trace_instant("encoder.frame", offset=self.bytes,
-                               kind="blob",
-                               wire_len=len(header) + length)
+                if _OBS.frames:
+                    _trace_instant("encoder.frame", offset=self.bytes,
+                                   kind="blob",
+                                   wire_len=len(header) + length)
                 self._lit_cost_blob(length)
             self._push(header, None)
         self._open_blobs.append(ws)

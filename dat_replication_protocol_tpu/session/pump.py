@@ -56,6 +56,7 @@ from ..obs.metrics import OBS as _OBS, counter as _counter, \
     histogram as _histogram
 from ..obs import wirecost as _wirecost
 from ..runtime import native
+from ..utils.trace import span
 from .decoder import Decoder, DecoderDestroyedError
 from .encoder import Encoder, EncoderDestroyedError
 from .transport import WAKE_FALLBACK, recv_over, send_over, \
@@ -209,8 +210,13 @@ def recv_pump(decoder: Decoder, fd: int,
         while not decoder.destroyed:
             buf = np.empty(st.cap, dtype=np.uint8)  # fresh: see _RecvState
             t0 = _perf()
-            r = native.pump_recv_scan(fd, buf, PUMP_SLICE, st.starts,
-                                      st.lens, st.ids, st.stats)
+            # a blocking read plus the frame scan: where the peer keeps
+            # the socket full it is the kernel's copy and the scan,
+            # where it does not it is the wait for the peer — the one
+            # wait a stage span may bracket (OBSERVABILITY.md)
+            with span("pump.recv"):
+                r = native.pump_recv_scan(fd, buf, PUMP_SLICE, st.starts,
+                                          st.lens, st.ids, st.stats)
             if r is None:  # library vanished mid-session (tests reset)
                 recv_over(decoder,
                           _metered_reader(decoder, _tapped_reader(fd, tap)))
@@ -238,8 +244,11 @@ def recv_pump(decoder: Decoder, fd: int,
                 tap(data)
             wake.clear()
             try:
-                ok = decoder.write_indexed(data, st.starts, st.lens,
-                                           st.ids, nframes, consumed)
+                # frame callbacks, blob join, digest submits (a private
+                # pipeline's digest.* stages nest inside)
+                with span("decode.write", bytes=nbytes, frames=nframes):
+                    ok = decoder.write_indexed(data, st.starts, st.lens,
+                                               st.ids, nframes, consumed)
             except DecoderDestroyedError:
                 return
             if not ok:

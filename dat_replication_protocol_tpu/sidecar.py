@@ -1371,7 +1371,9 @@ def main(argv=None) -> int:
         obs_metrics.enable()
         trace_sink = obs_tracing.attach_jsonl_sink(args.trace_jsonl)
     if args.stats_fd is not None:
-        obs_metrics.enable()  # --stats-fd IS the telemetry opt-in
+        # --stats-fd IS the telemetry opt-in; a snapshot reader needs
+        # no per-frame instants (--trace-jsonl/--flight-dir light those)
+        obs_metrics.enable(frames=False)
         emitter = StatsEmitter(args.stats_fd, args.stats_interval,
                                fmt=args.stats_format).start()
         _install_sigusr1(emitter)
@@ -1453,7 +1455,9 @@ def main(argv=None) -> int:
             args.snapshot, wire_offset=args.snapshot_offset)
     obs_srv = None
     if args.obs_http is not None:
-        obs_metrics.enable()  # a dark endpoint would serve zeros
+        # a dark endpoint would serve zeros; no route of it serves the
+        # per-frame instants
+        obs_metrics.enable(frames=False)
         obs_srv = obs_http.ObsHttpServer(
             args.obs_http, snapshot_fn=snapshot_stats,
             admission_fn=_active_admission_fn()).start()

@@ -1,29 +1,40 @@
-"""Profiling spans around host->device dispatch boundaries.
+"""Stage spans: one call at each layer boundary, read three ways.
 
 The reference has no tracing at all — only passive byte/frame counters
 (reference: encode.js:51-53, decode.js:68-70).  At device scale that is
 not enough: round 2 shipped a ~2000x CDC regression that a single trace
 would have localized in minutes (the cost was H2D staging, not the
-kernel).  SURVEY.md §5 therefore promises `jax.profiler` spans around
-every dispatch; this module is that hook.
+kernel).  This module is the hook every host-side stage of the device
+path is bracketed with::
 
-* :func:`span` — named annotation context.  Wrap host-side phases
-  (packing, dispatch, collect) so they show up on the TraceViewer
-  timeline next to the device ops.  Uses
-  ``jax.profiler.TraceAnnotation``; ~ns overhead when no trace is
-  active, so call sites leave it on unconditionally.
+    with span("digest.pack", items=k, bytes=b): ...
+
+* :func:`span` — named stage.  (1) ALWAYS a
+  ``jax.profiler.TraceAnnotation(name)``: it lands on the profiler's
+  host plane on the device trace's own clock, which is what attributes
+  the device's idle gaps to host stages; ~ns when no trace is active,
+  so call sites leave it on unconditionally.  (2) With the obs gate on,
+  one record in the obs span ring (``obs.tracing.SPANS``, field
+  ``src="jax"``) with its parent link and the caller's fields; a span
+  opened inside one that carries ``batch`` inherits it, so every stage
+  of one device batch shares the pipeline's dispatch ordinal.  (3) With
+  the gate on, the duration is added to the histogram
+  ``span.<name>.seconds``, whose ``count``/``sum`` ride every
+  ``--stats-fd`` snapshot.  With the gate off the fields are dropped
+  and the bound factory's annotation is returned directly.
+* :func:`annotation` — the first third alone, for a site whose lit
+  record another recorder already keeps (the edge loop's
+  ``LoopProfiler``).
 * :func:`trace_to` — whole-program capture into a profile directory
   (``bench.py --trace=DIR`` uses it; open with TensorBoard or Perfetto).
 
+Two rules keep the idle-gap attribution honest (OBSERVABILITY.md): no
+span brackets a wait on another thread or on a peer, and none brackets
+a session, connection or loop lifetime — sites are per batch, per pump
+slab or per lit loop turn, never per item or per frame.
+
 JAX is imported lazily: the session layer must stay importable (and
 fast) in processes that never touch a device.
-
-When the obs gate is on, :func:`span` ALSO records into the obs span
-ring (``obs.tracing.SPANS``, field ``src="jax"``) so device-dispatch
-phases appear in the exported Chrome trace next to the wire-offset
-frame spans — one timeline for host wire work and device work
-(ISSUE 4).  With the gate off, behavior is byte-identical to before:
-the bound factory is returned directly.
 """
 # datlint: disable-file=obs-discipline  — this module IS span plumbing:
 # it forwards caller-supplied span names into jax.profiler and the obs
@@ -33,9 +44,10 @@ from __future__ import annotations
 
 import contextlib
 import sys
+import threading
 
 from ..obs import tracing as _obs_tracing
-from ..obs.metrics import OBS as _OBS
+from ..obs.metrics import OBS as _OBS, histogram as _histogram
 
 
 class _NullSpan:
@@ -74,18 +86,35 @@ def _reset_span_binding_for_tests() -> None:
     _span_factory = None
 
 
+# span name -> its `span.<name>.seconds` histogram, bound at the
+# name's first lit use (a dict read under the GIL afterwards; the
+# registry keeps registrations across reset(), so handles stay valid)
+_span_hists: dict = {}
+
+# the `batch` field of the innermost lit span that carries one, per
+# thread: what a stage opened inside it inherits
+_tls = threading.local()
+
+
 class _JoinedSpan:
-    """jax TraceAnnotation + an obs span record of the same name, so
-    device-phase annotations land in the exported Chrome trace next to
-    the wire-offset spans (``src="jax"`` distinguishes them)."""
+    """The profiler annotation plus the two lit readings of the same
+    region: one obs span record (``src="jax"``, parent link, fields,
+    inherited ``batch``) and one ``span.<name>.seconds`` observation."""
 
-    __slots__ = ("_span", "_inner")
+    __slots__ = ("_span", "_inner", "_outer_batch")
 
-    def __init__(self, name: str, inner):
-        self._span = _obs_tracing.trace_span(name, src="jax")
+    def __init__(self, name: str, inner, fields: dict | None = None):
+        self._span = _obs_tracing.trace_span(name, src="jax",
+                                             **(fields or {}))
         self._inner = inner
 
     def __enter__(self):
+        fields = self._span.fields
+        outer = self._outer_batch = getattr(_tls, "batch", None)
+        if "batch" in fields:
+            _tls.batch = fields["batch"]
+        elif outer is not None:
+            fields["batch"] = outer
         self._span.__enter__()
         try:
             self._inner.__enter__()
@@ -94,6 +123,7 @@ class _JoinedSpan:
             # with-statement never runs __exit__, and an unpopped id
             # would corrupt the thread's span-parent stack for good
             self._span.__exit__(*sys.exc_info())
+            _tls.batch = outer
             raise
         return self
 
@@ -101,19 +131,31 @@ class _JoinedSpan:
         try:
             return self._inner.__exit__(*exc) or False
         finally:
-            self._span.__exit__(*exc)
+            span_ = self._span
+            span_.__exit__(*exc)
+            _tls.batch = self._outer_batch
+            if span_.dur is not None:
+                hist = _span_hists.get(span_.name)
+                if hist is None:
+                    hist = _span_hists[span_.name] = _histogram(
+                        f"span.{span_.name}.seconds")
+                hist.observe(span_.dur)
 
 
-def span(name: str):
-    """Named profiler annotation; inert if jax is unavailable.  With
-    the obs gate on, the span is additionally recorded into the obs
-    span ring (see module docstring)."""
-    factory = _span_factory
-    if factory is None:
-        factory = _bind_span_factory()
-    if _OBS.on:
-        return _JoinedSpan(name, factory(name))
+def annotation(name: str):
+    """The profiler annotation alone, gate or no gate; inert if jax is
+    unavailable."""
+    factory = _span_factory or _bind_span_factory()
     return factory(name)
+
+
+def span(name: str, **fields):
+    """Named stage (see module docstring).  ``fields`` reach the obs
+    span ring only — the profiler sees the bare name, so a trace
+    reduction can match it."""
+    if _OBS.on:
+        return _JoinedSpan(name, annotation(name), fields)
+    return annotation(name)
 
 
 @contextlib.contextmanager

@@ -352,7 +352,7 @@ def test_pipeline_prefetches_d2h_before_deliver():
 
 def test_dispatch_span_opens_before_prior_deliver_closes(obs_enabled):
     """The acceptance trace evidence: with the pipelined readback, the
-    device.dispatch span of batch N+1 OPENS before the device.deliver
+    digest.dispatch span of batch N+1 OPENS before the digest.collect
     span of batch N closes (h2d rides under compute, readback under the
     next submit)."""
     from dat_replication_protocol_tpu.backend.tpu_backend import (
@@ -366,8 +366,8 @@ def test_dispatch_span_opens_before_prior_deliver_closes(obs_enabled):
         pipe.submit(b"p%d" % i, got.append)
     pipe.flush()
     assert len(got) == 4
-    dispatches = SPANS.spans("device.dispatch")
-    delivers = SPANS.spans("device.deliver")
+    dispatches = SPANS.spans("digest.dispatch")
+    delivers = SPANS.spans("digest.collect")
     assert len(dispatches) == 4 and len(delivers) == 4
     # deliver of batch 0 happens inside dispatch of batch 2 (inflight
     # bound 2): dispatch[2] opened before deliver[0] closed

@@ -1,0 +1,452 @@
+"""Stage spans on the served digest path (ISSUE 26).
+
+One ``utils.trace.span`` call at each layer boundary, read three ways: a
+profiler annotation always, and lit an obs-ring record (parent link,
+fields, the batch ordinal) plus one ``span.<name>.seconds`` observation.
+Under test here:
+
+* the stages of one dispatch + flush: each recorded once, children
+  linked to ``digest.dispatch`` / ``digest.collect``, one ``batch``, and
+  children's seconds inside the parent's;
+* one observation per batch of every stage histogram and of the two
+  queue clocks, through a private pipeline and through a two-session hub;
+* the lit counters moved per item before this PR and per run after it:
+  their totals at quiescence are what they were;
+* ``OBS.frames``: ``--stats-fd`` alone records no per-frame instant,
+  ``--trace-jsonl`` still does;
+* the dark path of every new site: a ``span()`` call and nothing else.
+"""
+
+import dis
+import hashlib
+import inspect
+import os
+import subprocess
+import sys
+import time
+import types
+
+import pytest
+
+from dat_replication_protocol_tpu import sidecar
+from dat_replication_protocol_tpu.backend import tpu_backend
+from dat_replication_protocol_tpu.backend.tpu_backend import DigestPipeline
+from dat_replication_protocol_tpu.edge.loop import EdgeLoop
+from dat_replication_protocol_tpu.hub import ReplicationHub
+from dat_replication_protocol_tpu.hub import engine as hub_engine
+from dat_replication_protocol_tpu.obs import metrics as obs_metrics
+from dat_replication_protocol_tpu.obs.tracing import SPANS
+from dat_replication_protocol_tpu.ops import blake2b as blake2b_mod
+from dat_replication_protocol_tpu.session import pump as pump_mod
+from dat_replication_protocol_tpu.utils import trace as trace_mod
+
+from test_wire_fixtures import SESSION_4
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+DISPATCH_STAGES = ("digest.pack", "digest.h2d", "digest.launch")
+COLLECT_STAGES = ("digest.d2h_wait", "digest.unpack")
+DIGEST_STAGES = ("digest.dispatch", *DISPATCH_STAGES, "digest.collect",
+                 *COLLECT_STAGES, "digest.deliver")
+
+
+def _hist(name: str) -> dict:
+    return obs_metrics.snapshot()["histograms"].get(
+        name, {"count": 0, "sum": 0.0})
+
+
+def _counters() -> dict:
+    return obs_metrics.snapshot()["counters"]
+
+
+def _blake(p: bytes) -> bytes:
+    return hashlib.blake2b(p, digest_size=32).digest()
+
+
+def _host_batch(payloads):
+    return [_blake(p) for p in payloads]
+
+
+# -- (a) one dispatch + flush on the device engine ----------------------------
+
+def test_one_batch_records_every_digest_stage_once(obs_enabled, monkeypatch):
+    monkeypatch.setenv("DAT_DEVICE_HASH", "1")
+    pipe = DigestPipeline(max_batch=4)
+    got = []
+    payloads = [b"stage-%d" % i for i in range(4)]  # one 1-block bucket
+    for p in payloads:
+        pipe.submit(p, got.append)     # the 4th submit dispatches
+    pipe.flush()
+    assert got == [_blake(p) for p in payloads]
+
+    by_name = {n: SPANS.spans(n) for n in DIGEST_STAGES}
+    assert {n: len(r) for n, r in by_name.items()} == \
+        {n: 1 for n in DIGEST_STAGES}
+    rec = {n: r[0] for n, r in by_name.items()}
+    for n in DISPATCH_STAGES:
+        assert rec[n]["parent"] == rec["digest.dispatch"]["id"], n
+    for n in COLLECT_STAGES:
+        assert rec[n]["parent"] == rec["digest.collect"]["id"], n
+    # one identifier ties the batch's stages together
+    assert {r["fields"]["batch"] for r in rec.values()} == {1}
+    assert all(r["fields"]["src"] == "jax" for r in rec.values())
+    assert rec["digest.dispatch"]["fields"]["items"] == 4
+    assert rec["digest.dispatch"]["fields"]["bytes"] == \
+        sum(map(len, payloads))
+    for parent, kids in (("digest.dispatch", DISPATCH_STAGES),
+                         ("digest.collect", COLLECT_STAGES)):
+        assert sum(rec[k]["dur"] for k in kids) <= rec[parent]["dur"]
+    # the same durations, as histogram sums a snapshot reader can take
+    for n in DIGEST_STAGES:
+        h = _hist(f"span.{n}.seconds")
+        assert h["count"] == 1
+        assert h["sum"] == pytest.approx(rec[n]["dur"])
+
+
+def test_batch_is_inherited_only_inside_a_span_that_carries_it(obs_enabled):
+    with trace_mod.span("outer.stage", batch=7, items=2):
+        with trace_mod.span("inner.stage", items=1):
+            pass
+    with trace_mod.span("later.stage"):
+        pass
+    inner, = SPANS.spans("inner.stage")
+    assert inner["fields"] == {"src": "jax", "items": 1, "batch": 7}
+    assert "batch" not in SPANS.spans("later.stage")[0]["fields"]
+
+
+# -- (b) one observation per batch --------------------------------------------
+
+def test_private_pipeline_observes_each_clock_once_per_batch(obs_enabled):
+    pipe = DigestPipeline(hash_batch=_host_batch, max_batch=2,
+                          max_inflight=2)
+    got = []
+    for i in range(6):                         # three batches of two
+        pipe.submit(b"p%d" % i, got.append)
+    pipe.flush()
+    assert len(got) == 6 and pipe.dispatches == 3
+    for name in ("span.digest.dispatch.seconds",
+                 "span.digest.collect.seconds",
+                 "span.digest.deliver.seconds",
+                 "digest.batch.fill_s", "digest.batch.residence_s"):
+        assert _hist(name)["count"] == 3, name
+    # a batch's residence holds its collect and its deliver
+    assert _hist("digest.batch.residence_s")["sum"] >= \
+        _hist("span.digest.collect.seconds")["sum"] \
+        + _hist("span.digest.deliver.seconds")["sum"]
+    assert [r["fields"]["batch"] for r in SPANS.spans("digest.deliver")] \
+        == [1, 2, 3]
+
+
+def test_two_session_hub_observes_each_clock_once_per_batch(obs_enabled):
+    hub = ReplicationHub(hash_batch=_host_batch, max_batch=4,
+                         linger_s=0.001)
+    try:
+        a, b = hub.register("a"), hub.register("b")
+        got = {"a": [], "b": []}
+        a.submit_many([b"a%d" % i for i in range(6)],
+                      lambda tag, d: got["a"].append(tag))
+        for i in range(6):
+            b.submit(b"b%d" % i, lambda tag, d: got["b"].append(tag), i)
+        a.flush()
+        b.flush()
+        assert got == {"a": list(range(6)), "b": list(range(6))}
+        a.close()
+        b.close()
+    finally:
+        hub.close()
+    batches = _counters()["device.dispatch.batches"]
+    assert batches >= 3                      # 12 items, at most 4 a batch
+    # the fill clock is the hub's (started in the sessions' queues) and
+    # the pipeline behind it observes no second one of its own
+    for name in ("digest.batch.fill_s", "digest.batch.residence_s",
+                 "span.digest.dispatch.seconds",
+                 "span.digest.deliver.seconds"):
+        assert _hist(name)["count"] == batches, name
+    assert _hist("span.hub.submit.seconds")["count"] == batches
+    assert _hist("span.hub.compose.seconds")["count"] >= batches
+    assert _hist("span.hub.distribute.seconds")["count"] >= 1
+    assert _hist("hub.dispatch.wait_s")["count"] >= 1
+    # every run mark was consumed with its items
+    assert not a._state.marks and not b._state.marks
+
+
+def test_hub_fill_clock_starts_in_the_session_queue(obs_enabled):
+    """An item that sat in a session's queue for a while before the
+    dispatcher composed it reads that wait, not the pipeline's."""
+    hub = ReplicationHub(hash_batch=_host_batch, max_batch=4,
+                         linger_s=5.0)
+    try:
+        s = hub.register("slow")
+        s.submit(b"only", lambda d: None)     # sits in the queue ...
+        time.sleep(0.12)
+        s.flush()                             # ... until the barrier
+        s.close()
+    finally:
+        hub.close()
+    fill = _hist("digest.batch.fill_s")
+    assert fill["count"] == 1 and fill["sum"] >= 0.1
+
+
+# -- (c) per-run counters keep per-item totals --------------------------------
+
+def _through_private_pipeline(payloads, dec_emit):
+    pipe = DigestPipeline(hash_batch=_host_batch, max_batch=8)
+    for i, p in enumerate(payloads):
+        pipe.submit(p, dec_emit, i)
+    pipe.flush()
+
+
+def _through_hub(payloads, dec_emit, many):
+    hub = ReplicationHub(hash_batch=_host_batch, max_batch=8,
+                         linger_s=0.001)
+    try:
+        s = hub.register("s")
+        if many:
+            s.submit_many(payloads, dec_emit)
+        else:
+            for i, p in enumerate(payloads):
+                s.submit(p, dec_emit, i)
+        s.flush()
+        s.close()
+    finally:
+        hub.close()
+
+
+@pytest.mark.parametrize("path", ["pipeline", "hub", "submit_many"])
+def test_counter_totals_at_quiescence_are_per_item_totals(obs_enabled, path):
+    from dat_replication_protocol_tpu import decode
+
+    dec = decode(backend="tpu", pipeline=DigestPipeline(
+        hash_batch=_host_batch))
+    seen = []
+    dec.on_digest(lambda kind, seq, d: seen.append(seq))
+    payloads = [b"x" * (10 + i) for i in range(20)]
+    if path == "pipeline":
+        _through_private_pipeline(payloads, dec._emit_change_digest)
+    else:
+        _through_hub(payloads, dec._emit_change_digest,
+                     many=path == "submit_many")
+    assert seen == list(range(20))
+    c = _counters()
+    assert c["device.submit.items"] == 20
+    assert c["device.submit.bytes"] == sum(map(len, payloads))
+    assert c["decoder.digests"] == 20
+    assert c["device.dispatch.batches"] >= 3
+
+
+def test_sidecar_session_digest_counters_match_its_record(obs_enabled):
+    """The whole served path, in process: what the session says it
+    delivered is what the per-run counters add up to."""
+    fed = {"done": False}
+
+    def read_bytes(_n):
+        if fed["done"]:
+            return b""
+        fed["done"] = True
+        return SESSION_4
+
+    out = sidecar.run_session(read_bytes, lambda data: None)
+    assert out["ok"] and out["digests"] == 2
+    c = _counters()
+    assert c["decoder.digests"] == 2
+    assert c["device.submit.items"] == 2
+
+
+# -- (d) OBS.frames -----------------------------------------------------------
+
+def _frames_after_session() -> int:
+    fed = {"done": False}
+
+    def read_bytes(_n):
+        if fed["done"]:
+            return b""
+        fed["done"] = True
+        return SESSION_4
+
+    assert sidecar.run_session(read_bytes, lambda data: None)["ok"]
+    return len(SPANS.spans("decoder.frame")) \
+        + len(SPANS.spans("encoder.frame"))
+
+
+def test_gate_without_frames_records_no_frame_instant(obs_enabled):
+    obs_metrics.disable()
+    assert not obs_metrics.OBS.frames
+    obs_metrics.enable(frames=False)
+    assert obs_metrics.OBS.on and not obs_metrics.OBS.frames
+    assert _frames_after_session() == 0
+    assert _counters()["decoder.digests"] == 2     # the rest is lit
+    assert SPANS.spans("digest.dispatch")
+    obs_metrics.enable()                           # as tests and DAT_OBS=1
+    assert obs_metrics.OBS.frames
+    assert _frames_after_session() >= 4            # 2 frames in, 2 out
+    obs_metrics.enable(frames=False)               # never un-lights them
+    assert obs_metrics.OBS.frames
+
+
+_MAIN_AND_REPORT = (
+    "import sys\n"
+    "from dat_replication_protocol_tpu import sidecar\n"
+    "from dat_replication_protocol_tpu.obs import metrics, tracing\n"
+    "rc = sidecar.main(sys.argv[1:])\n"
+    "sys.stderr.write('GATE %s %s %d\\n' % (metrics.OBS.on, "
+    "metrics.OBS.frames, len(tracing.SPANS.spans('decoder.frame'))))\n"
+    "sys.exit(rc)\n")
+
+
+@pytest.mark.parametrize("flags, frames", [
+    (["--stats-fd", "2", "--stats-interval", "60"], False),
+    (["--obs-http", "0"], False),
+    (["--stats-fd", "2", "--stats-interval", "60", "--trace-jsonl",
+      "{tmp}/peer.jsonl"], True),
+], ids=["stats-fd", "obs-http", "stats-fd+trace-jsonl"])
+def test_sidecar_flags_light_what_their_reader_needs(tmp_path, flags,
+                                                     frames):
+    env = dict(os.environ, DAT_DEVICE_HASH="0")
+    env.pop("DAT_OBS", None)
+    flags = [f.replace("{tmp}", str(tmp_path)) for f in flags]
+    proc = subprocess.run(
+        [sys.executable, "-c", _MAIN_AND_REPORT, "--stdio", *flags],
+        input=SESSION_4, capture_output=True, cwd=REPO, env=env,
+        timeout=120)
+    assert proc.returncode == 0, proc.stderr.decode()
+    gate = [ln for ln in proc.stderr.decode().splitlines()
+            if ln.startswith("GATE ")][-1].split()
+    assert gate[1] == "True"
+    assert gate[2] == str(frames)
+    assert (int(gate[3]) > 0) == frames
+    if frames:
+        log = (tmp_path / "peer.jsonl").read_text()
+        assert '"span": "decoder.frame"' in log
+        assert '"span": "encoder.frame"' in log
+
+
+# -- (e) the dark path of every new site --------------------------------------
+
+def _codes(code):
+    yield code
+    for c in code.co_consts:
+        if isinstance(c, types.CodeType):
+            yield from _codes(c)
+
+
+def _all_names(fn) -> set:
+    """Every global and attribute name in a function's bytecode,
+    closures included."""
+    names = set()
+    for code in _codes(fn.__code__):
+        names |= set(code.co_names)
+    return names
+
+
+# every function that gained a stage site.  The site's dark half is the
+# span() call and nothing else, so the function may name no clock and no
+# histogram beyond the ones listed for it: those it had before (the
+# pump's own) or reads in a lit-only branch (`if _OBS.on` / a `t0` that
+# is None when dark), which the next tests pin
+SITES = {
+    blake2b_mod.blake2b_batch_begin: set(),
+    DigestPipeline.dispatch: set(),
+    DigestPipeline._deliver_oldest: {"_monotonic", "_H_RESIDENCE"},
+    pump_mod.recv_pump: {"_perf", "_H_NATIVE"},
+}
+CLOCKS = {"monotonic", "_monotonic", "perf_counter", "_perf", "time",
+          "_histogram"}
+
+
+@pytest.mark.parametrize("fn", list(SITES), ids=lambda f: f.__qualname__)
+def test_new_sites_name_no_clock_or_histogram_of_their_own(fn):
+    names = _all_names(fn)
+    assert "span" in names
+    timing = {n for n in names if n in CLOCKS or n.startswith("_H_")}
+    assert timing <= SITES[fn], sorted(timing)
+
+
+def _gated_on_obs(fn, target: str) -> bool:
+    """``target`` is loaded only after an ``_OBS`` load, in bytecode
+    order: the function's one gate test comes first."""
+    seen_gate = False
+    for ins in dis.get_instructions(fn):
+        if ins.argval == "_OBS":
+            seen_gate = True
+        if ins.argval == target and not seen_gate:
+            return False
+    return True
+
+
+def test_pipeline_clock_reads_sit_behind_the_gate():
+    assert _gated_on_obs(DigestPipeline.submit, "_monotonic")
+    for lit_only in ("fold_digest_tallies", "_H_RESIDENCE", "_monotonic"):
+        assert _gated_on_obs(DigestPipeline._deliver_oldest, lit_only)
+    # dispatch reads its clock in the lit helper alone
+    assert "_monotonic" not in DigestPipeline.dispatch.__code__.co_names
+    assert "_lit_dispatch" in DigestPipeline.dispatch.__code__.co_names
+    assert "_monotonic" in DigestPipeline._lit_dispatch.__code__.co_names
+
+
+def test_span_dark_is_the_bare_annotation(monkeypatch):
+    """Gate off: span() hands back the bound factory's object, fields
+    dropped; no ring record, no histogram, no clock."""
+    assert not obs_metrics.OBS.on
+    made = []
+
+    class Bare:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace_mod, "_span_factory", Bare)
+    before = len(SPANS.spans())
+    hists = set(obs_metrics.snapshot()["histograms"])
+    s = trace_mod.span("digest.pack", items=3, nblocks=1)
+    assert type(s) is Bare and made == ["digest.pack"]
+    with s:
+        pass
+    assert len(SPANS.spans()) == before
+    assert set(obs_metrics.snapshot()["histograms"]) == hists
+    code = trace_mod.span.__code__
+    assert not {"monotonic", "time", "_histogram"} & set(code.co_names)
+
+
+def test_hub_dark_turn_names_no_wait_clock():
+    """The dispatcher's wait is timed in its lit branch alone, and the
+    three hub stages are plain span() sites."""
+    loop = hub_engine.ReplicationHub._dispatch_loop.__code__
+    assert "_H_WAIT" not in loop.co_names
+    assert "span" in loop.co_names
+    wait = hub_engine.ReplicationHub._idle_wait_locked
+    assert _gated_on_obs(wait, "_H_WAIT")
+    consts = {c for code in _codes(loop) for c in code.co_consts
+              if isinstance(c, str)}
+    assert {"hub.compose", "hub.submit", "hub.distribute"} <= consts
+
+
+def test_edge_dark_twin_has_no_stage_site():
+    dark = EdgeLoop._dark_turn.__code__
+    assert "_annotation" not in dark.co_names and "span" not in dark.co_names
+    # the shared per-session turns open them in their lit branches only
+    for fn in (EdgeLoop._io_turn, EdgeLoop._sweep_one):
+        names = {c for code in _codes(fn.__code__) for c in code.co_consts
+                 if isinstance(c, str)}
+        assert names & {"edge.read", "edge.hub_drain", "edge.tx"}
+    assert trace_mod.annotation("edge.read").__class__ is \
+        trace_mod._span_factory
+
+
+def test_backend_opens_no_second_span_system_at_its_sites():
+    src = inspect.getsource(tpu_backend)
+    assert '"device.dispatch"' not in src and '"device.deliver"' not in src
+    assert "_trace_span" not in src
+    assert '"device.dispatch.batches"' in src      # the counter stays
+
+
+def test_obs_discipline_is_clean():
+    r = subprocess.run(
+        [sys.executable, "-m", "dat_replication_protocol_tpu.analysis"],
+        capture_output=True, text=True, cwd=REPO, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
