@@ -31,7 +31,9 @@ reference: README.md:73).
 
 from __future__ import annotations
 
+import collections
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -366,6 +368,40 @@ blake2b_packed_donated = _jit_site("ops.blake2b.packed_donated",
                                    blake2b_packed_donated)
 
 
+def split_words(words):
+    """Raw little-endian u32 message words ``(B, nblocks*32)`` (a row is
+    the item's zero-padded bytes viewed ``<u4``) -> ``(mh, ml)``, each
+    ``(B, nblocks, 16)``: u32 index 2k is 64-bit word k's low half,
+    2k+1 its high half.  Traced inside the one program per bucket, so
+    the de-interleave is device time, not a host pass.  Two stride-2
+    slices of the 2-D rows, not ``reshape(B, nb, 16, 2)[..., h]``: by
+    the v5e compiler's own cycle estimates the minor-dim-of-2 form costs
+    three times the copy work ahead of the kernel (PERF.md §6, PR 27)."""
+    B = words.shape[0]
+    return (words[:, 1::2].reshape(B, -1, 16),
+            words[:, 0::2].reshape(B, -1, 16))
+
+
+def _blake2b_words_impl(words, lengths, digest_size: int = DIGEST_SIZE):
+    """:func:`blake2b_packed` over the raw words :func:`stage_payloads`
+    lays out: the hi/lo split happens here, on the device."""
+    mh, ml = split_words(words)
+    return _blake2b_packed_impl(mh, ml, lengths, digest_size)
+
+
+blake2b_words = _jit_site(
+    "ops.blake2b.words",
+    functools.partial(jax.jit, static_argnames=("digest_size",))(
+        _blake2b_words_impl),
+)
+# donated twin: the staged words are consumed by exactly one dispatch
+blake2b_words_donated = _jit_site(
+    "ops.blake2b.words_donated",
+    functools.partial(jax.jit, static_argnames=("digest_size",),
+                      donate_argnums=(0,))(_blake2b_words_impl),
+)
+
+
 def donation_supported() -> bool:
     """Whether this backend honors buffer donation: the ONE owner of the
     donated-vs-plain dispatch decision (CPU jax silently ignores
@@ -527,29 +563,120 @@ class Blake2bStream:
 # ---------------------------------------------------------------------------
 
 
+# slot widths up to this are zeroed by ONE fill of the whole staging
+# buffer before the row copies; wider slots zero each row's tail (and the
+# batch-padding rows) instead, so a full 1 MiB slot is written once
+_FILL_WHOLE_MAX = 8192
+
+# the staging pool's bound: today's largest bucket (1,024 x 1 MiB) twice
+_STAGE_POOL_BYTES = 2 << 30
+
+_M_STAGE_REUSE = _counter("digest.stage.reuse")
+_M_STAGE_ALLOC = _counter("digest.stage.alloc")
+
+
+class _StagePool:
+    """Process-wide host staging buffers for :func:`blake2b_batch_begin`,
+    oldest first, bounded in bytes.
+
+    ``device_put`` returns before the transfer and jax may read the host
+    array until it completes, so a buffer is parked with a FENCE — an
+    output of the program that consumed it — and handed out again only
+    once that fence is observed ready.  ``take`` never waits: nothing
+    ready of that shape means a fresh ``np.empty``.  Shared by every
+    pipeline of the process (the ``plain`` sidecar runs one per
+    connection), hence the lock and the bound."""
+
+    def __init__(self, max_bytes: int):
+        self._max_bytes = max_bytes
+        self._lock = threading.Lock()
+        self._parked: collections.deque = collections.deque()
+        self._bytes = 0
+
+    def take(self, shape: tuple[int, int]) -> np.ndarray:
+        with self._lock:
+            for k, (buf, fence) in enumerate(self._parked):
+                if buf.shape == shape and fence.is_ready():
+                    del self._parked[k]
+                    self._bytes -= buf.nbytes
+                    break
+            else:
+                buf = None
+        if _OBS.on:
+            (_M_STAGE_ALLOC if buf is None else _M_STAGE_REUSE).inc()
+        return np.empty(shape, dtype=np.uint8) if buf is None else buf
+
+    def give(self, buf: np.ndarray, fence) -> None:
+        with self._lock:
+            self._parked.append((buf, fence))
+            self._bytes += buf.nbytes
+            while self._bytes > self._max_bytes:
+                self._bytes -= self._parked.popleft()[0].nbytes
+
+
+_STAGE_POOL = _StagePool(_STAGE_POOL_BYTES)
+
+
+def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
+    """Lay ``payloads`` into the rows of ``buf`` ((rows, nblocks*128)
+    uint8, contents arbitrary): one ``memcpy`` per payload, every byte
+    past it zeroed — the row's tail and the rows beyond ``len(payloads)``
+    (batch padding) — which is the zero-padding contract of
+    :func:`blake2b_packed`.  Returns the ``(rows,)`` uint32 lengths.
+
+    THE routine that puts payload bytes into staging rows:
+    :func:`blake2b_batch_begin` ships ``buf`` viewed ``<u4`` as it is
+    and the device splits the words (:func:`split_words`);
+    :func:`pack_payloads` splits the same rows in numpy.
+    """
+    rows, width = buf.shape
+    n_items = len(payloads)
+    lens = np.fromiter(map(len, payloads), dtype=np.int64, count=n_items)
+    longest = int(lens.max(initial=0))
+    if longest >= 1 << 31:
+        raise ValueError("per-item payload limit is < 2 GiB; chunk first")
+    if longest > width:
+        raise ValueError(
+            f"nblocks={width // BLOCK_BYTES} < required "
+            f"{-(-longest // BLOCK_BYTES)}")
+    lengths = np.zeros((rows,), dtype=np.uint32)
+    lengths[:n_items] = lens
+    flat = memoryview(buf.reshape(-1))
+    off = 0
+    if width <= _FILL_WHOLE_MAX:
+        buf.fill(0)
+        for p in payloads:
+            flat[off:off + len(p)] = p
+            off += width
+    else:
+        for i, p in enumerate(payloads):
+            n = len(p)
+            flat[off:off + n] = p
+            if n < width:
+                buf[i, n:] = 0
+            off += width
+        buf[n_items:] = 0
+    return lengths
+
+
 def pack_payloads(payloads, nblocks: int | None = None):
     """Pack byte strings into padded (B, nblocks, 16) hi/lo uint32 arrays.
 
     Little-endian 64-bit message words: u32-word index 2k is word k's low
     half, 2k+1 its high half.  Zero padding satisfies the blake2b_packed
-    contract.
+    contract.  Rows are laid by :func:`stage_payloads` and split here, on
+    the host, for the callers that feed ``(mh, ml, lengths)`` programs;
+    the served batch path (:func:`blake2b_batch_begin`) ships the unsplit
+    rows and splits on the device.
     """
     B = len(payloads)
-    max_len = max((len(p) for p in payloads), default=0)
-    need = max(1, -(-max_len // BLOCK_BYTES))
     if nblocks is None:
-        nblocks = need
-    elif nblocks < need:
-        raise ValueError(f"nblocks={nblocks} < required {need}")
-    buf = np.zeros((B, nblocks * BLOCK_BYTES), dtype=np.uint8)
-    lengths = np.empty((B,), dtype=np.uint32)
-    for i, p in enumerate(payloads):
-        if len(p) >= 1 << 31:
-            raise ValueError("per-item payload limit is < 2 GiB; chunk first")
-        buf[i, : len(p)] = np.frombuffer(p, dtype=np.uint8)
-        lengths[i] = len(p)
-    words = buf.view("<u4").reshape(B, nblocks, 32)
-    return words[:, :, 1::2].copy(), words[:, :, 0::2].copy(), lengths
+        longest = max(map(len, payloads), default=0)
+        nblocks = max(1, -(-longest // BLOCK_BYTES))
+    buf = np.empty((B, nblocks * BLOCK_BYTES), dtype=np.uint8)
+    lengths = stage_payloads(payloads, buf)
+    words = buf.view("<u4").reshape(B, nblocks, 16, 2)
+    return words[..., 1].copy(), words[..., 0].copy(), lengths
 
 
 def digests_to_bytes(hh, hl, digest_size: int = DIGEST_SIZE) -> list[bytes]:
@@ -572,7 +699,11 @@ def _bucket_nblocks(n: int) -> int:
 
 
 # below this bucket size the pallas kernel's pad-to-1024-items overhead
-# outweighs its throughput edge over the XLA-scan path
+# outweighs its throughput edge over the XLA-scan path.  The batch edge
+# compares the PADDED batch with it: a 257-511-item bucket pads to 512
+# rows either way, and sending it to the scan would compile a (512, nb)
+# scan program that no full batch ever warms (it did, inside
+# edgehub.feed's window, once the dispatcher outran its queue: PR 27)
 _PALLAS_MIN_ITEMS = 512
 
 
@@ -588,9 +719,16 @@ def blake2b_batch_begin(
 
     Items are grouped into power-of-two block-count buckets; each bucket
     is one padded XLA dispatch.  ``use_pallas=None`` selects, per bucket,
-    the Pallas kernel on TPU backends when the bucket is large enough to
-    amortize its 1024-item tile padding, and the portable XLA-scan path
-    otherwise.
+    the Pallas kernel on TPU backends when the bucket's padded batch is
+    large enough to amortize its 1024-item tile padding, and the portable
+    XLA-scan path otherwise.
+
+    A bucket is staged as ONE array of raw little-endian u32 message
+    words (:func:`stage_payloads`: one copy per item, no host-side
+    de-interleave) and one ``device_put``; the hi/lo word split happens
+    on the device, inside the bucket's one program
+    (:func:`split_words`).  Staging buffers come from a small
+    process-wide pool (:class:`_StagePool`).
     """
     on_tpu = jax.default_backend() == "tpu"
     donate = donation_supported()
@@ -600,20 +738,25 @@ def blake2b_batch_begin(
         buckets.setdefault(nb, []).append(i)
     handles = []
     for nb, idxs in buckets.items():
+        # pad the batch axis to a power of two as well: jit specializes
+        # per (B, nblocks), so unbucketed batch sizes recompile every
+        # distinct count (minutes each on the CPU scanned path).  Empty
+        # payloads are valid; their digests are dropped in collect().
+        Bp = _bucket_nblocks(len(idxs))
         pallas_bucket = (
             use_pallas
             if use_pallas is not None
-            else on_tpu and len(idxs) >= _PALLAS_MIN_ITEMS
+            else on_tpu and Bp >= _PALLAS_MIN_ITEMS
         )
         if pallas_bucket:
             if donate:
                 from .blake2b_pallas import (
-                    blake2b_packed_pallas_donated as packed_fn,
+                    blake2b_words_pallas_donated as words_fn,
                 )
             else:
-                from .blake2b_pallas import blake2b_packed_pallas as packed_fn
+                from .blake2b_pallas import blake2b_words_pallas as words_fn
         else:
-            packed_fn = blake2b_packed_donated if donate else blake2b_packed
+            words_fn = blake2b_words_donated if donate else blake2b_words
         if _OBS.on:
             # keyed per bucket: the engine choice is per block-count
             # bucket, and the change-only memo must not flap when a
@@ -621,35 +764,31 @@ def blake2b_batch_begin(
             _note_engine("blake2b.batch",
                          "pallas" if pallas_bucket else "xla-scan",
                          key=nb, items=len(idxs), nblocks=nb)
-        # pad the batch axis to a power of two as well: jit specializes
-        # per (B, nblocks), so unbucketed batch sizes recompile every
-        # distinct count (minutes each on the CPU scanned path).  Empty
-        # payloads are valid; their digests are dropped in collect().
-        batch = [payloads[i] for i in idxs]
-        Bp = _bucket_nblocks(len(batch))
         if _OBS.on:
             # the Pallas wrapper pads on to whole 1024-item tiles
             _BUCKETS.note("pallas" if pallas_bucket else "xla-scan", nb,
-                          len(batch),
+                          len(idxs),
                           -(-Bp // 1024) * 1024 if pallas_bucket else Bp)
+        # one copy per item into a (Bp, nb*128) byte buffer, shipped as
+        # raw <u4 words: the hi/lo split is the program's first step
         with span("digest.pack", items=len(idxs), nblocks=nb):
-            batch += [b""] * (Bp - len(batch))
-            mh, ml, lengths = pack_payloads(batch, nblocks=nb)
+            buf = _STAGE_POOL.take((Bp, nb * BLOCK_BYTES))
+            lengths = stage_payloads([payloads[i] for i in idxs], buf)
+            words = buf.view("<u4")
         if _OBS.on:
-            _M_H2D.inc(mh.nbytes + ml.nbytes + lengths.nbytes)
+            _M_H2D.inc(words.nbytes + lengths.nbytes)
         # stage explicitly (device_put returns immediately): the upload
         # streams while earlier batches compress, and — when donation is
-        # supported — the staged buffers are DONATED to the dispatch, so
-        # successive batches double-buffer through recycled staging HBM
-        # instead of growing the live set.  The span is the HOST's time
-        # inside the two calls, not the link's.
+        # supported — the staged words are DONATED to the dispatch, so
+        # successive batches recycle staging HBM instead of growing the
+        # live set.  The span is the HOST's time inside the call, not
+        # the link's.
         with span("digest.h2d", items=len(idxs), nblocks=nb):
-            mh_d = jax.device_put(mh)
-            ml_d = jax.device_put(ml)
+            words_d = jax.device_put(words)
         with span("digest.launch", items=len(idxs), nblocks=nb):
-            hh, hl = packed_fn(
-                mh_d, ml_d, jnp.asarray(lengths), digest_size
-            )
+            hh, hl = words_fn(words_d, jnp.asarray(lengths), digest_size)
+            # hh ready => the program ran => the transfer out of buf is over
+            _STAGE_POOL.give(buf, hh)
             handles.append((idxs, hh[: len(idxs)], hl[: len(idxs)]))
 
     def start_d2h() -> None:

@@ -37,7 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .blake2b import _IV_HI, _IV_LO, DIGEST_SIZE, compress_soa
+from .blake2b import _IV_HI, _IV_LO, DIGEST_SIZE, compress_soa, split_words
 from ..obs.device import jit_site as _jit_site
 from .u64 import U32
 
@@ -333,6 +333,12 @@ def to_native(mh, ml, lengths, block_items: int = 1024):
     Pads the batch up to a multiple of ``block_items`` (zero payloads are
     valid BLAKE2b inputs; the wrapper drops their digests).  Returns
     (mh_n, ml_n, lengths_n, B).
+
+    ``mh``/``ml`` arrive already split: by the host
+    (:func:`.blake2b.pack_payloads`) for :func:`blake2b_packed_pallas`'s
+    callers, or by :func:`.blake2b.split_words` earlier in the same
+    program for :func:`blake2b_words_pallas`, where the split and these
+    transposes are the program's layout copies ahead of the kernel.
     """
     B, nb, _ = mh.shape
     Bp = -(-B // block_items) * block_items
@@ -378,4 +384,30 @@ blake2b_packed_pallas_donated = functools.partial(
 )(blake2b_packed_pallas)
 blake2b_packed_pallas_donated = _jit_site(
     "ops.blake2b_pallas.packed_donated", blake2b_packed_pallas_donated
+)
+
+
+def blake2b_words_pallas(words, lengths, digest_size: int = DIGEST_SIZE,
+                         block_items: int = 1024, interpret: bool = False):
+    """:func:`blake2b_packed_pallas` over raw staged words: ``words`` is
+    ``(B, nblocks*32)`` uint32 as :func:`.blake2b.stage_payloads` lays
+    them, split hi/lo here — split, transposes and kernel are one
+    ``jit_blake2b_words_pallas`` program per ``(B, nblocks)`` bucket."""
+    mh, ml = split_words(words)
+    return blake2b_packed_pallas(mh, ml, lengths, digest_size, block_items,
+                                 interpret)
+
+
+_WORDS_STATIC = ("digest_size", "block_items", "interpret")
+# the donated twin first: it wraps the function, and the name is about
+# to be rebound to the plain twin's jit site
+blake2b_words_pallas_donated = _jit_site(
+    "ops.blake2b_pallas.words_donated",
+    functools.partial(jax.jit, static_argnames=_WORDS_STATIC,
+                      donate_argnums=(0,))(blake2b_words_pallas),
+)
+blake2b_words_pallas = _jit_site(
+    "ops.blake2b_pallas.words",
+    functools.partial(jax.jit, static_argnames=_WORDS_STATIC)(
+        blake2b_words_pallas),
 )

@@ -193,3 +193,45 @@ def test_g_interleave_byte_exact():
     assert digests_to_bytes(*from_native(hh, hl, B)) == [
         hashlib.blake2b(p, digest_size=32).digest() for p in payloads
     ]
+
+
+# -- the raw-words entry point: the hi/lo split inside the program ----------
+
+@pytest.mark.parametrize(
+    "lens, nblocks",
+    [([0, 1, 127, 128], 1), ([129, 255, 256, 0, 7], 2), ([512] * 3, 4)],
+    ids=["one-block", "two-blocks", "full-slots"],
+)
+def test_words_entry_point_splits_and_matches_hashlib(lens, nblocks):
+    import numpy as np
+
+    from dat_replication_protocol_tpu.ops.blake2b import stage_payloads
+    from dat_replication_protocol_tpu.ops.blake2b_pallas import (
+        blake2b_words_pallas,
+    )
+
+    payloads = [bytes((i + 3 * k) & 0xFF for i in range(n))
+                for k, n in enumerate(lens)]
+    buf = np.full((8, nblocks * 128), 0x5A, dtype=np.uint8)   # stale bytes
+    lengths = stage_payloads(payloads, buf)
+    hh, hl = blake2b_words_pallas(
+        jnp.asarray(buf.view("<u4")), jnp.asarray(lengths), interpret=True)
+    assert digests_to_bytes(hh, hl)[: len(payloads)] == [
+        hashlib.blake2b(p, digest_size=32).digest() for p in payloads
+    ]
+
+
+@pytest.mark.parametrize(
+    "twin", ["blake2b_words_pallas", "blake2b_words_pallas_donated"])
+def test_words_program_keeps_the_prefix_the_trace_reader_finds(twin):
+    """benchmarks/layer_metrics/blake2b_hbm_share.py sums the device time
+    of the programs named ``jit_blake2b*``: split, transposes and kernel
+    stay ONE such program."""
+    import jax
+
+    from dat_replication_protocol_tpu.ops import blake2b_pallas
+
+    lowered = getattr(blake2b_pallas, twin)._fn.lower(
+        jax.ShapeDtypeStruct((8, 32), jnp.uint32),
+        jax.ShapeDtypeStruct((8,), jnp.uint32), interpret=True)
+    assert "module @jit_blake2b_words_pallas" in lowered.as_text()

@@ -103,6 +103,35 @@ def test_one_batch_records_every_digest_stage_once(obs_enabled, monkeypatch):
         assert h["sum"] == pytest.approx(rec[n]["dur"])
 
 
+@pytest.mark.parametrize("rounds", [1, 3])
+def test_each_bucket_stages_once_and_ships_what_it_staged(obs_enabled,
+                                                          monkeypatch, rounds):
+    """ISSUE 27: a bucket is ONE staged array of raw words and one
+    ``device_put``.  Per bucket still one ``digest.pack`` / ``.h2d`` /
+    ``.launch``; ``device.h2d.bytes`` is the staged rows plus their
+    lengths; every staging buffer is counted as a reuse or an alloc."""
+    monkeypatch.setattr(blake2b_mod, "_STAGE_POOL",
+                        blake2b_mod._StagePool(1 << 20))
+    # three buckets a round: 1 block x 3 items -> rows padded to 4,
+    # 2 blocks x 2, 8 blocks x 1
+    payloads = [b"a", b"b" * 128, b"", b"c" * 129, b"d" * 256, b"e" * 1000]
+    staged = 4 * 1 * 128 + 2 * 2 * 128 + 1 * 8 * 128
+    lengths = 4 * (4 + 2 + 1)
+    for _ in range(rounds):
+        assert blake2b_mod.blake2b_batch(payloads) == _host_batch(payloads)
+    for n in DISPATCH_STAGES:
+        recs = SPANS.spans(n)
+        assert len(recs) == 3 * rounds, n
+        assert sorted(r["fields"]["nblocks"] for r in recs) == \
+            sorted([1, 2, 8] * rounds)
+        assert _hist(f"span.{n}.seconds")["count"] == 3 * rounds
+    got = _counters()
+    assert got["device.h2d.bytes"] == rounds * (staged + lengths)
+    # collected before the next round, so each later round reuses all three
+    assert got["digest.stage.alloc"] == 3
+    assert got.get("digest.stage.reuse", 0) == 3 * (rounds - 1)
+
+
 def test_batch_is_inherited_only_inside_a_span_that_carries_it(obs_enabled):
     with trace_mod.span("outer.stage", batch=7, items=2):
         with trace_mod.span("inner.stage", items=1):
