@@ -15,8 +15,8 @@ child process that owns the device, run one after another:
   hub     the same traffic split over 8 concurrent sessions against
           `--edge --hub`.
   second  the plain sidecar started again and sent a shorter session of
-          whole batches, so every shape repeats: the persistent compile
-          cache must hit.
+          whole batches, so every shape repeats: it writes no cache
+          entry, and hits whatever the first start wrote.
   ops     one child driving the public ops at BASELINE.json sizes:
           `runtime.content_address` (device route vs native host route
           vs hashlib), the `ops.merkle` diff of two 1M-leaf snapshots,
@@ -529,12 +529,23 @@ def stage_plain(seed: int, sizes: dict, dry: bool, second: bool = False,
              f"{BATCH_ITEMS}-item / 1 GiB cap on the pallas engine: "
              f"bucket pallas:{nb} = {row}")
         engines = {k.split(":")[0] for k in rep["blake2b_buckets"]}
-        need(stage, engines == {"pallas", "xla-scan"},
+        # on a chip every bucket, the small change buckets included,
+        # runs the Pallas program of its slot width: the scan is the
+        # CPU's engine
+        need(stage, engines == {"pallas"},
              f"engines that served buckets: {sorted(engines)}")
     if second and not dry:
-        need(stage, rep["cache"]["hits"] > 0 and rep["cache"]["misses"] == 0,
-             f"persistent cache on a repeated session: {rep['cache']}")
-        if first is not None:
+        # a repeated session writes no entry, and hits what the first
+        # start wrote — if it wrote any: the served programs are a few
+        # Pallas programs that may each compile under jax's floor for
+        # the persistent cache, and then a second start has nothing to
+        # read and nothing to save
+        wrote = first is not None and first["cache"]["misses"] > 0
+        need(stage, rep["cache"]["misses"] == 0
+             and (rep["cache"]["hits"] > 0 or not wrote),
+             f"persistent cache on a repeated session: {rep['cache']}"
+             f" (the first start's: {first and first['cache']})")
+        if wrote:
             need(stage, rep["setup_s"] < first["setup_s"],
                  f"second start set-up {rep['setup_s']}s, first "
                  f"{first['setup_s']}s")
@@ -595,7 +606,8 @@ def stage_hub(seed: int, sizes: dict, dry: bool, mesh: bool = False) -> dict:
             "dispatch_batches": batches,
             "dispatch_items": c.get("hub.dispatch.items", 0),
             # an observation for the next issue, not a claim: what the
-            # hub's linger composes against the 512-item pallas floor
+            # hub's linger composes (its row count is declared per slot
+            # width: ops/blake2b.batch_rows)
             "items_per_batch": round(
                 c.get("hub.dispatch.items", 0) / max(1, batches), 1),
         }

@@ -47,6 +47,12 @@ _M_D2H = _counter("device.d2h.bytes")
 # saturated pipeline; 0 means every upload waited for an idle device.
 _M_H2D_OVERLAP = _counter("device.h2d.overlap")
 
+# this layer's chunking (and ops.fused_cdc_hash_pallas's, which follows
+# it): a chunk under this many items goes to the XLA scan, and a Pallas
+# bucket's chunks are widened to it.  The served batch edge
+# (ops.blake2b.blake2b_batch_begin) has no such floor.
+PALLAS_MIN_CHUNK_ITEMS = 512
+
 
 def pack_ragged(buf: np.ndarray, offs: np.ndarray, lens: np.ndarray,
                 nblocks: int | None = None):
@@ -187,10 +193,10 @@ def hash_extents_device(buf: np.ndarray, offs, lens,
             # keep the bucket kernel-eligible even when that makes one
             # chunk larger than pipeline_bytes — the byte budget above
             # still bounds how many ride in flight
-            chunk_b = max(chunk_b, blake2b._PALLAS_MIN_ITEMS)
+            chunk_b = max(chunk_b, PALLAS_MIN_CHUNK_ITEMS)
         chunk_b = blake2b._bucket_nblocks(min(chunk_b, max(1, B)))
         donate = blake2b.donation_supported()
-        pallas_pick = use_pallas and chunk_b >= blake2b._PALLAS_MIN_ITEMS
+        pallas_pick = use_pallas and chunk_b >= PALLAS_MIN_CHUNK_ITEMS
         if pallas_pick:
             if donate:
                 from ..ops.blake2b_pallas import (

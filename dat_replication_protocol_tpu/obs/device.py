@@ -354,10 +354,12 @@ def note_engine(component: str, engine: str, key=None, **fields) -> None:
     the gate.
 
     ``key`` widens the change-only memo for decisions that are
-    legitimately per-shape: the blake2b batch edge picks its engine
-    per block-count BUCKET, and a payload mix straddling the pallas
-    item floor would otherwise flap pallas<->xla-scan on every
-    dispatch, churning the bounded event ring with noise."""
+    legitimately per-shape: the feed layer (``batch.feed``) picks its
+    engine per block-count BUCKET, and a payload mix straddling its
+    chunk floor would otherwise flap pallas<->xla-scan on every
+    dispatch, churning the bounded event ring with noise.  (The served
+    batch edge, ``ops.blake2b``, has one engine per backend and no
+    key.)"""
     memo = component if key is None else (component, key)
     with _engine_lock:
         if _engine_last.get(memo) == engine:
@@ -382,12 +384,14 @@ def reset_engine_notes() -> None:
 
 class BucketTable:
     """Process-global traffic of the blake2b batch edge per (engine,
-    block-count bucket): dispatches, real items, and items after
-    padding.  The engine is chosen per bucket (``pallas`` from 512
-    items on a TPU, ``xla-scan`` below), so this table is what says
-    which kernel a traffic mix actually reached and what its padding
-    cost — bounded cardinality (two engines x power-of-two block
-    counts).  Call sites guard with ``if _OBS.on:``."""
+    block-count bucket): dispatches, real items, and ``padded_items`` —
+    the DECLARED row count each dispatch was staged at
+    (``ops.blake2b.batch_rows``: one per slot width up to 4 KiB, a few
+    doubling ones for wider slots).  The engine is the backend's
+    (``pallas`` on a TPU, ``xla-scan`` elsewhere), so this table says
+    which kernel a traffic mix reached and what its padding cost —
+    bounded cardinality (two engines x power-of-two block counts).
+    Call sites guard with ``if _OBS.on:``."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()

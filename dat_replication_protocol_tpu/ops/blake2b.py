@@ -698,13 +698,38 @@ def _bucket_nblocks(n: int) -> int:
     return next_pow2(n)
 
 
-# below this bucket size the pallas kernel's pad-to-1024-items overhead
-# outweighs its throughput edge over the XLA-scan path.  The batch edge
-# compares the PADDED batch with it: a 257-511-item bucket pads to 512
-# rows either way, and sending it to the scan would compile a (512, nb)
-# scan program that no full batch ever warms (it did, inside
-# edgehub.feed's window, once the dispatcher outran its queue: PR 27)
-_PALLAS_MIN_ITEMS = 512
+# The batch edge's DECLARED row counts.  ``jit`` specialises a program
+# per ``(rows, nblocks)``, and a Pallas program costs seconds to trace and
+# lower even when the compile cache holds it, so the staging buffer's row
+# count comes from a small set that depends only on the slot's bytes
+# (``nblocks * 128``), never on the item count: its least member is the
+# largest power of two up to a full kernel tile whose staging stays within
+# ``_ROWS_FLOOR_BYTES``, but not under the kernel's smallest tile; the
+# others double up to the full tile.  A slot up to 4 KiB has ONE row count
+# (a change feed is one program whatever its sessions' sizes); a 1 MiB
+# slot has 32, 64 ... 1,024, so a lone blob stages 32 MiB, not a GiB.
+_ROWS_FULL = 1024  # one full kernel tile, and the pipelines' item cap
+MIN_TILE_ITEMS = 32  # the Pallas kernel's smallest (packed) row tile
+_ROWS_FLOOR_BYTES = 4 << 20
+
+
+def declared_rows(nblocks: int) -> tuple[int, ...]:
+    """The row counts a bucket of ``nblocks``-block slots is staged at."""
+    least = _ROWS_FULL
+    while (least > MIN_TILE_ITEMS
+           and least * nblocks * BLOCK_BYTES > _ROWS_FLOOR_BYTES):
+        least >>= 1
+    return tuple(least << k
+                 for k in range((_ROWS_FULL // least).bit_length()))
+
+
+def batch_rows(n_items: int, nblocks: int) -> int:
+    """The smallest declared row count that holds ``n_items``; past a
+    full tile (no pipeline submits that: the served paths cap a batch at
+    1,024 items) the next power of two, as whole tiles.  No bucket stages
+    more than ``max(4 MiB, 32 x slot, 4 x its payload bytes)``: a bucket's
+    items each fill over half their slot."""
+    return max(declared_rows(nblocks)[0], _bucket_nblocks(n_items))
 
 
 def blake2b_batch_begin(
@@ -718,10 +743,12 @@ def blake2b_batch_begin(
     split the async DigestPipeline uses to overlap parse and hash.
 
     Items are grouped into power-of-two block-count buckets; each bucket
-    is one padded XLA dispatch.  ``use_pallas=None`` selects, per bucket,
-    the Pallas kernel on TPU backends when the bucket's padded batch is
-    large enough to amortize its 1024-item tile padding, and the portable
-    XLA-scan path otherwise.
+    is one padded dispatch at a declared row count (:func:`batch_rows`),
+    so the programs a process builds are one per slot width for small
+    slots and a handful for wide ones, whatever the item counts.
+    ``use_pallas=None`` is the Pallas kernel on a TPU backend and the
+    portable XLA scan elsewhere, for every bucket alike: on a chip no
+    item count reaches the scan.
 
     A bucket is staged as ONE array of raw little-endian u32 message
     words (:func:`stage_payloads`: one copy per item, no host-side
@@ -730,45 +757,30 @@ def blake2b_batch_begin(
     (:func:`split_words`).  Staging buffers come from a small
     process-wide pool (:class:`_StagePool`).
     """
-    on_tpu = jax.default_backend() == "tpu"
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
     donate = donation_supported()
+    if use_pallas:
+        if donate:
+            from .blake2b_pallas import blake2b_words_pallas_donated as words_fn
+        else:
+            from .blake2b_pallas import blake2b_words_pallas as words_fn
+    else:
+        words_fn = blake2b_words_donated if donate else blake2b_words
+    engine = "pallas" if use_pallas else "xla-scan"
+    if _OBS.on:
+        _note_engine("blake2b.batch", engine, items=len(payloads))
     buckets: dict[int, list[int]] = {}
     for i, p in enumerate(payloads):
         nb = _bucket_nblocks(max(1, -(-len(p) // BLOCK_BYTES)))
         buckets.setdefault(nb, []).append(i)
     handles = []
     for nb, idxs in buckets.items():
-        # pad the batch axis to a power of two as well: jit specializes
-        # per (B, nblocks), so unbucketed batch sizes recompile every
-        # distinct count (minutes each on the CPU scanned path).  Empty
-        # payloads are valid; their digests are dropped in collect().
-        Bp = _bucket_nblocks(len(idxs))
-        pallas_bucket = (
-            use_pallas
-            if use_pallas is not None
-            else on_tpu and Bp >= _PALLAS_MIN_ITEMS
-        )
-        if pallas_bucket:
-            if donate:
-                from .blake2b_pallas import (
-                    blake2b_words_pallas_donated as words_fn,
-                )
-            else:
-                from .blake2b_pallas import blake2b_words_pallas as words_fn
-        else:
-            words_fn = blake2b_words_donated if donate else blake2b_words
+        # rows beyond the items are empty payloads: valid, and their
+        # digests are dropped in collect()
+        Bp = batch_rows(len(idxs), nb)
         if _OBS.on:
-            # keyed per bucket: the engine choice is per block-count
-            # bucket, and the change-only memo must not flap when a
-            # payload mix straddles the pallas item floor
-            _note_engine("blake2b.batch",
-                         "pallas" if pallas_bucket else "xla-scan",
-                         key=nb, items=len(idxs), nblocks=nb)
-        if _OBS.on:
-            # the Pallas wrapper pads on to whole 1024-item tiles
-            _BUCKETS.note("pallas" if pallas_bucket else "xla-scan", nb,
-                          len(idxs),
-                          -(-Bp // 1024) * 1024 if pallas_bucket else Bp)
+            _BUCKETS.note(engine, nb, len(idxs), Bp)
         # one copy per item into a (Bp, nb*128) byte buffer, shipped as
         # raw <u4 words: the hi/lo split is the program's first step
         with span("digest.pack", items=len(idxs), nblocks=nb):

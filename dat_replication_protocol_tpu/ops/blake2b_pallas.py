@@ -18,6 +18,11 @@ Layout (TPU-first):
 * Grid = (batch_tiles, nblocks): batch tiles are embarrassingly parallel;
   the block axis is sequential ("arbitrary") with the chaining state in
   VMEM scratch, initialized at block 0 and emitted at the last block.
+* A batch under one 1,024-item tile is ONE tile in a packed layout,
+  ``(nblocks, 16*S, L)`` with ``S x L`` items (``L`` = 128 lanes, or the
+  whole batch under 128): the word axis shares the sublane axis, so HBM
+  holds no padded sublanes and a few wide rows stay a few wide rows
+  (:func:`tile_items`, :func:`to_native`).
 * Per-item variable lengths use the same active/final masks as the scan
   version (:func:`.blake2b.blake2b_packed`) — no dynamic shapes.
 
@@ -37,13 +42,37 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .blake2b import _IV_HI, _IV_LO, DIGEST_SIZE, compress_soa, split_words
+from .blake2b import (
+    _IV_HI,
+    _IV_LO,
+    DIGEST_SIZE,
+    MIN_TILE_ITEMS,
+    compress_soa,
+    split_words,
+)
 from ..obs.device import jit_site as _jit_site
+from ..utils.num import next_pow2
 from .u64 import U32
 
 # batch items per kernel tile: 8 sublanes x BTL lanes
 _LANE = 128
 _SUBLANE = 8
+
+
+def tile_items(B: int, block_items: int = 1024) -> int:
+    """Rows one kernel tile holds for a batch of ``B``: ``block_items``
+    once the batch fills a tile, else the batch itself (a power of two,
+    at least ``MIN_TILE_ITEMS``) as ONE packed tile — what keeps a few
+    wide rows from being padded to 1,024 of them."""
+    if B >= block_items:
+        return block_items
+    return max(MIN_TILE_ITEMS, next_pow2(B))
+
+
+def _packed_shape(items: int) -> tuple[int, int]:
+    """(sublanes S, lanes L) of a packed single tile of ``items``."""
+    lanes = min(_LANE, items)
+    return items // lanes, lanes
 
 
 class _RefLanes:
@@ -113,20 +142,31 @@ class _RefWords:
     spilling a hot value.
     """
 
-    def __init__(self, mh_ref, ml_ref, k: int = 0):
+    def __init__(self, mh_ref, ml_ref, k: int = 0, packed: int = 0):
         self._mh = mh_ref
         self._ml = ml_ref
         self._k = k
+        self._packed = packed
 
     def __getitem__(self, w):
-        w = int(w)
-        return self._mh[self._k, w], self._ml[self._k, w]
+        return _word(self._mh, self._ml, self._k, int(w), self._packed)
+
+
+def _word(mh_ref, ml_ref, k: int, w: int, packed: int):
+    """Message word ``w`` of the step's block ``k`` as a (hi, lo) pair of
+    item tiles: ``ref[k, w]`` of a ``(bps, 16, 8, BTL)`` block, or — in a
+    packed single tile of ``packed`` sublanes — rows ``w*S .. w*S+S`` of
+    a ``(bps, 16*S, L)`` block."""
+    if not packed:
+        return mh_ref[k, w], ml_ref[k, w]
+    rows = pl.ds(w * packed, packed)
+    return mh_ref[k, rows, :], ml_ref[k, rows, :]
 
 
 def _kernel(*refs, digest_size: int, unroll: bool = True,
             msg_loads: bool = False, vmem_state: bool = False,
             state_loads: bool = False, blocks_per_step: int = 1,
-            g_interleave: bool = False):
+            g_interleave: bool = False, packed: int = 0):
     if vmem_state:
         (len_ref, mh_ref, ml_ref, outh_ref, outl_ref,
          sth_ref, stl_ref, vh_ref, vl_ref) = refs
@@ -165,9 +205,9 @@ def _kernel(*refs, digest_size: int, unroll: bool = True,
         t_lo = jnp.where(cap < lengths, cap, lengths)
 
         if msg_loads and unroll:
-            m = _RefWords(mh_ref, ml_ref)
+            m = _RefWords(mh_ref, ml_ref, packed=packed)
         else:
-            m = [(mh_ref[0, w], ml_ref[0, w]) for w in range(16)]
+            m = [_word(mh_ref, ml_ref, 0, w, packed) for w in range(16)]
         if state_loads and unroll:
             h = _RefState(sth_ref, stl_ref)
         else:
@@ -194,9 +234,9 @@ def _kernel(*refs, digest_size: int, unroll: bool = True,
             cap = (ju + U32(1)) << U32(7)
             t_lo = jnp.where(cap < lengths, cap, lengths)
             if msg_loads:
-                m = _RefWords(mh_ref, ml_ref, k)
+                m = _RefWords(mh_ref, ml_ref, k, packed)
             else:
-                m = [(mh_ref[k, w], ml_ref[k, w]) for w in range(16)]
+                m = [_word(mh_ref, ml_ref, k, w, packed) for w in range(16)]
             nh = compress_soa(h, m, t_lo, final, unroll=True, lanes=lanes,
                               g_interleave=g_interleave)
             h = [
@@ -236,19 +276,37 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
     as ``(hh, hl)``, each (8, 8, B/8): word-major, batch split like the
     input.
 
+    A batch under one tile arrives packed (:func:`to_native`):
+    ``mh``/``ml`` (nblocks, 16*S, L), ``lengths`` (S, L), and the digest
+    words come back (8, S, L); ``block_items`` is not consulted.
+
     ``blocks_per_step`` > 1 compresses that many consecutive message
     blocks per grid step with the chaining state held in registers
     between them (``nblocks`` must divide evenly); it prices Mosaic's
     per-grid-step overhead against register pressure.
     """
-    nb, _, s, bl = mh.shape
-    if s != _SUBLANE:
-        raise ValueError(f"batch must be split (8, B/8); got sublane {s}")
-    if block_items % (_SUBLANE * _LANE):
-        raise ValueError(f"block_items must be a multiple of {_SUBLANE * _LANE}")
-    btl = block_items // _SUBLANE
-    if bl % btl:
-        raise ValueError(f"B/8={bl} not a multiple of tile width {btl}")
+    packed = lengths.shape[0] if mh.ndim == 3 else 0
+    if packed:
+        # ONE tile: (S, L) items, the word axis folded into the sublanes
+        nb = mh.shape[0]
+        tile = lengths.shape
+        if mh.shape != (nb, 16 * tile[0], tile[1]):
+            raise ValueError(
+                f"packed words {mh.shape} do not hold lengths {tile}")
+        n_tiles = 1
+    else:
+        nb, _, s, bl = mh.shape
+        if s != _SUBLANE:
+            raise ValueError(
+                f"batch must be split (8, B/8); got sublane {s}")
+        if block_items % (_SUBLANE * _LANE):
+            raise ValueError(
+                f"block_items must be a multiple of {_SUBLANE * _LANE}")
+        btl = block_items // _SUBLANE
+        if bl % btl:
+            raise ValueError(f"B/8={bl} not a multiple of tile width {btl}")
+        tile = (_SUBLANE, btl)
+        n_tiles = bl // btl
     if blocks_per_step < 1 or nb % blocks_per_step:
         raise ValueError(
             f"blocks_per_step={blocks_per_step} must divide nblocks={nb}"
@@ -259,7 +317,7 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
         # benchmark identical code under two variant labels
         raise ValueError("state_loads has no effect with blocks_per_step > 1")
 
-    grid = (bl // btl, nb // blocks_per_step)
+    grid = (n_tiles, nb // blocks_per_step)
     # Mosaic gets the straight-line unrolled rounds; the interpreter (CPU
     # tests) gets the scanned rounds, whose 12x-smaller graph sidesteps
     # the CPU backend's pathological compile of the unrolled chain
@@ -275,43 +333,30 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
         _kernel, digest_size=digest_size, unroll=unroll,
         msg_loads=msg_loads, vmem_state=vmem_state,
         state_loads=state_loads, blocks_per_step=blocks_per_step,
-        g_interleave=g_interleave,
+        g_interleave=g_interleave, packed=packed,
     )
     bps = blocks_per_step
-    in_specs = [
-        pl.BlockSpec((_SUBLANE, btl), lambda i, j: (0, i)),
-        pl.BlockSpec((bps, 16, _SUBLANE, btl), lambda i, j: (j, 0, 0, i)),
-        pl.BlockSpec((bps, 16, _SUBLANE, btl), lambda i, j: (j, 0, 0, i)),
-    ]
+    if packed:
+        msg_spec = pl.BlockSpec((bps,) + mh.shape[1:], lambda i, j: (j, 0, 0))
+    else:
+        msg_spec = pl.BlockSpec((bps, 16) + tile, lambda i, j: (j, 0, 0, i))
+    in_specs = [pl.BlockSpec(tile, lambda i, j: (0, i)), msg_spec, msg_spec]
     inputs = [lengths, mh, ml]
     if not unroll:
         from .blake2b import _ROUND_SIGMA
 
         in_specs.append(pl.BlockSpec((12, 16), lambda i, j: (0, 0)))
         inputs.append(jnp.asarray(np.stack(_ROUND_SIGMA)))
+    out_shape = jax.ShapeDtypeStruct((8, tile[0], tile[1] * n_tiles),
+                                     jnp.uint32)
     outh, outl = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((8, _SUBLANE, btl), lambda i, j: (0, 0, i)),
-            pl.BlockSpec((8, _SUBLANE, btl), lambda i, j: (0, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((8, _SUBLANE, bl), jnp.uint32),
-            jax.ShapeDtypeStruct((8, _SUBLANE, bl), jnp.uint32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((8, _SUBLANE, btl), jnp.uint32),
-            pltpu.VMEM((8, _SUBLANE, btl), jnp.uint32),
-        ] + (
-            [
-                pltpu.VMEM((16, _SUBLANE, btl), jnp.uint32),
-                pltpu.VMEM((16, _SUBLANE, btl), jnp.uint32),
-            ]
-            if vmem_state
-            else []
-        ),
+        out_specs=[pl.BlockSpec((8,) + tile, lambda i, j: (0, 0, i))] * 2,
+        out_shape=[out_shape, out_shape],
+        scratch_shapes=[pltpu.VMEM((8,) + tile, jnp.uint32)] * 2
+        + ([pltpu.VMEM((16,) + tile, jnp.uint32)] * 2 if vmem_state else []),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -330,9 +375,11 @@ blake2b_native = _jit_site("ops.blake2b_pallas.native", blake2b_native)
 def to_native(mh, ml, lengths, block_items: int = 1024):
     """(B, nblocks, 16) padded-batch layout -> kernel-native layout.
 
-    Pads the batch up to a multiple of ``block_items`` (zero payloads are
-    valid BLAKE2b inputs; the wrapper drops their digests).  Returns
-    (mh_n, ml_n, lengths_n, B).
+    Pads the batch up to whole tiles of :func:`tile_items` rows (zero
+    payloads are valid BLAKE2b inputs; the wrapper drops their digests).
+    Returns (mh_n, ml_n, lengths_n, B): (nblocks, 16, 8, Bp/8) words and
+    (8, Bp/8) lengths for whole 1,024-item tiles, the packed
+    (nblocks, 16*S, L) and (S, L) for a batch under one.
 
     ``mh``/``ml`` arrive already split: by the host
     (:func:`.blake2b.pack_payloads`) for :func:`blake2b_packed_pallas`'s
@@ -341,15 +388,23 @@ def to_native(mh, ml, lengths, block_items: int = 1024):
     transposes are the program's layout copies ahead of the kernel.
     """
     B, nb, _ = mh.shape
-    Bp = -(-B // block_items) * block_items
+    tile = tile_items(B, block_items)
+    Bp = -(-B // tile) * tile
     if Bp != B:
         mh = jnp.pad(mh, ((0, Bp - B), (0, 0), (0, 0)))
         ml = jnp.pad(ml, ((0, Bp - B), (0, 0), (0, 0)))
         lengths = jnp.pad(lengths, (0, Bp - B))
-    mh_n = jnp.transpose(mh, (1, 2, 0)).reshape(nb, 16, _SUBLANE, Bp // _SUBLANE)
-    ml_n = jnp.transpose(ml, (1, 2, 0)).reshape(nb, 16, _SUBLANE, Bp // _SUBLANE)
-    len_n = lengths.reshape(_SUBLANE, Bp // _SUBLANE)
-    return mh_n, ml_n, len_n, B
+    if tile < block_items:
+        # ONE packed tile: item i sits at (i // L, i % L), and a word's S
+        # sublanes follow the word before it
+        s, lanes = _packed_shape(tile)
+        shape, len_shape = (nb, 16 * s, lanes), (s, lanes)
+    else:
+        shape = (nb, 16, _SUBLANE, Bp // _SUBLANE)
+        len_shape = shape[2:]
+    mh_n = jnp.transpose(mh, (1, 2, 0)).reshape(shape)
+    ml_n = jnp.transpose(ml, (1, 2, 0)).reshape(shape)
+    return mh_n, ml_n, lengths.reshape(len_shape), B
 
 
 def from_native(outh, outl, B: int):

@@ -324,7 +324,7 @@ def hash_cuts_device(words, cuts, nbytes: int, use_pallas: bool | None = None,
     by the batched BLAKE2b the backend routes to.  Returns ``(hh, hl)``
     device arrays, each (nchunks, 4) uint32, in cut order.
     """
-    from ..batch.feed import bucketed_extents
+    from ..batch.feed import PALLAS_MIN_CHUNK_ITEMS, bucketed_extents
     from . import blake2b
 
     ends = np.asarray(cuts, dtype=np.int64)
@@ -343,12 +343,12 @@ def hash_cuts_device(words, cuts, nbytes: int, use_pallas: bool | None = None,
         B = len(idx)
         chunk_b = max(1, pipeline_bytes // (nb * 128))
         if use_pallas:
-            chunk_b = max(chunk_b, blake2b._PALLAS_MIN_ITEMS)
+            chunk_b = max(chunk_b, PALLAS_MIN_CHUNK_ITEMS)
         chunk_b = blake2b._bucket_nblocks(min(chunk_b, max(1, B)))
         # donated dispatch, same routing as feed.hash_extents_device:
         # the device-packed mh/ml are consumed by exactly one program,
         # so their HBM recycles into the next chunk's pack
-        if use_pallas and chunk_b >= blake2b._PALLAS_MIN_ITEMS:
+        if use_pallas and chunk_b >= PALLAS_MIN_CHUNK_ITEMS:
             if donate:
                 from .blake2b_pallas import (
                     blake2b_packed_pallas_donated as fn,

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from dat_replication_protocol_tpu.backend.tpu_backend import DigestPipeline
+from dat_replication_protocol_tpu.obs.events import EVENTS
 from dat_replication_protocol_tpu.ops import blake2b as b2
 from dat_replication_protocol_tpu.ops import blake2b_pallas as b2p
 
@@ -121,31 +122,113 @@ def _interpreted_pallas(monkeypatch):
             b2p, name, functools.partial(getattr(b2p, name), interpret=True))
 
 
-@pytest.mark.parametrize("engine", ["xla-scan", "pallas", "straddle"])
+@pytest.mark.parametrize("engine", ["xla-scan", "pallas", "tpu-default",
+                                    "cpu-default"])
 def test_batch_begin_matches_hashlib_on_each_engine(engine, obs_enabled,
                                                     monkeypatch):
     rng = random.Random(27)
     few = [rng.randbytes(rng.choice([129, 200, 256])) for _ in range(3)]
-    if engine == "straddle":
-        # one call, both engines: a bucket whose padded batch reaches the
-        # 512-row floor goes to Pallas, the 3-item bucket beside it to
-        # the XLA scan
-        _interpreted_pallas(monkeypatch)
-        monkeypatch.setattr(b2.jax, "default_backend", lambda: "tpu")
-        monkeypatch.setenv("DAT_DONATE", "0")
+    if engine.endswith("-default"):
+        # use_pallas=None: ONE engine for the whole call, by backend —
+        # a 3-item bucket beside a 257-item one takes the same route
+        if engine == "tpu-default":
+            _interpreted_pallas(monkeypatch)
+            monkeypatch.setattr(b2.jax, "default_backend", lambda: "tpu")
+            monkeypatch.setenv("DAT_DONATE", "0")
         many = [rng.randbytes(rng.choice([0, 1, 64, 128]))
-                for _ in range(b2._PALLAS_MIN_ITEMS // 2 + 1)]
+                for _ in range(257)]
         payloads, use = many[:100] + few + many[100:], None
-        reached = {"pallas:1", "xla-scan:2"}
+        name = "pallas" if engine == "tpu-default" else "xla-scan"
     else:
         if engine == "pallas":
             _interpreted_pallas(monkeypatch)
         payloads = few + [b"", rng.randbytes(128), rng.randbytes(5)]
-        use = engine == "pallas"
-        reached = {f"{engine}:1", f"{engine}:2"}
+        use, name = engine == "pallas", engine
     assert b2.blake2b_batch(payloads, use_pallas=use) == \
         [host(p) for p in payloads]
-    assert set(b2._BUCKETS.snapshot()) == reached
+    rows = b2._BUCKETS.snapshot()
+    assert set(rows) == {f"{name}:1", f"{name}:2"}
+    # a slot this narrow has one declared row count, whatever the items
+    assert {r["padded_items"] for r in rows.values()} == {1024}
+    # and the call noted ONE engine, not one per bucket
+    assert [e["fields"]["engine"]
+            for e in EVENTS.events("device.engine.select")
+            if e["fields"]["component"] == "blake2b.batch"] == [name]
+
+
+def _slot_payloads(rng, nblocks, n_items):
+    """``n_items`` seeded payloads that all land in the ``nblocks``
+    bucket, the block-edge lengths first: for one block the empty
+    message, one byte, a whole block; for wider slots the first byte
+    past the half slot, a whole block past it, a block plus one, the
+    last byte short of the slot, the whole slot."""
+    lo = (nblocks // 2) * 128
+    edges = [0, 1, 127, 128] if nblocks == 1 else \
+        [lo + 1, lo + 128, lo + 129, nblocks * 128 - 1, nblocks * 128]
+    return [rng.randbytes(edges[i % len(edges)]) for i in range(n_items)]
+
+
+@pytest.mark.parametrize(
+    "nblocks,n_items,rows",
+    [(1, 4, 1024), (16, 1, 1024), (16, 1024, 1024),      # narrow: one count
+     (128, 1, 256), (128, 257, 512), (128, 513, 1024),   # 16 KiB slot
+     (1024, 1, 32), (1024, 33, 64)],                     # 128 KiB slot
+    ids=lambda v: str(v),
+)
+def test_each_declared_row_count_matches_hashlib_and_the_scan(
+        nblocks, n_items, rows, obs_enabled, monkeypatch):
+    assert rows in b2.declared_rows(nblocks)
+    _interpreted_pallas(monkeypatch)
+    payloads = _slot_payloads(random.Random(29 * nblocks + n_items),
+                              nblocks, n_items)
+    want = [host(p) for p in payloads]
+    assert b2.blake2b_batch(payloads, use_pallas=True) == want
+    assert b2.blake2b_batch(payloads, use_pallas=False) == want
+    assert b2._BUCKETS.snapshot() == {
+        f"{e}:{nblocks}": {"dispatches": 1, "items": n_items,
+                           "padded_items": rows}
+        for e in ("pallas", "xla-scan")}
+
+
+@pytest.mark.parametrize("engine", ["xla-scan", "pallas"])
+def test_every_item_count_of_a_narrow_slot_is_one_program(engine, obs_enabled,
+                                                          monkeypatch):
+    if engine == "pallas":
+        _interpreted_pallas(monkeypatch)
+    rng = random.Random(31)
+    traces = obs_enabled.REGISTRY.counter("device.jit.traces")
+    # a digest size no other test asks for: the jit cache is the
+    # process's, and a program an earlier test built would count nothing
+    size = 28 if engine == "pallas" else 24
+    for n in (1, 2, 3, 31, 32, 33, 511, 512, 513, 1024):
+        payloads = [rng.randbytes(rng.randrange(1025, 2049))
+                    for _ in range(n)]
+        assert b2.blake2b_batch(payloads, size, engine == "pallas") \
+            == [host(p, size) for p in payloads]
+    assert traces.value == 1
+    assert b2._BUCKETS.snapshot() == {f"{engine}:16": {
+        "dispatches": 10, "items": 2662, "padded_items": 10 * 1024}}
+
+
+@pytest.mark.parametrize("nblocks", [1 << k for k in range(17)])
+def test_staging_bound_holds_for_every_slot_width(nblocks):
+    """Slots of 128 B to 8 MiB: one row count up to 4 KiB, and no bucket
+    stages more than max(4 MiB, 32 x slot, 4 x its payload bytes)."""
+    slot = nblocks * 128
+    declared = b2.declared_rows(nblocks)
+    assert declared[-1] == 1024
+    assert all(b == 2 * a for a, b in zip(declared, declared[1:]))
+    assert (len(declared) == 1) == (slot <= 4096)
+    assert declared[0] == min(1024, max(32, (4 << 20) // slot))
+    least_payload = 0 if nblocks == 1 else slot // 2 + 1
+    for n in (1, 2, 3, 31, 32, 33, 63, 64, 65, 511, 512, 513, 1023, 1024):
+        rows = b2.batch_rows(n, nblocks)
+        assert rows in declared and rows >= n
+        assert rows == next(r for r in declared if r >= n)
+        assert rows * slot <= max(4 << 20, 32 * slot, 4 * n * least_payload)
+    # past a full tile: whole tiles, still under four times the payload
+    assert b2.batch_rows(1025, nblocks) == 2048
+    assert b2.batch_rows(5000, nblocks) == 8192
 
 
 class _Fence:

@@ -235,3 +235,53 @@ def test_words_program_keeps_the_prefix_the_trace_reader_finds(twin):
         jax.ShapeDtypeStruct((8, 32), jnp.uint32),
         jax.ShapeDtypeStruct((8,), jnp.uint32), interpret=True)
     assert "module @jit_blake2b_words_pallas" in lowered.as_text()
+
+
+# -- tiles under 1,024 items: one packed tile, no padding to a full one -----
+
+@pytest.mark.parametrize(
+    "items, tile, packed",
+    [(1, 32, (1, 32)), (32, 32, (1, 32)), (33, 64, (1, 64)),
+     (128, 128, (1, 128)), (129, 256, (2, 128)), (512, 512, (4, 128)),
+     (513, 1024, None), (1024, 1024, None), (5000, 1024, None)],
+    ids=lambda v: str(v),
+)
+def test_a_batch_under_one_tile_is_its_own_packed_tile(items, tile, packed):
+    from dat_replication_protocol_tpu.ops.blake2b_pallas import (
+        _packed_shape,
+        tile_items,
+        to_native,
+    )
+
+    assert tile_items(items) == tile
+    mh = jnp.zeros((items, 2, 16), jnp.uint32)
+    mh_n, ml_n, len_n, B = to_native(mh, mh, jnp.zeros((items,), jnp.uint32))
+    assert B == items and ml_n.shape == mh_n.shape
+    if packed is None:
+        rows = -(-items // 1024) * 1024
+        assert mh_n.shape == (2, 16, 8, rows // 8)
+        assert len_n.shape == (8, rows // 8)
+    else:
+        assert _packed_shape(tile) == packed == len_n.shape
+        assert mh_n.shape == (2, 16 * packed[0], packed[1])
+
+
+@pytest.mark.parametrize("items", [1, 32, 33, 100, 128, 200, 256, 300, 512])
+def test_each_packed_tile_matches_hashlib(items):
+    """Every tile width under 1,024 rows — lanes 32, 64, 128 and 1, 2, 4
+    sublanes — on lengths that end in every place a block can."""
+    edges = [0, 1, 127, 128, 129, 255, 256, 257, 383, 384]
+    payloads = [bytes((7 * i + k) & 0xFF for k in range(edges[i % 10]))
+                for i in range(items)]
+    assert _run(payloads, nblocks=4) == [
+        hashlib.blake2b(p, digest_size=32).digest() for p in payloads
+    ]
+
+
+def test_native_refuses_words_that_do_not_hold_their_lengths():
+    from dat_replication_protocol_tpu.ops.blake2b_pallas import blake2b_native
+
+    with pytest.raises(ValueError, match="do not hold lengths"):
+        blake2b_native(jnp.zeros((2, 16 * 2, 128), jnp.uint32),
+                       jnp.zeros((2, 16 * 2, 128), jnp.uint32),
+                       jnp.zeros((1, 128), jnp.uint32), interpret=True)

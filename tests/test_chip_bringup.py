@@ -238,7 +238,7 @@ def test_bucket_table_says_which_kernel_a_bucket_reached(obs_enabled):
     assert blake2b_batch(payloads) == [
         hashlib.blake2b(p, digest_size=32).digest() for p in payloads]
     assert obs_device.BUCKETS.snapshot() == {
-        "xla-scan:1": {"dispatches": 1, "items": 3, "padded_items": 4}}
+        "xla-scan:1": {"dispatches": 1, "items": 3, "padded_items": 1024}}
 
 
 def test_compile_events_reach_the_registry(obs_enabled):

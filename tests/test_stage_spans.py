@@ -111,12 +111,12 @@ def test_each_bucket_stages_once_and_ships_what_it_staged(obs_enabled,
     ``.launch``; ``device.h2d.bytes`` is the staged rows plus their
     lengths; every staging buffer is counted as a reuse or an alloc."""
     monkeypatch.setattr(blake2b_mod, "_STAGE_POOL",
-                        blake2b_mod._StagePool(1 << 20))
-    # three buckets a round: 1 block x 3 items -> rows padded to 4,
-    # 2 blocks x 2, 8 blocks x 1
+                        blake2b_mod._StagePool(4 << 20))
+    # three buckets a round: 1 block x 3 items, 2 blocks x 2, 8 blocks
+    # x 1 — each at its slot's one declared row count, 1,024
     payloads = [b"a", b"b" * 128, b"", b"c" * 129, b"d" * 256, b"e" * 1000]
-    staged = 4 * 1 * 128 + 2 * 2 * 128 + 1 * 8 * 128
-    lengths = 4 * (4 + 2 + 1)
+    staged = 1024 * (1 + 2 + 8) * 128
+    lengths = 4 * 1024 * 3
     for _ in range(rounds):
         assert blake2b_mod.blake2b_batch(payloads) == _host_batch(payloads)
     for n in DISPATCH_STAGES:
