@@ -31,8 +31,10 @@ from typing import Callable, Optional
 from ..obs.device import note_engine as _note_engine
 from ..obs.metrics import OBS as _OBS, counter as _counter, \
     histogram as _histogram
-from ..session.decoder import BlobReader, Decoder
+from ..session.decoder import BlobReader, Decoder, \
+    _M_DEC_BLOB_COPIED
 from ..session.encoder import Encoder
+from ..utils.payload import PayloadParts
 from ..utils.trace import span
 
 DIGEST_SIZE = 32  # BLAKE2b-256, dat's content-hash size
@@ -87,7 +89,21 @@ def fold_digest_tallies() -> None:
         _M_ENC_DIGESTS.inc(n)
 
 
-def _host_hash_batch(payloads: list[bytes]) -> list[bytes]:
+def _host_hash_parts(payload: PayloadParts) -> bytes:
+    h = hashlib.blake2b(digest_size=DIGEST_SIZE)
+    for part in payload.parts:
+        h.update(part)
+    return h.digest()
+
+
+def _host_hash_batch(payloads: list) -> list[bytes]:
+    if any(type(p) is PayloadParts for p in payloads):
+        # a payload held as its pieces is hashed piece by piece; the
+        # whole ones take the batch engines below
+        whole = iter(_host_hash_batch(
+            [p for p in payloads if type(p) is not PayloadParts]))
+        return [_host_hash_parts(p) if type(p) is PayloadParts
+                else next(whole) for p in payloads]
     if len(payloads) >= 64:
         # many-payload batches: the native thread-parallel C pass skips
         # the ~1us/call interpreter overhead that binds a hashlib loop
@@ -226,6 +242,9 @@ class DigestPipeline:
         # engines: ``hash_begin(payloads) -> collect()`` is the async
         # interface; a plain ``hash_batch`` callable (tests, custom
         # engines) is wrapped to compute eagerly at dispatch time
+        # a caller's own engine is handed ``bytes`` alone: submit_parts
+        # joins for it
+        self._joins_parts = hash_begin is not None or hash_batch is not None
         if hash_begin is None:
             if hash_batch is not None:
                 hash_begin = lambda ps: (lambda out=hash_batch(ps): out)  # noqa: E731
@@ -239,12 +258,22 @@ class DigestPipeline:
         # alone would admit e.g. 1024 x 8 MiB blobs in one batch
         self._max_batch_bytes = max_batch_bytes
         self._max_inflight = max(1, max_inflight)
-        # ordered queue of ("payload", bytes, cb) | ("stream", stream, cb):
-        # payload entries batch into one device dispatch; stream entries
-        # were already hashed incrementally (their bytes never queue here)
-        # and only finalize at delivery, preserving submit-order delivery
+        # ordered queue of (item, on_digest, tag): ``item`` is a queued
+        # payload's byte count, or a finished stream.  Payloads batch
+        # into one device dispatch and wait in ``_payloads``, in entry
+        # order, only until it: a batch in flight holds lengths, not
+        # bytes.  Stream entries were already hashed incrementally
+        # (their bytes never queue here) and only finalize at delivery,
+        # preserving submit-order delivery
         self._entries: list[tuple] = []
+        self._payloads: list = []
         self._pending_bytes = 0
+        # submit_parts' account of the slabs its views pin: the slab the
+        # last view came from, the bytes of it no submitted view covers,
+        # and how much of _pending_bytes is such spare, not payload
+        self._slab = None
+        self._slab_spare = 0
+        self._spare_bytes = 0
         # (entries, collect, batch ordinal, lit dispatch-start time)
         self._inflight: list[tuple] = []
         # lit: submit time of the oldest queued item (None: none yet)
@@ -260,13 +289,48 @@ class DigestPipeline:
         rates (a lambda per change was ~20% of the digest path)."""
         if _OBS.on and self._fill_t0 is None:
             self._fill_t0 = _monotonic()
-        self._entries.append(("payload", payload, on_digest, tag))
-        self._pending_bytes += len(payload)
+        n = len(payload)
+        self._payloads.append(payload)
+        self._entries.append((n, on_digest, tag))
+        self._pending_bytes += n
         if (
             len(self._entries) >= self._max_batch
             or self._pending_bytes >= self._max_batch_bytes
         ):
             self.dispatch()
+
+    def submit_parts(self, parts, on_digest: Callable[[bytes], None],
+                     tag=None) -> None:
+        """Queue one payload that arrived in pieces — ``bytes`` objects
+        or views of receive slabs, in order — without joining them: the
+        pack copies each piece into the item's staging row, and the
+        pieces are dropped there.
+
+        A view pins its whole slab, so the slabs are charged against
+        ``max_batch_bytes`` with the payloads: views arrive in slab
+        order, and when they move on to another slab, the bytes of the
+        last one that no submitted view covers (headers, other frames)
+        count as queued.  A queue then pins at most ``max_batch_bytes``
+        and the two slabs at its ends — the first, shared with the batch
+        before, and the one still being filled."""
+        if self._joins_parts:
+            whole = len(parts) == 1 and type(parts[0]) is bytes
+            self.submit(parts[0] if whole else b"".join(parts),
+                        on_digest, tag)
+            return
+        for part in parts:
+            if type(part) is memoryview:
+                if part.obj is not self._slab:
+                    self._pending_bytes += self._slab_spare
+                    self._spare_bytes += self._slab_spare
+                    self._slab = part.obj
+                    self._slab_spare = memoryview(part.obj).nbytes
+                self._slab_spare -= len(part)
+        if len(parts) == 1:
+            payload = parts[0]  # whole already: bytes, or one view
+        else:
+            payload = PayloadParts(parts) if parts else b""
+        self.submit(payload, on_digest, tag)
 
     def submit_stream(self, stream, on_digest: Callable[[bytes], None],
                       tag=None) -> None:
@@ -282,7 +346,7 @@ class DigestPipeline:
             _M_SUBMIT_BYTES.inc(int(getattr(stream, "length", 0)))
             if isinstance(stream, _HostStream):
                 _M_HOST_STREAM_BYTES.inc(stream.length)
-        self._entries.append(("stream", stream, on_digest, tag))
+        self._entries.append((stream, on_digest, tag))
         if len(self._entries) >= self._max_batch:
             self.dispatch()
 
@@ -316,15 +380,18 @@ class DigestPipeline:
         if not self._entries:
             return
         entries, self._entries = self._entries, []
-        pending = self._pending_bytes
-        self._pending_bytes = 0
+        payloads, self._payloads = self._payloads, []
+        pending = self._pending_bytes - self._spare_bytes
+        self._pending_bytes = self._spare_bytes = 0
         self.dispatches += 1
         batch = self.dispatches
         t0 = self._lit_dispatch(len(entries), pending) if _OBS.on else None
         with span("digest.dispatch", batch=batch, items=len(entries),
                   bytes=pending):
-            payloads = [e[1] for e in entries if e[0] == "payload"]
             collect = self._hash_begin(payloads) if payloads else (lambda: [])
+            # the engine has copied (or hashed) them: the batch in flight
+            # keeps lengths, and the slabs its parts were views of go
+            del payloads
         self._prefetch_inflight()  # older batches' D2H rides under this
         # batch's compute (idempotent per closure)
         self._inflight.append((entries, collect, batch, t0))
@@ -354,7 +421,7 @@ class DigestPipeline:
         entries, collect, batch, t0 = self._inflight.pop(0)
         with span("digest.collect", batch=batch, items=len(entries)):
             digest_list = collect()
-        payload_count = sum(1 for e in entries if e[0] == "payload")
+        payload_count = sum(1 for e in entries if type(e[0]) is int)
         if len(digest_list) != payload_count:
             raise RuntimeError(
                 f"hash backend returned {len(digest_list)} digests for "
@@ -362,9 +429,9 @@ class DigestPipeline:
             )
         with span("digest.deliver", batch=batch, items=len(entries)):
             digests = iter(digest_list)
-            for kind, item, cb, tag in entries:
-                if kind == "payload":
-                    self.hashed_bytes += len(item)
+            for item, cb, tag in entries:
+                if type(item) is int:
+                    self.hashed_bytes += item
                     d = bytes(next(digests))
                 else:
                     self.hashed_bytes += item.length
@@ -404,10 +471,16 @@ class TpuDecoder(Decoder):
                  stream_threshold: int = DEFAULT_STREAM_THRESHOLD, **kwargs):
         super().__init__(**kwargs)
         self._pipeline = pipeline if pipeline is not None else DigestPipeline()
+        # a pipeline that takes a payload in pieces copies each into the
+        # staging row: no join.  One that does not (the hub's session
+        # facade parks payloads against a byte budget, which a pinned
+        # slab would escape) gets ONE bytes per blob
+        self._submit_parts = getattr(self._pipeline, "submit_parts", None)
         self._digest_cbs: list[OnDigest] = []
         self._change_seq = 0
         self._blob_seq = 0
-        self._blob_parts: dict[int, list[bytes]] = {}
+        # an open blob's pieces, as _note_blob_bytes hands them over
+        self._blob_parts: dict[int, list] = {}
         # blobs at least this long hash incrementally (O(segment) memory,
         # no < 2 GiB cap) instead of joining chunks for the batch path
         self._stream_threshold = stream_threshold
@@ -523,10 +596,11 @@ class TpuDecoder(Decoder):
         self._blob_seq += 1
         super()._open_blob_if_ready()
 
-    def _note_blob_bytes(self, data: bytes) -> None:
-        # shares the decoder's already-materialized bytes object — the
-        # digest path holds references, not a second copy of the blob
-        # (round-2 verdict weak #5)
+    def _note_blob_bytes(self, data) -> None:
+        # shares what the decoder made of the piece — its bytes object
+        # where a blob handler reads one, else a view of the written
+        # memory: the digest path holds references, not a second copy
+        # of the blob (round-2 verdict weak #5)
         seq = self._blob_seq - 1
         if seq in self._blob_streams:
             self._blob_streams[seq].update(data)
@@ -540,8 +614,16 @@ class TpuDecoder(Decoder):
         if stream is not None:
             self._pipeline.submit_stream(stream, self._emit_blob_digest, seq)
         elif parts is not None:
-            self._pipeline.submit(b"".join(parts), self._emit_blob_digest,
-                                  seq)
+            if self._submit_parts is not None:
+                self._submit_parts(parts, self._emit_blob_digest, seq)
+            else:
+                if len(parts) == 1 and type(parts[0]) is bytes:
+                    payload = parts[0]
+                else:
+                    payload = b"".join(parts)
+                    if _OBS.on:
+                        _M_DEC_BLOB_COPIED.inc(len(payload))
+                self._pipeline.submit(payload, self._emit_blob_digest, seq)
         super()._end_blob()
 
     def _maybe_finalize(self) -> None:

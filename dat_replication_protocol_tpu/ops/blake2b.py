@@ -44,6 +44,7 @@ from ..obs.device import jit_site as _jit_site
 from ..obs.device import note_engine as _note_engine
 from ..obs.metrics import OBS as _OBS
 from ..obs.metrics import counter as _counter
+from ..utils.payload import PayloadParts
 from ..utils.trace import span
 from .u64 import U32, add64, add64_3, ror64
 
@@ -617,11 +618,20 @@ class _StagePool:
 _STAGE_POOL = _StagePool(_STAGE_POOL_BYTES)
 
 
+def _lay_parts(flat: memoryview, off: int, payload: PayloadParts) -> None:
+    for part in payload.parts:
+        end = off + len(part)
+        flat[off:end] = part
+        off = end
+
+
 def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
     """Lay ``payloads`` into the rows of ``buf`` ((rows, nblocks*128)
-    uint8, contents arbitrary): one ``memcpy`` per payload, every byte
-    past it zeroed — the row's tail and the rows beyond ``len(payloads)``
-    (batch padding) — which is the zero-padding contract of
+    uint8, contents arbitrary): one ``memcpy`` per payload — per piece
+    of one held as its pieces (:class:`..utils.payload.PayloadParts`:
+    the row is where they are joined) — and every byte past it zeroed:
+    the row's tail and the rows beyond ``len(payloads)`` (batch
+    padding), which is the zero-padding contract of
     :func:`blake2b_packed`.  Returns the ``(rows,)`` uint32 lengths.
 
     THE routine that puts payload bytes into staging rows:
@@ -646,12 +656,18 @@ def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
     if width <= _FILL_WHOLE_MAX:
         buf.fill(0)
         for p in payloads:
-            flat[off:off + len(p)] = p
+            if type(p) is PayloadParts:
+                _lay_parts(flat, off, p)
+            else:
+                flat[off:off + len(p)] = p
             off += width
     else:
         for i, p in enumerate(payloads):
             n = len(p)
-            flat[off:off + n] = p
+            if type(p) is PayloadParts:
+                _lay_parts(flat, off, p)
+            else:
+                flat[off:off + n] = p
             if n < width:
                 buf[i, n:] = 0
             off += width
@@ -813,8 +829,10 @@ def blake2b_batch_begin(
             hh.copy_to_host_async()
             hl.copy_to_host_async()
 
+    n_items = len(payloads)  # the closures below keep no payload alive
+
     def collect() -> list[bytes]:
-        out: list[bytes | None] = [None] * len(payloads)
+        out: list[bytes | None] = [None] * n_items
         for idxs, hh, hl in handles:
             if _OBS.on:
                 # two (B, 8) u32 halves fetched per bucket = 64 B/item

@@ -54,6 +54,10 @@ _M_DEC_BYTES = _counter("decoder.bytes")
 _M_DEC_CHANGES = _counter("decoder.changes")
 _M_DEC_BLOBS = _counter("decoder.blobs")
 _M_DEC_BLOB_BYTES = _counter("decoder.blob.bytes")
+# blob payload bytes that took a copy on the way to their consumer (a
+# registered blob handler's ``bytes``, a digest pipeline that wants one
+# ``bytes`` per blob); over decoder.blob.bytes: copies per blob byte
+_M_DEC_BLOB_COPIED = _counter("decoder.blob.copied.bytes")
 _M_DEC_REQUEUES = _counter("decoder.requeues")
 _M_DEC_ERRORS = _counter("decoder.errors")
 # columnar ChangeBatch frames dispatched (rows ride decoder.changes)
@@ -100,6 +104,9 @@ class BlobReader:
         self._end_cbs: list[Callable[[], None]] = []
         self._buffered: list[bytes] = []
         self._paused = False
+        # opened by the decoder's default handler: nobody reads a chunk,
+        # so the decoder materialises none (Decoder._blob_data)
+        self._unread = False
 
     def on_data(self, cb: Callable[[bytes], None]) -> "BlobReader":
         self._data_cb = cb
@@ -358,7 +365,15 @@ class Decoder:
         """Feed wire bytes. Returns True if fully consumed synchronously;
         False if parsing stalled on an outstanding ``done`` (the
         ``on_consumed`` callback then fires when the app drains —
-        reference: decode.js:124-133,168)."""
+        reference: decode.js:124-133,168).
+
+        The decoder may keep views of ``data`` past the call — unparsed
+        input in its cursors, and a blob's payload as views until the
+        blob's digest is dispatched (a digesting decoder with no blob
+        handler registered copies a blob's bytes only into the staging
+        row).  Hand it memory it may pin and nobody rewrites:
+        immutable ``bytes``, or a buffer that is not written again
+        (the pumps allocate a slab per receive for this)."""
         if self.destroyed:
             raise DecoderDestroyedError("write after destroy")
         if self.finished or self._end_queued:
@@ -1794,7 +1809,10 @@ class Decoder:
                 self._pending -= 1
                 self._resume()
 
-        handler = self._on_blob if self._on_blob is not None else _drain_blob
+        handler = self._on_blob
+        if handler is None:
+            handler = _drain_blob
+            blob._unread = True
         try:
             handler(blob, done)
         finally:
@@ -1813,17 +1831,27 @@ class Decoder:
         take = min(len(chunk), self._missing)
         self._parsed += take
         self._missing -= take
-        # materialize ONCE; bytes are immutable, so every consumer —
-        # the BlobReader and any _note_blob_bytes subscriber (digest
-        # buffering) — shares this object instead of re-copying the
-        # scratch memoryview
-        data = bytes(chunk[:take])
         rest = chunk[take:]
         if _OBS.on:
             _M_DEC_BLOB_BYTES.inc(take)
         try:
-            self._note_blob_bytes(data)
-            blob._deliver(data)
+            if blob._unread:
+                # no blob handler registered: no reader for a bytes
+                # object, so none is made — a _note_blob_bytes
+                # subscriber (digest buffering) gets a read-only view of
+                # the caller's memory, which write() may keep (API.md)
+                self._note_blob_bytes(chunk[:take].toreadonly())
+                blob.received += take
+            else:
+                # materialize ONCE; bytes are immutable, so the
+                # BlobReader and any _note_blob_bytes subscriber share
+                # this object instead of re-copying the scratch
+                # memoryview
+                data = bytes(chunk[:take])
+                if _OBS.on:
+                    _M_DEC_BLOB_COPIED.inc(take)
+                self._note_blob_bytes(data)
+                blob._deliver(data)
         except BaseException:
             self._requeue_tail(rest)  # reader raise: keep the tail
             raise
@@ -1837,9 +1865,11 @@ class Decoder:
                 self._end_blob()
         return rest
 
-    def _note_blob_bytes(self, data: bytes) -> None:
-        """Hook: called with each materialized blob payload piece (exactly
-        the bytes object delivered to the BlobReader).  Base: no-op."""
+    def _note_blob_bytes(self, data) -> None:
+        """Hook: called with each blob payload piece, in order — the
+        ``bytes`` object delivered to the BlobReader where a blob
+        handler is registered, else a read-only view of the written
+        memory.  Base: no-op."""
 
     def _end_blob(self) -> None:
         blob, self._current_blob = self._current_blob, None

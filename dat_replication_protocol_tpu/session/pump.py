@@ -234,9 +234,11 @@ def recv_pump(decoder: Decoder, fd: int,
                 _note_batch(nbytes, st.stats)
                 _lit_rx(decoder, nbytes)
             # zero-copy handoff: the decoder owns this slab's memory
-            # from here (its cursors may pin slices of it); the tap
-            # sees the same bytes as one read-only view
-            data = memoryview(buf)[:nbytes]
+            # from here (its cursors may pin slices of it, a digesting
+            # decoder a blob's until the pack); the tap sees the same
+            # bytes as one read-only view.  The view is OF the received
+            # prefix, so its ``.obj`` says how much a slice of it pins
+            data = memoryview(buf[:nbytes])
             if tap is not None:
                 # the broadcast tee (FanoutServer.publish): an append +
                 # O(1) mark under the server lock — never blocks
@@ -523,7 +525,7 @@ def recv_step(pump: EdgePump, decoder: Decoder, tap=None) -> tuple:
         if _OBS.on:
             _note_batch(nbytes, st.stats)
             _lit_rx(decoder, nbytes)
-        data = memoryview(buf)[:nbytes]
+        data = memoryview(buf[:nbytes])  # as recv_pump: the prefix's own
         if tap is not None:
             # the broadcast tee (FanoutServer.publish): an append +
             # O(1) mark under the server lock — never blocks the loop
