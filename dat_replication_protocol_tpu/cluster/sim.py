@@ -137,7 +137,7 @@ class ClusterSim:
             self._index[key] = i
         expected_node = ReplicaNode("expected", honest_records)
         self.expected_digest = expected_node.content_digest()
-        # divergence size in bytes (the bench's denominator): wire the
+        # divergence size in bytes: wire the
         # mesh MUST move for every replica to reach the union
         self.union_wire_bytes = len(expected_node.canonical_wire())
         self.divergence_bytes = sum(

@@ -1442,7 +1442,7 @@ int64_t dat_gear_candidates(const uint8_t* buf, int64_t n, int64_t avg_bits,
 // The 8-lane engine below is AVX-512F (native 64-bit rotates via
 // vprorq, double the lane width of the AVX2 engine); b2b_many_avx2 and
 // its callers are untouched — the incumbent two-pass route keeps its
-// tested engine, and the A/B in bench.py config 8 is route vs route.
+// tested engine.
 // ---------------------------------------------------------------------------
 
 namespace {

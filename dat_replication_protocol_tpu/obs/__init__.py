@@ -12,7 +12,7 @@ the layer must be importable and near-free in every process that
 touches the session stack, including the stripped CI image
 (PAPERS: *Simplicity Scales*).
 
-Four parts:
+The parts:
 
 * :mod:`.metrics` — Counters / Gauges / Histograms in a process-global
   registry behind ONE hoisted enable gate (``OBS.on``): the disabled
@@ -34,13 +34,7 @@ Four parts:
 * :mod:`.device` — the device boundary (ISSUE 5): the recompile
   sentinel (:func:`~.device.jit_site` wrappers counting traces vs
   cache hits per jit call-site, with a :class:`~.device.RecompileBudget`),
-  the backend-init watchdog (staged ``backend.init`` progress with a
-  deadline that dumps a flight bundle naming the stuck stage), device
-  memory gauges, and engine-selection attribution.
-* :mod:`.perf` — the perf-budget regression gate: compares a
-  ``bench.py --metrics`` artifact against checked-in per-metric
-  budgets (``artifacts/perf_budgets.json``); the CLI's ``perf-check``
-  exits nonzero on regression.
+  device memory gauges, and engine-selection attribution.
 * :mod:`.wirecost` — the wire cost plane (ISSUE 20): a per-link byte
   ledger attributing EVERY wire byte to a frame class (change,
   change_batch, blob, reconcile, snapshot, framing-overhead) at the
@@ -76,7 +70,6 @@ from __future__ import annotations
 
 from .device import (
     SENTINEL,
-    BackendInitWatchdog,
     JitSentinel,
     RecompileBudget,
     jit_site,
@@ -141,7 +134,6 @@ __all__ = [
     "SENTINEL",
     "JitSentinel",
     "RecompileBudget",
-    "BackendInitWatchdog",
     "jit_site",
     "note_engine",
     "sample_device_gauges",

@@ -6,7 +6,6 @@
     python -m dat_replication_protocol_tpu.obs loopdoctor LOG.jsonl|BUNDLE_DIR [--threshold S] [--json]
     python -m dat_replication_protocol_tpu.obs meshdoctor LOG... [--json]
     python -m dat_replication_protocol_tpu.obs costdoctor LOG... [--max-overhead R] [--json]
-    python -m dat_replication_protocol_tpu.obs perf-check BENCH.json [--budgets PATH] [--host-only]
     python -m dat_replication_protocol_tpu.obs fleet TARGET... [--check SLO.json | --watch]
 
 ``timeline`` merges N peers' JSONL event/span logs (written by
@@ -80,14 +79,6 @@ no class accounts for), ``overhead-anomaly`` (framing overhead past
 under ``--min-goodput``), or ``amplification-regression`` (a fan-out
 link's delivered/source ratio collapsing from its peak — peers not
 draining the published stream).  A clean lit log flags nothing.
-
-``perf-check`` is the perf-budget regression gate (ISSUE 5): it
-compares one bench artifact (the one JSON line ``bench.py`` prints)
-against the checked-in per-metric budgets
-(``artifacts/perf_budgets.json`` by default; see :mod:`.perf` for the
-file format) and exits 1 on any regression — the bench trajectory as
-an enforced contract instead of an unread JSON trail.  ``--host-only``
-evaluates only the host-group configs (CPU-safe, what tier-1 runs).
 """
 
 from __future__ import annotations
@@ -444,7 +435,7 @@ def cmd_dump(args) -> int:
         print(f"checkpoint: {ckpt}")
     extra = man.get("extra")
     if extra:
-        # e.g. the backend-init watchdog's stuck stage + stage timeline
+        # e.g. a recovered run's reconnect stats
         print(f"extra: {extra}")
     for plan in man.get("fault_plans", []):
         active = {k: v for k, v in plan.items()
@@ -1155,21 +1146,6 @@ def cmd_costdoctor(args) -> int:
     return 1 if report["flags"] else 0
 
 
-def cmd_perf_check(args) -> int:
-    from .perf import DEFAULT_BUDGETS_PATH, run_check
-
-    budgets = args.budgets
-    if budgets is None:
-        # repo-checkout default first (the file is checked in next to
-        # the package), falling back to CWD-relative
-        repo = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        cand = os.path.join(repo, DEFAULT_BUDGETS_PATH)
-        budgets = cand if os.path.exists(cand) else DEFAULT_BUDGETS_PATH
-    return run_check(args.snapshot, budgets_path=budgets,
-                     host_only=args.host_only)
-
-
 def cmd_fleet(args) -> int:
     from .fleet import FleetView, run_dashboard, run_fleet_check
 
@@ -1285,19 +1261,6 @@ def main(argv=None) -> int:
                     help="machine-readable output")
     cd.set_defaults(fn=cmd_costdoctor)
 
-    pc = sub.add_parser(
-        "perf-check",
-        help="compare a bench.py artifact against the checked-in "
-             "perf budgets; exit 1 on regression")
-    pc.add_argument("snapshot", help="bench artifact JSON (the one-line "
-                                     "object bench.py prints)")
-    pc.add_argument("--budgets", default=None, metavar="PATH",
-                    help="budget file (default: artifacts/perf_budgets.json "
-                         "next to the package, else CWD-relative)")
-    pc.add_argument("--host-only", action="store_true",
-                    help="evaluate only host-group configs (CPU-safe)")
-    pc.set_defaults(fn=cmd_perf_check)
-
     fl = sub.add_parser(
         "fleet",
         help="poll N replica targets (http:// endpoints and/or "
@@ -1309,8 +1272,7 @@ def main(argv=None) -> int:
                          "--stats-fd JSONL file")
     fl.add_argument("--check", metavar="SLO.json", default=None,
                     help="evaluate the fleet against a declarative SLO "
-                         "file and exit 1 on breach (the perf-check "
-                         "contract for fleet health; see "
+                         "file and exit 1 on breach (see "
                          "OBSERVABILITY.md for the schema)")
     fl.add_argument("--watch", action="store_true",
                     help="live TTY dashboard (plain ANSI, one screen "

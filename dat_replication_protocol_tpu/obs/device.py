@@ -1,16 +1,15 @@
-"""Device-path telemetry: recompile sentinel + backend-init watchdog.
+"""Device-path telemetry: recompile sentinel, device gauges, engines.
 
 PRs 3-4 made the *host* session datapath observable; the device path —
 Pallas kernels, the DigestPipeline, mesh programs — stayed dark: the
-round-5 bench artifact ends with an opaque ``"backend init hung
-(> 87s)"`` and the recompile hazards behind the round-2 ~2000x CDC
-regression (SURVEY.md §5) were guarded only by code comments.  This
-module extends the same zero-dependency ``obs`` discipline (hoisted
+recompile hazards behind the round-2 ~2000x CDC regression
+(SURVEY.md §5) were guarded only by code comments.  This module
+extends the same zero-dependency ``obs`` discipline (hoisted
 ``OBS.on`` gate, literal names, bounded rings) down to the device
 boundary.  JAX is never imported at module level — the session layer
 must stay importable (and hang-proof) in device-less processes.
 
-Three parts:
+Two parts:
 
 * **Recompile sentinel** — :func:`jit_site` wraps a jitted callable
   with a named call-site.  Per call (gate on) it detects whether the
@@ -24,13 +23,6 @@ Three parts:
   failure mode ``ops/blake2b.py`` buckets against (jit specializes per
   (B, nblocks); an unbucketed stream recompiles every distinct count,
   minutes each on the CPU scanned path).
-* **Backend-init watchdog** — :class:`BackendInitWatchdog` wraps
-  backend bring-up in a ``backend.init`` span with staged progress
-  events (``platform_probe`` -> ``first_device_call`` ->
-  ``first_compile``) and a deadline timer that, instead of today's
-  opaque multi-minute hang, emits ``backend.init.stuck`` naming the
-  stage it is stuck IN and dumps a flight-recorder bundle (when armed)
-  whose manifest carries the stage and elapsed seconds.
 * **Device gauges / engine attribution** — :func:`sample_device_gauges`
   snapshots live-buffer count and device bytes-in-use at phase
   boundaries (only when a backend is ALREADY initialized: the sampler
@@ -44,15 +36,12 @@ from __future__ import annotations
 
 import sys
 import threading
-import time
 from typing import Callable, Optional
 
 from .events import emit as _emit
 from .metrics import OBS as _OBS
 from .metrics import counter as _counter
 from .metrics import gauge as _gauge
-from . import flight as _flight
-from . import tracing as _tracing
 
 __all__ = [
     "SENTINEL",
@@ -60,7 +49,6 @@ __all__ = [
     "BucketTable",
     "JitSentinel",
     "RecompileBudget",
-    "BackendInitWatchdog",
     "jit_site",
     "note_engine",
     "sample_device_gauges",
@@ -372,8 +360,8 @@ def note_engine(component: str, engine: str, key=None, **fields) -> None:
 def reset_engine_notes() -> None:
     """Forget the change-only memo so the NEXT dispatch re-emits every
     component's ``device.engine.select``.  Capture boundaries call this
-    alongside clearing the event/span rings (bench's per-config trace
-    export, the test fixture) — a cleared ring with a warm memo would
+    alongside clearing the event/span rings (the test fixture) — a
+    cleared ring with a warm memo would
     silently drop engine attribution from every later capture."""
     with _engine_lock:
         _engine_last.clear()
@@ -428,9 +416,9 @@ BUCKETS = BucketTable()
 def sample_device_gauges() -> bool:
     """Update ``device.mem.live_buffers`` / ``device.mem.bytes_in_use``
     from an ALREADY-initialized jax backend; returns True when a sample
-    was taken.  Never initializes a backend itself: the sampler runs
-    inside the init watchdog, whose job is to attribute a slow first
-    init, not to cause it — an uninitialized process samples nothing."""
+    was taken.  Never initializes a backend itself: a sampler must not
+    be what causes a slow first init — an uninitialized process samples
+    nothing."""
     if not _OBS.on:
         return False
     if "jax" not in sys.modules:
@@ -500,134 +488,3 @@ def watch_compile_events() -> None:
     jax.monitoring.register_event_listener(_on_jax_event)
     jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
     _watching_compiles = True
-
-
-# -- backend-init watchdog ----------------------------------------------------
-
-# the canonical stage ladder (callers may add their own stages between;
-# the names below are what bench.py's probe and the docs use)
-INIT_STAGES = ("platform_probe", "first_device_call", "first_compile")
-
-
-class BackendInitWatchdog:
-    """Deadline + staged progress around backend bring-up.
-
-    Usage::
-
-        with BackendInitWatchdog(deadline_s=90) as wd:
-            wd.stage("platform_probe")
-            import jax; jax.config.update(...)
-            wd.stage("first_device_call")
-            jax.devices()
-            wd.stage("first_compile")
-            jax.jit(f)(x)
-
-    Each ``stage()`` emits ``backend.init.stage`` and samples the
-    device gauges.  If the deadline expires before ``__exit__``, the
-    timer thread emits ``backend.init.stuck`` naming the stage the init
-    is stuck IN and dumps a flight bundle (reason
-    ``backend-init-stuck``) whose manifest ``extra`` carries the stage,
-    elapsed seconds, and the full stage timeline — the answer the
-    round-5 ``"backend init hung (> 87s)"`` string never gave.  The
-    watchdog only OBSERVES: the wrapped init keeps running (callers
-    own their own timeouts/subprocesses)."""
-
-    def __init__(self, deadline_s: float = 90.0,
-                 on_timeout: Optional[Callable[["BackendInitWatchdog"], None]]
-                 = None):
-        if deadline_s <= 0:
-            raise ValueError("deadline must be positive")
-        self.deadline_s = deadline_s
-        self.fired = False
-        self.finished = False
-        self.stages: list[tuple[str, float]] = []  # (name, elapsed_s)
-        self._on_timeout = on_timeout
-        self._lock = threading.Lock()
-        self._t0 = 0.0
-        self._timer: Optional[threading.Timer] = None
-        self._span = None
-
-    @property
-    def current_stage(self) -> Optional[str]:
-        with self._lock:
-            return self.stages[-1][0] if self.stages else None
-
-    @property
-    def elapsed_s(self) -> float:
-        return time.monotonic() - self._t0
-
-    def __enter__(self) -> "BackendInitWatchdog":
-        self._t0 = time.monotonic()
-        self._span = _tracing.trace_span("backend.init",
-                                         deadline_s=self.deadline_s)
-        self._span.__enter__()
-        self._timer = threading.Timer(self.deadline_s, self._fire)
-        self._timer.daemon = True
-        self._timer.start()
-        return self
-
-    def stage(self, name: str) -> None:
-        """Enter a named init stage (names are literals at call sites —
-        same greppability contract as event names)."""
-        elapsed = self.elapsed_s
-        with self._lock:
-            self.stages.append((name, round(elapsed, 3)))
-        if _OBS.on:
-            _emit("backend.init.stage", stage=name,
-                  elapsed_s=round(elapsed, 3))
-        sample_device_gauges()
-
-    def _fire(self) -> None:
-        with self._lock:
-            if self.finished:
-                return
-            self.fired = True
-            stage = self.stages[-1][0] if self.stages else None
-            timeline = list(self.stages)
-        elapsed = round(self.elapsed_s, 3)
-        if _OBS.on:
-            _emit("backend.init.stuck", stage=stage, elapsed_s=elapsed,
-                  deadline_s=self.deadline_s)
-        # bundle FIRST: sampling gauges talks to the very backend that
-        # just proved itself wedged and can block this timer thread
-        # forever — the post-mortem must already be on disk by then
-        # (the registry in the bundle carries the gauges the last
-        # healthy stage() sampled).
-        _flight.dump(
-            "backend-init-stuck",
-            extra={"stage": stage, "elapsed_s": elapsed,
-                   "deadline_s": self.deadline_s,
-                   "stages": [{"stage": s, "at_s": at} for s, at in timeline]},
-        )
-        cb = self._on_timeout
-        if cb is not None:
-            try:
-                cb(self)
-            except Exception:
-                pass  # an observer callback must never break the init
-        # last, for the same reason the bundle came first: if the
-        # wedged backend hangs this sample, only the (daemon) timer
-        # thread is lost
-        sample_device_gauges()
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        with self._lock:
-            self.finished = True
-        if self._timer is not None:
-            self._timer.cancel()
-            # an init that completes RIGHT AT the deadline races a
-            # _fire already past its finished check — by then the init
-            # really did exceed the deadline, so the stuck record is
-            # earned; joining just makes the ordering deterministic
-            # (stuck/dump land before done, and self.fired is stable
-            # once this returns)
-            if self._timer.is_alive():
-                self._timer.join(timeout=2.0)
-        if _OBS.on:
-            _emit("backend.init.done", elapsed_s=round(self.elapsed_s, 3),
-                  stages=len(self.stages), stuck=self.fired,
-                  error=(exc_type.__name__ if exc_type else None))
-        sample_device_gauges()
-        if self._span is not None:
-            self._span.__exit__(exc_type, exc, tb)
-        return False

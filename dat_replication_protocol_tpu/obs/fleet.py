@@ -24,12 +24,12 @@ sections into per-link replication lag:
 A bounded history ring per link supports rate/burn computation (bytes
 drained per second, polls-until-caught-up).  Rendering is either a
 plain-ANSI one-screen TTY dashboard (:func:`render_dashboard`) or
-``--check slo.json``: declarative SLOs evaluated into the same
-row-shaped report ``perf-check`` emits, exit 1 on breach — CI gates on
-fleet health exactly like it gates on perf budgets.
+``--check slo.json``: declarative SLOs evaluated into a row-shaped
+report, one row per check, exit 1 on breach — what CI gates fleet
+health on.
 
 SLO file schema (JSON object; every key optional — an empty object
-passes vacuously is NOT allowed, same contract as perf budgets):
+passes vacuously is NOT allowed):
 
 ``max_lag_bytes`` / ``max_lag_seconds``
     per-link bounds at the final poll;
@@ -284,7 +284,7 @@ class FleetTarget:
     path to a ``--stats-fd`` JSONL file (the last complete snapshot
     line is used; ``emit_seq`` gaps are counted as dropped lines), or a
     zero-argument callable returning the snapshot dict (in-process
-    fleets: tests, bench legs)."""
+    fleets: tests)."""
 
     def __init__(self, spec, name: Optional[str] = None,
                  timeout: float = DEFAULT_TIMEOUT):
@@ -577,7 +577,7 @@ def load_slo(path: str) -> dict:
     """Parse + validate an SLO file.  Malformed input (not an object,
     unknown keys, non-numeric bounds, or NO evaluable keys) raises
     ``ValueError`` — a gate that silently evaluates nothing is not a
-    gate (the perf-budget precedent)."""
+    gate."""
     with open(path, encoding="utf-8") as f:
         slo = json.load(f)
     if not isinstance(slo, dict):
@@ -720,9 +720,9 @@ def _evaluate_mesh_slo(g: dict, mesh: dict, row) -> None:
 
 
 def evaluate_slo(slo: dict, sample: dict) -> list[dict]:
-    """One fleet sample against one SLO: verdict rows in the
-    ``perf-check`` shape (``{"check", "subject", "status", "detail"}``;
-    callers gate on ``any(r["status"] == "fail")``)."""
+    """One fleet sample against one SLO: verdict rows
+    ``{"check", "subject", "status", "detail"}`` (callers gate on
+    ``any(r["status"] == "fail")``)."""
     rows: list[dict] = []
 
     def row(check: str, subject: str, ok: bool, detail: str) -> None:
@@ -917,9 +917,9 @@ def evaluate_slo(slo: dict, sample: dict) -> list[dict]:
 def run_fleet_check(targets, slo_path: str, polls: int = 3,
                     interval: float = 0.5, out=None) -> int:
     """The CI gate: poll, evaluate the FINAL sample, report one line
-    per check, exit 1 on breach (the ``perf-check`` contract for fleet
-    health).  A malformed SLO is itself a failure row — a gate must
-    fail loudly, never pass on an unreadable contract."""
+    per check, exit 1 on breach.  A malformed SLO is itself a failure
+    row — a gate must fail loudly, never pass on an unreadable
+    contract."""
     out = out if out is not None else sys.stdout
     try:
         slo = load_slo(slo_path)

@@ -17,9 +17,9 @@ the liveness route):
   caller's ``snapshot_fn`` carries them — the sidecar passes its
   ``snapshot_stats``, so the endpoint and ``--stats-fd`` serve the
   SAME dict);
-* ``GET /healthz``  — staged health: backend-init watchdog state (from
-  the event ring), admission open/closed (a LOCK-FREE callable the
-  owner installs — see ``ReplicationHub.admission_state``), whether
+* ``GET /healthz``  — staged health: admission open/closed (a
+  LOCK-FREE callable the owner installs — see
+  ``ReplicationHub.admission_state``), event-loop lag, whether
   the flight recorder is armed and the obs gate is on.  HTTP 200 when
   every stage is healthy, 503 otherwise — load-balancer compatible.
   The handler must never take a device or hub lock: a wedged engine
@@ -56,7 +56,7 @@ _MAX_EVENTS_TAIL = 4096
 
 def default_snapshot() -> dict:
     """The core stats record for processes that are not the sidecar
-    (bench legs, embedded fleets): registry + device sentinel +
+    (embedded fleets, tests): registry + device sentinel +
     watermarks + ring health.  The sidecar passes its richer
     ``snapshot_stats`` (same shape plus hub/fanout breakdowns)."""
     return {
@@ -76,32 +76,13 @@ def default_healthz(admission_fn: Optional[Callable[[], dict]] = None
     that is currently degraded, not a single opaque boolean).
 
     Lock discipline: everything read here is either a plain attribute
-    (``OBS.on``, ``FLIGHT.armed``), the event ring (its own ring lock,
-    never a device or hub lock), or ``admission_fn`` — which owners
+    (``OBS.on``, ``FLIGHT.armed``) or ``admission_fn`` — which owners
     must implement lock-free (``ReplicationHub.admission_state`` is
     the reference).  The datlint obs-discipline healthz check enforces
     the no-device/hub-lock half mechanically on this module."""
     stages: dict = {}
     ok = True
-    # stage 1: backend init — stuck beats done beats in-progress
-    stuck = _EVENTS.last("backend.init.stuck")
-    done = _EVENTS.last("backend.init.done")
-    stage = _EVENTS.last("backend.init.stage")
-    if stuck is not None and (done is None
-                              or stuck["seq"] > done["seq"]):
-        stages["backend_init"] = {"ok": False, "state": "stuck",
-                                  **stuck.get("fields", {})}
-        ok = False
-    elif done is not None:
-        stages["backend_init"] = {"ok": True, "state": "done",
-                                  **done.get("fields", {})}
-    elif stage is not None:
-        stages["backend_init"] = {"ok": True, "state": "in-progress",
-                                  **stage.get("fields", {})}
-    else:
-        # no watchdog ran: host-only process, nothing to report
-        stages["backend_init"] = {"ok": True, "state": "idle"}
-    # stage 2: admission (hub/fanout owners install the callable)
+    # stage 1: admission (hub/fanout owners install the callable)
     if admission_fn is not None:
         try:
             # the admission_state contract (datlint healthz check):
@@ -113,7 +94,7 @@ def default_healthz(admission_fn: Optional[Callable[[], dict]] = None
             adm = {"open": False, "error": f"{type(e).__name__}: {e}"}
         stages["admission"] = {"ok": bool(adm.get("open")), **adm}
         ok = ok and bool(adm.get("open"))
-    # stage 3: event-loop lag (ISSUE 18) — a loop that has fallen
+    # stage 2: event-loop lag (ISSUE 18) — a loop that has fallen
     # behind its tick is degraded the same way a closed admission gate
     # is: the flight deck's live lag view, plain attribute reads off
     # each loop's profiler (lock-free, at worst one turn stale).  Dark
@@ -129,7 +110,7 @@ def default_healthz(admission_fn: Optional[Callable[[], dict]] = None
         stages["loop_lag"] = {"ok": not behind, "behind": behind,
                               "lag_s": lag}
         ok = ok and not behind
-    # stage 4: observability itself (armed recorder, live gate)
+    # stage 3: observability itself (armed recorder, live gate)
     stages["flight_recorder"] = {"ok": True, "armed": _FLIGHT.armed}
     stages["obs_gate"] = {"ok": True, "on": _OBS.on}
     return {"ok": ok, "stages": stages, "ts": time.time(),

@@ -73,8 +73,8 @@ PHASES = ("poll-wait", "accept", "read", "hub-drain", "tx",
 SAMPLE_EVERY = 32
 TOP_K = 3
 
-# per-loop work ring for the local p99 (bench config 15 reads it
-# without sharing the process-global histogram across runs)
+# per-loop work ring for the local p99 (a loop's own, not the
+# process-global histogram's)
 _WORK_RING = 512
 
 # a loop is "behind its tick" for /healthz once its live lag exceeds
@@ -318,8 +318,8 @@ class LoopProfiler:
         }
 
     def state(self) -> dict:
-        """Loop-local summary for ``EdgeLoop.snapshot()`` and bench
-        config 15 (per-loop p99 without the process-global ring)."""
+        """Loop-local summary for ``EdgeLoop.snapshot()`` (per-loop p99
+        without the process-global ring)."""
         return {
             "name": self.name,
             "turns": self.turns,

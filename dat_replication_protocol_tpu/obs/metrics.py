@@ -20,8 +20,8 @@ Design constraints (ISSUE 3 acceptance, OBSERVABILITY.md):
   aggressively multi-threaded (pumps, ack threads, the sidecar).  The
   overhead budget test bounds only the disabled path.
 * **Snapshots are plain dicts** (JSON-able as-is): the sidecar's
-  ``--stats-fd`` dumps, ``bench.py --metrics`` attribution, and the
-  conformance oracle all consume the same shape.
+  ``--stats-fd`` dumps and the conformance oracle consume the same
+  shape.
 
 Histograms keep BOTH fixed-bucket counts (cheap, mergeable) and a
 fixed-size ring of recent observations (wraparound overwrite) so
@@ -373,11 +373,10 @@ class Registry:
 
     def reset(self) -> None:
         """Zero every metric's VALUE, keeping registrations (and the
-        handles instrumentation sites hoisted) intact — per-test and
-        per-bench-config isolation.  Collectors ARE dropped: they hold
-        references into live owner state (a hub), and a collector
-        surviving its test/config would leak that state into the next
-        snapshot."""
+        handles instrumentation sites hoisted) intact — per-test
+        isolation.  Collectors ARE dropped: they hold references into
+        live owner state (a hub), and a collector surviving its test
+        would leak that state into the next snapshot."""
         with self._lock:
             metrics = list(self._metrics.values())
             self._collectors.clear()

@@ -185,8 +185,7 @@ class PropagationBoard:
     def exchange_p99(self) -> Optional[float]:
         """p99 exchange wall seconds over the recent window (None
         before the first completed exchange) — the fleet SLO's
-        ``max_exchange_p99_s`` input and bench 14's ``exchange_p99_s``
-        field."""
+        ``max_exchange_p99_s`` input."""
         return self._quantile(0.99)
 
     def _quantile(self, q: float) -> Optional[float]:

@@ -185,7 +185,7 @@ class WatermarkBoard:
             if marks_from is not None:
                 _check_label("link", marks_from)
                 entry.marks_from = marks_from
-        # idempotent re-registration: Registry.reset() (test/bench
+        # idempotent re-registration: Registry.reset() (test
         # isolation) drops collectors on purpose; the next track() must
         # bring the watermark plane back instead of staying dark
         _REGISTRY.register_collector("watermarks", self._collector_fn)

@@ -139,68 +139,6 @@ def _rounds_unrolled(v, m):
     return v
 
 
-def _g_stage4(v, quads, ms):
-    """Four independent G mixes emitted stage-by-stage in lockstep.
-
-    Semantically identical to calling :func:`_g` on each quad in turn
-    (the 4 column Gs touch disjoint lanes, as do the 4 diagonal Gs); the
-    only difference is SSA emission order — each of the 8 G stages is
-    issued for all four quads before the next stage, so ~4 independent
-    ops sit between every dependent pair in the instruction stream.  A
-    scheduling experiment: a perfect scheduler would make this a no-op.
-    """
-    regs = [[v[a], v[b], v[c], v[d]] for (a, b, c, d) in quads]
-
-    def stage_add3(operand):
-        # a = a + b + m: destination lane 0, addend lane 1 — both fixed
-        # by the G function's shape (advisor r4: a parameterized dst
-        # with a hardcoded addend invited miscalls)
-        for k in range(4):
-            (ah, al) = regs[k][0]
-            (bh, bl) = regs[k][1]
-            (xh, xl) = operand[k]
-            regs[k][0] = add64_3(ah, al, bh, bl, xh, xl)
-
-    def stage_xor_ror(dst, src, r):
-        for k in range(4):
-            (dh, dl) = regs[k][dst]
-            (sh, sl) = regs[k][src]
-            regs[k][dst] = ror64(dh ^ sh, dl ^ sl, r)
-
-    def stage_add(dst, src):
-        for k in range(4):
-            (ch, cl) = regs[k][dst]
-            (dh, dl) = regs[k][src]
-            regs[k][dst] = add64(ch, cl, dh, dl)
-
-    xs = [p[0] for p in ms]
-    ys = [p[1] for p in ms]
-    stage_add3(xs)
-    stage_xor_ror(3, 0, 32)
-    stage_add(2, 3)
-    stage_xor_ror(1, 2, 24)
-    stage_add3(ys)
-    stage_xor_ror(3, 0, 16)
-    stage_add(2, 3)
-    stage_xor_ror(1, 2, 63)
-    for k, (a, b, c, d) in enumerate(quads):
-        v[a], v[b], v[c], v[d] = regs[k]
-
-
-def _rounds_unrolled_interleaved(v, m):
-    """The 12 rounds with columns/diagonals emitted in 4-way lockstep."""
-    for sigma in _ROUND_SIGMA:
-        _g_stage4(
-            v, _G_LANES[:4],
-            [(m[sigma[2 * gi]], m[sigma[2 * gi + 1]]) for gi in range(4)],
-        )
-        _g_stage4(
-            v, _G_LANES[4:],
-            [(m[sigma[2 * gi]], m[sigma[2 * gi + 1]]) for gi in range(4, 8)],
-        )
-    return v
-
-
 def _rounds_scanned(v, m, sigma=None):
     """The 12 rounds as a lax.scan with runtime sigma gathers.
 
@@ -234,7 +172,7 @@ def _rounds_scanned(v, m, sigma=None):
 
 
 def compress_soa(h, m, t_lo, is_final, unroll: bool | None = None, sigma=None,
-                 t_hi=None, lanes=None, g_interleave: bool = False):
+                 t_hi=None):
     """One BLAKE2b compression in SoA layout.
 
     ``h``: list of 8 (hi, lo) pairs of (B,) uint32 vectors; ``m``: list of
@@ -246,19 +184,11 @@ def compress_soa(h, m, t_lo, is_final, unroll: bool | None = None, sigma=None,
     ``unroll=None`` picks per backend: unrolled rounds on accelerators,
     scanned rounds on CPU (see the two round helpers).  Both are
     byte-exact RFC 7693.
-
-    ``lanes``: optional mutable container for the 16 working-vector
-    lanes (indexable get/set of (hi, lo) pairs — e.g. the Pallas
-    kernel's VMEM-scratch view).  The compression schedule then runs
-    against that storage instead of Python-list registers; unrolled
-    rounds only (the scanned form stacks arrays).
     """
     if unroll is None:
         unroll = jax.default_backend() != "cpu"
-    if lanes is not None and not unroll:
-        raise ValueError("a lanes container requires unrolled rounds")
     shape = t_lo.shape  # any batch shape: (B,) under scan, (8, B/8) in pallas
-    v = lanes if lanes is not None else [None] * 16
+    v = [None] * 16
     for i in range(8):
         v[i] = h[i]
         v[8 + i] = (
@@ -271,8 +201,7 @@ def compress_soa(h, m, t_lo, is_final, unroll: bool | None = None, sigma=None,
     v[14] = (v[14][0] ^ f, v[14][1] ^ f)
 
     if unroll:
-        rounds = _rounds_unrolled_interleaved if g_interleave else _rounds_unrolled
-        v = rounds(v, m)
+        v = _rounds_unrolled(v, m)
     else:
         v = _rounds_scanned(v, m, sigma)
 

@@ -75,60 +75,6 @@ def _packed_shape(items: int) -> tuple[int, int]:
     return items // lanes, lanes
 
 
-class _RefLanes:
-    """Working-vector lanes resident in VMEM scratch, one load/store per
-    G access.
-
-    The 16 v-lanes (32 hi/lo u32 tiles) are the kernel's register
-    working set; together with message words they overflow the vector
-    register file (measured: doubling the tile width halves
-    throughput).  This view lets the unrolled rounds run unchanged
-    (``_g`` mutates ``v`` by Python indexing) while each lane's live
-    range shrinks to the G mixes that touch it — the scheduler chooses
-    VMEM traffic instead of spills.  Correctness relies on Pallas's
-    sequential in-kernel semantics: a G's stores are visible to the
-    next G's loads.
-    """
-
-    def __init__(self, vh_ref, vl_ref):
-        self._vh = vh_ref
-        self._vl = vl_ref
-
-    def __getitem__(self, i):
-        i = int(i)
-        return self._vh[i], self._vl[i]
-
-    def __setitem__(self, i, pair):
-        i = int(i)
-        self._vh[i], self._vl[i] = pair
-
-
-class _RefState:
-    """Lazy chaining-state view: ``h[i]`` loads from VMEM at use site.
-
-    ``compress_soa`` touches h twice — initializing v[0..7] before the
-    rounds and xoring into the result after them — yet an eagerly-loaded
-    h pins 16 hi/lo vregs across all 12 rounds for those two uses.
-    Loading at the use sites makes h's live ranges two short windows the
-    scheduler can place freely (the third read, _kernel's active-mask
-    select, re-loads the same scratch).
-    """
-
-    def __init__(self, sth_ref, stl_ref):
-        self._sh = sth_ref
-        self._sl = stl_ref
-
-    def __len__(self):
-        return 8
-
-    def __getitem__(self, i):
-        i = int(i)
-        return self._sh[i], self._sl[i]
-
-    def __iter__(self):
-        return (self[i] for i in range(8))
-
-
 class _RefWords:
     """Lazy message-word view: ``m[w]`` issues the VMEM loads at use site.
 
@@ -142,36 +88,28 @@ class _RefWords:
     spilling a hot value.
     """
 
-    def __init__(self, mh_ref, ml_ref, k: int = 0, packed: int = 0):
+    def __init__(self, mh_ref, ml_ref, packed: int):
         self._mh = mh_ref
         self._ml = ml_ref
-        self._k = k
         self._packed = packed
 
     def __getitem__(self, w):
-        return _word(self._mh, self._ml, self._k, int(w), self._packed)
+        return _word(self._mh, self._ml, int(w), self._packed)
 
 
-def _word(mh_ref, ml_ref, k: int, w: int, packed: int):
-    """Message word ``w`` of the step's block ``k`` as a (hi, lo) pair of
-    item tiles: ``ref[k, w]`` of a ``(bps, 16, 8, BTL)`` block, or — in a
+def _word(mh_ref, ml_ref, w: int, packed: int):
+    """Message word ``w`` of the step's block as a (hi, lo) pair of item
+    tiles: ``ref[0, w]`` of a ``(1, 16, 8, BTL)`` block, or — in a
     packed single tile of ``packed`` sublanes — rows ``w*S .. w*S+S`` of
-    a ``(bps, 16*S, L)`` block."""
+    a ``(1, 16*S, L)`` block."""
     if not packed:
-        return mh_ref[k, w], ml_ref[k, w]
+        return mh_ref[0, w], ml_ref[0, w]
     rows = pl.ds(w * packed, packed)
-    return mh_ref[k, rows, :], ml_ref[k, rows, :]
+    return mh_ref[0, rows, :], ml_ref[0, rows, :]
 
 
-def _kernel(*refs, digest_size: int, unroll: bool = True,
-            msg_loads: bool = False, vmem_state: bool = False,
-            state_loads: bool = False, blocks_per_step: int = 1,
-            g_interleave: bool = False, packed: int = 0):
-    if vmem_state:
-        (len_ref, mh_ref, ml_ref, outh_ref, outl_ref,
-         sth_ref, stl_ref, vh_ref, vl_ref) = refs
-        sigma = None
-    elif unroll:
+def _kernel(*refs, digest_size: int, unroll: bool = True, packed: int = 0):
+    if unroll:
         len_ref, mh_ref, ml_ref, outh_ref, outl_ref, sth_ref, stl_ref = refs
         sigma = None
     else:
@@ -197,58 +135,21 @@ def _kernel(*refs, digest_size: int, unroll: bool = True,
     nb_ceil = (lengths + U32(127)) >> U32(7)
     item_blocks = jnp.where(nb_ceil == U32(0), U32(1), nb_ceil)
 
-    if blocks_per_step == 1:
-        ju = j.astype(U32)
-        active = ju < item_blocks
-        final = ju == item_blocks - U32(1)
-        cap = (ju + U32(1)) << U32(7)
-        t_lo = jnp.where(cap < lengths, cap, lengths)
+    ju = j.astype(U32)
+    active = ju < item_blocks
+    final = ju == item_blocks - U32(1)
+    cap = (ju + U32(1)) << U32(7)
+    t_lo = jnp.where(cap < lengths, cap, lengths)
 
-        if msg_loads and unroll:
-            m = _RefWords(mh_ref, ml_ref, packed=packed)
-        else:
-            m = [_word(mh_ref, ml_ref, 0, w, packed) for w in range(16)]
-        if state_loads and unroll:
-            h = _RefState(sth_ref, stl_ref)
-        else:
-            h = [(sth_ref[w], stl_ref[w]) for w in range(8)]
-        lanes = _RefLanes(vh_ref, vl_ref) if vmem_state else None
-        nh = compress_soa(h, m, t_lo, final, unroll=unroll, sigma=sigma,
-                          lanes=lanes, g_interleave=g_interleave)
-        for w in range(8):
-            sth_ref[w] = jnp.where(active, nh[w][0], h[w][0])
-            stl_ref[w] = jnp.where(active, nh[w][1], h[w][1])
+    if unroll:
+        m = _RefWords(mh_ref, ml_ref, packed)
     else:
-        # multi-block step: chain h through registers across the
-        # sub-blocks, touching the VMEM chaining scratch once per step
-        # instead of once per block — the structural variant pricing
-        # per-grid-step overhead (mask recompute is per sub-block, but
-        # state load/store, pl.when dispatch, and Mosaic's step
-        # prologue/epilogue amortize over blocks_per_step compressions)
-        h = [(sth_ref[w], stl_ref[w]) for w in range(8)]
-        lanes = _RefLanes(vh_ref, vl_ref) if vmem_state else None
-        for k in range(blocks_per_step):
-            ju = (j * blocks_per_step + k).astype(U32)
-            active = ju < item_blocks
-            final = ju == item_blocks - U32(1)
-            cap = (ju + U32(1)) << U32(7)
-            t_lo = jnp.where(cap < lengths, cap, lengths)
-            if msg_loads:
-                m = _RefWords(mh_ref, ml_ref, k, packed)
-            else:
-                m = [_word(mh_ref, ml_ref, k, w, packed) for w in range(16)]
-            nh = compress_soa(h, m, t_lo, final, unroll=True, lanes=lanes,
-                              g_interleave=g_interleave)
-            h = [
-                (
-                    jnp.where(active, nh[w][0], h[w][0]),
-                    jnp.where(active, nh[w][1], h[w][1]),
-                )
-                for w in range(8)
-            ]
-        for w in range(8):
-            sth_ref[w] = h[w][0]
-            stl_ref[w] = h[w][1]
+        m = [_word(mh_ref, ml_ref, w, packed) for w in range(16)]
+    h = [(sth_ref[w], stl_ref[w]) for w in range(8)]
+    nh = compress_soa(h, m, t_lo, final, unroll=unroll, sigma=sigma)
+    for w in range(8):
+        sth_ref[w] = jnp.where(active, nh[w][0], h[w][0])
+        stl_ref[w] = jnp.where(active, nh[w][1], h[w][1])
 
     @pl.when(j == nb - 1)
     def _emit():
@@ -259,15 +160,10 @@ def _kernel(*refs, digest_size: int, unroll: bool = True,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("digest_size", "block_items", "interpret", "msg_loads",
-                     "vmem_state", "state_loads", "blocks_per_step",
-                     "g_interleave"),
+    static_argnames=("digest_size", "block_items", "interpret"),
 )
 def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
-                   block_items: int = 1024, interpret: bool = False,
-                   msg_loads: bool = True, vmem_state: bool = False,
-                   state_loads: bool = False, blocks_per_step: int = 1,
-                   g_interleave: bool = False):
+                   block_items: int = 1024, interpret: bool = False):
     """Hash in the kernel-native layout.
 
     ``mh``/``ml``: (nblocks, 16, 8, B/8) uint32 message word halves;
@@ -279,11 +175,6 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
     A batch under one tile arrives packed (:func:`to_native`):
     ``mh``/``ml`` (nblocks, 16*S, L), ``lengths`` (S, L), and the digest
     words come back (8, S, L); ``block_items`` is not consulted.
-
-    ``blocks_per_step`` > 1 compresses that many consecutive message
-    blocks per grid step with the chaining state held in registers
-    between them (``nblocks`` must divide evenly); it prices Mosaic's
-    per-grid-step overhead against register pressure.
     """
     packed = lengths.shape[0] if mh.ndim == 3 else 0
     if packed:
@@ -307,39 +198,18 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
             raise ValueError(f"B/8={bl} not a multiple of tile width {btl}")
         tile = (_SUBLANE, btl)
         n_tiles = bl // btl
-    if blocks_per_step < 1 or nb % blocks_per_step:
-        raise ValueError(
-            f"blocks_per_step={blocks_per_step} must divide nblocks={nb}"
-        )
-    if state_loads and blocks_per_step > 1:
-        # the multi-block branch chains h through registers and never
-        # consults the lazy-state view; refuse rather than silently
-        # benchmark identical code under two variant labels
-        raise ValueError("state_loads has no effect with blocks_per_step > 1")
-
-    grid = (n_tiles, nb // blocks_per_step)
+    grid = (n_tiles, nb)
     # Mosaic gets the straight-line unrolled rounds; the interpreter (CPU
     # tests) gets the scanned rounds, whose 12x-smaller graph sidesteps
     # the CPU backend's pathological compile of the unrolled chain
-    # vmem_state mutates lane refs inside the rounds and state_loads
-    # reads h refs lazily — neither has a scanned formulation, so both
-    # force unrolled rounds (interpret included; keep interpret shapes
-    # tiny there, the CPU compile of the unrolled chain is the slow part
-    # the scanned path normally dodges).  Without the state_loads term
-    # the interpret-mode tests would silently exercise the eager path.
-    unroll = ((not interpret) or vmem_state or state_loads
-              or blocks_per_step > 1 or g_interleave)
+    unroll = not interpret
     kernel = functools.partial(
-        _kernel, digest_size=digest_size, unroll=unroll,
-        msg_loads=msg_loads, vmem_state=vmem_state,
-        state_loads=state_loads, blocks_per_step=blocks_per_step,
-        g_interleave=g_interleave, packed=packed,
+        _kernel, digest_size=digest_size, unroll=unroll, packed=packed,
     )
-    bps = blocks_per_step
     if packed:
-        msg_spec = pl.BlockSpec((bps,) + mh.shape[1:], lambda i, j: (j, 0, 0))
+        msg_spec = pl.BlockSpec((1,) + mh.shape[1:], lambda i, j: (j, 0, 0))
     else:
-        msg_spec = pl.BlockSpec((bps, 16) + tile, lambda i, j: (j, 0, 0, i))
+        msg_spec = pl.BlockSpec((1, 16) + tile, lambda i, j: (j, 0, 0, i))
     in_specs = [pl.BlockSpec(tile, lambda i, j: (0, i)), msg_spec, msg_spec]
     inputs = [lengths, mh, ml]
     if not unroll:
@@ -355,8 +225,7 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((8,) + tile, lambda i, j: (0, 0, i))] * 2,
         out_shape=[out_shape, out_shape],
-        scratch_shapes=[pltpu.VMEM((8,) + tile, jnp.uint32)] * 2
-        + ([pltpu.VMEM((16,) + tile, jnp.uint32)] * 2 if vmem_state else []),
+        scratch_shapes=[pltpu.VMEM((8,) + tile, jnp.uint32)] * 2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -368,7 +237,6 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
 
 
 # recompile sentinel: the kernel specializes per (nblocks, B) tile shape
-# plus every static knob the bench calibrates over
 blake2b_native = _jit_site("ops.blake2b_pallas.native", blake2b_native)
 
 

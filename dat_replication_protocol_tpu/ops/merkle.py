@@ -141,8 +141,8 @@ def _node_neq(ahh, ahl, bhh, bhl):
 def diff_root_guided(a_leaf_hh, a_leaf_hl, b_leaf_hh, b_leaf_hl):
     """Build both trees and diff them in one jitted program.
 
-    Returns (mask, a_root_pair, b_root_pair).  This is the bench config-5
-    kernel: two snapshots' leaf digests in, differing-leaf mask out.
+    Returns (mask, a_root_pair, b_root_pair): two snapshots' leaf
+    digests in, differing-leaf mask out (BASELINE.json configs[4]).
 
     Both trees are built as ONE concatenated tree: with a power-of-two
     leaf width, the even/odd sibling pairing never crosses the midpoint
@@ -333,8 +333,8 @@ def pad_leaves(hh, hl):
     """Zero-pad the leaf axis up to the next power of two.
 
     Zero digests act as the empty-subtree sentinel; both snapshots of a
-    diff must be padded to the same width (the bench and the parallel
-    layer always compare equal-width snapshots).
+    diff must be padded to the same width (the parallel layer always
+    compares equal-width snapshots).
     """
     n = hh.shape[0]
     p = 1

@@ -2,7 +2,7 @@
 
 A tree level is the ideal Pallas shape: every parent is exactly ONE
 BLAKE2b compression of a fixed 64-byte two-child message (level 0 of the
-1M-leaf bench config is a 524288-item batch).  The general batched
+1M-leaf BASELINE.json config is a 524288-item batch).  The general batched
 kernel (:mod:`.blake2b_pallas`) spends its flexibility on variable
 lengths, multi-block chaining, and VMEM state carried across a grid
 axis; none of that applies here, so this kernel is the stripped-down

@@ -442,9 +442,9 @@ def _clamp_thin_bits(thin_bits: int | None, stride: int) -> int | None:
 
 def pallas_active() -> bool:
     """The ONE owner of the "do Pallas kernels run here" decision —
-    candidates_begin's route dispatch, effective_route's fused->bitmask
-    aliasing, and the bench's calibration/label all consult this, so
-    they can never disagree about which kernel actually executes."""
+    candidates_begin's route dispatch and effective_route's
+    fused->bitmask aliasing both consult this, so they can never
+    disagree about which kernel actually executes."""
     return jax.default_backend() == "tpu"
 
 
@@ -455,9 +455,9 @@ def effective_route(use_pallas: bool | None = None) -> str:
     and alias ``fused``/``fused1p`` to ``bitmask`` off-Pallas (neither
     fused kernel has an XLA formulation; fused1p's HOST engine is routed
     separately by :func:`..runtime.content.content_digests`, which
-    consults the raw env value).  Both the dispatch path and the bench
-    artifact label use this, so the recorded route is always the route
-    that actually ran.  ``use_pallas=None`` consults
+    consults the raw env value).  The dispatch path and
+    ``chip_smoke.py``'s route check both use this, so the route named
+    is always the route that actually ran.  ``use_pallas=None`` consults
     :func:`pallas_active`."""
     import os
 
@@ -503,8 +503,8 @@ def candidates_begin(words, nbytes: int, avg_bits: int = 13,
     Returns a zero-arg ``collect()`` closure: the device scan is
     dispatched asynchronously here, and ``collect()`` blocks on the
     result transfer — so a caller streaming multiple slabs can overlap
-    slab N's D2H with slab N+1's compute (:func:`chunk_stream` and the
-    bench both do, at depth 2).
+    slab N's D2H with slab N+1's compute (:func:`chunk_stream` does, at
+    depth 2).
     """
     if nbytes == 0:
         return lambda: np.empty((0,), dtype=np.int64)
@@ -545,7 +545,7 @@ def candidates_begin(words, nbytes: int, avg_bits: int = 13,
     if thin_bits is not None and thin_bits >= 8:
         # fast path: windowed first-candidate extraction + occ/offsets
         # transfer (kernel route per _extract_first_occ; the env knobs
-        # are for on-device measurement comparison / bench calibration)
+        # are for on-device measurement comparison)
         route = effective_route(use_pallas)
         with span("cdc.dispatch"):
             first = _extract_first_occ(
@@ -726,13 +726,12 @@ def chunk_stream(
     pipelining TWO slabs are in flight, each holding the input words
     plus the ``_build_rows`` copy (and the bitmask route's mask), so
     HBM high-water is roughly 4x the slab size — 1 GiB slabs fit any
-    current backend.  Callers on a >= 16 GiB-HBM device (the bench's
-    10 GiB config) should pass ``slab_tiles=16384`` (2 GiB): round-4
-    phase attribution measured ~63 ms fixed per-dispatch cost against
+    current backend.  Callers on a >= 16 GiB-HBM device should pass
+    ``slab_tiles=16384`` (2 GiB): round-4 phase attribution measured ~63 ms fixed per-dispatch cost against
     ~5 ms/GiB marginal, so fewer, larger slabs win until memory does.
     Host-resident data pays one H2D transfer per slab; for data
     already on device use :func:`candidates_words` +
-    :func:`_greedy_select` directly (the bench's 10 GiB config does).
+    :func:`_greedy_select` directly.
     """
     if min_size is None:
         min_size = 1 << (avg_bits - 2)

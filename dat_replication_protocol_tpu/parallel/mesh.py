@@ -214,8 +214,8 @@ def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
     axis is padded to ``n_devices * 2**k`` and uploaded with a batch-dim
     :class:`~jax.sharding.NamedSharding` so every chip receives only its
     shard over the interconnect and hashes it locally — the multiplexed
-    sessions' combined digest work is what finally fills an 8-chip mesh
-    (MULTICHIP_r05.json) that any single session's batch rarely could.
+    sessions' combined digest work is what can fill a mesh that any
+    single session's batch rarely could.
     """
     from ..utils.num import next_pow2
 

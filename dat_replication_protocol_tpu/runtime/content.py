@@ -179,8 +179,8 @@ def content_digests(data, avg_bits: int = 13,
     # the same resident words) for buffers within its per-call cap; the
     # slabbed two-pass composition for anything larger — and for an
     # EXPLICIT route="2p" (the A/B incumbent must stay the two-pass
-    # host-repack composition on every backend, or the bench's
-    # comparison label lies about what ran)
+    # host-repack composition on every backend, or an A/B's
+    # label lies about what ran)
     from ..ops.fused_cdc_hash_pallas import RESIDENCY_CAP
 
     if route != "2p" and n < RESIDENCY_CAP:

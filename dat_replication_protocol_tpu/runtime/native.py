@@ -219,7 +219,7 @@ def _load_once() -> ctypes.CDLL | None:
         # once per process (only the winning builder reaches here —
         # emitted AFTER the lock releases, the sink can block): which
         # engine tier this host actually has, the first question when
-        # a bench number moves between runners
+        # a number moves between hosts
         _emit("device.native.load", ok=lib is not None)
     return _lib
 

@@ -19,8 +19,8 @@ Layering:
   suite drives THIS against the fault injector; the live drivers wrap
   it.
 * :func:`reconcile_local` — both sides in one process with exact wire
-  metering (every message round-trips the real codec); the bench's A/B
-  harness and the property suite's workhorse.
+  metering (every message round-trips the real codec); the property
+  suite's workhorse.
 * :func:`run_initiator` / :func:`run_responder` — the live duplex
   drivers over blocking byte pairs (the :mod:`..session.transport`
   contract), composing with PR 2's resume machinery: both directions

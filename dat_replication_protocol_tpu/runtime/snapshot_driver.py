@@ -32,7 +32,7 @@ Layering (the reconcile-driver doctrine):
   :class:`~..wire.snapshot_codec.SnapshotMsg` messages, collect reply
   payloads.  The chaos suite drives THESE against the fault injector.
 * :func:`snapshot_local` — both sides in one process with exact wire
-  metering; the bench's A/B harness.
+  metering.
 * :func:`run_snapshot_responder` / :func:`run_snapshot_joiner` — live
   duplex drivers over blocking byte pairs (the
   :mod:`..session.transport` contract).  The sidecar serves the
@@ -277,7 +277,7 @@ class SnapshotSource:
         then the DONE frame.  N cold joiners are served slices of this
         log — one hash+read+encode pass however large the flash crowd
         (``snapshot.cold.bytes`` counts the bytes leaving; the digest
-        counters stay flat, which is the bench's hash-once proof)."""
+        counters stay flat: the hash-once proof)."""
         from ..fanout.log import BroadcastLog
 
         with self._lock:

@@ -1,1 +1,1 @@
-from .trace import span, trace_to  # noqa: F401
+from .trace import span  # noqa: F401

@@ -1,9 +1,9 @@
 """Persistent XLA compile-cache placement (one owner for all entry points).
 
-``ops/blake2b.py`` compiles one program per (power-of-two batch,
-power-of-two block count) bucket, seconds each on the chip and longer
-on the CPU backend's scanned programs; a persistent cache turns a second
-start into cache hits.  The directory is part of jax's cache key, so it
+``ops/blake2b.py`` compiles one program per (declared rows,
+power-of-two block count) bucket; a persistent cache turns a second
+start into cache hits for every program that compiles above the floor
+set below.  The directory is part of jax's cache key, so it
 must not move between runs:
 
 * ``JAX_COMPILATION_CACHE_DIR`` in the environment places the cache from
@@ -33,9 +33,8 @@ def enable_compile_cache() -> str:
 
     Call before the first device program compiles (jax latches the
     cache at first use).  Every entry point that can reach a device
-    program goes through here: the sidecar, ``bench.py``,
-    ``chip_smoke.py``, ``__graft_entry__.py``, the examples and
-    ``tests/conftest.py``.
+    program goes through here: the sidecar, ``chip_smoke.py``,
+    ``__graft_entry__.py``, the examples and ``tests/conftest.py``.
     """
     placed = os.environ.get(CACHE_ENV)
     if placed:
@@ -44,7 +43,8 @@ def enable_compile_cache() -> str:
 
     os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
-    # the XLA-scan buckets compile in a few seconds on the chip; the
-    # default 1 s floor would skip the small ones a sidecar meets most
+    # half of jax's default 1 s floor.  The served Pallas BLAKE2b
+    # programs still compile under it on the chip: a second start
+    # builds them again and reads nothing back (PERF.md §6, PR 29)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     return DEFAULT_CACHE_DIR

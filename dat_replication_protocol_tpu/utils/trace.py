@@ -25,8 +25,6 @@ path is bracketed with::
 * :func:`annotation` — the first third alone, for a site whose lit
   record another recorder already keeps (the edge loop's
   ``LoopProfiler``).
-* :func:`trace_to` — whole-program capture into a profile directory
-  (``bench.py --trace=DIR`` uses it; open with TensorBoard or Perfetto).
 
 Two rules keep the idle-gap attribution honest (OBSERVABILITY.md): no
 span brackets a wait on another thread or on a peer, and none brackets
@@ -42,7 +40,6 @@ fast) in processes that never touch a device.
 
 from __future__ import annotations
 
-import contextlib
 import sys
 import threading
 
@@ -156,18 +153,3 @@ def span(name: str, **fields):
     if _OBS.on:
         return _JoinedSpan(name, annotation(name), fields)
     return annotation(name)
-
-
-@contextlib.contextmanager
-def trace_to(log_dir: str | None):
-    """Capture a jax profiler trace into ``log_dir`` (no-op if None)."""
-    if not log_dir:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

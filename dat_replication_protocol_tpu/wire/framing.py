@@ -105,8 +105,8 @@ def iter_frames(wire):
     """Walk a complete recorded frame stream: yields ``(start, type_id,
     payload_start, end)`` per frame, where ``wire[payload_start:end]``
     is the payload and ``wire[start:end]`` the whole frame.  The ONE
-    owner of the header walk over recorded wire (cold-log replay, the
-    bench's chaos-arm frame scan) — every hand-rolled copy of the
+    owner of the header walk over recorded wire (cold-log replay) —
+    every hand-rolled copy of the
     varint/id-byte slicing is a layout fork that must track header
     changes in lockstep."""
     at = 0

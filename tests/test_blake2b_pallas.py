@@ -1,9 +1,10 @@
 """Pallas BLAKE2b kernel vs hashlib, via the interpreter on CPU.
 
-The real Mosaic compile path runs on TPU (exercised by bench.py and the
-driver); these tests check the kernel's logic — layout plumbing, state
-chaining across blocks, variable-length masks, batch padding — with
-``interpret=True`` on tiny shapes.
+The real Mosaic compile path is checked by ``tests/test_tpu_compile.py``
+(compiled for a described chip) and runs on the TPU under
+``chip_smoke.py`` and the benchmark; these tests check the kernel's
+logic — layout plumbing, state chaining across blocks, variable-length
+masks, batch padding — with ``interpret=True`` on tiny shapes.
 """
 
 import hashlib
@@ -44,158 +45,23 @@ def test_multiblock_chaining():
     ]
 
 
-@pytest.mark.slow
-def test_vmem_state_variant_matches_hashlib():
-    # the register-pressure experiment: working-vector lanes in VMEM
-    # scratch, per-G load/store.  Tiny shapes: this variant has no
-    # scanned form, so interpret compiles the unrolled chain (~30 s of
-    # pure compile — slow-marked; the vmem_state COMPOSITIONS stay
-    # tier-1 in the state_loads/bps/g_interleave parity tests below)
-    from dat_replication_protocol_tpu.ops.blake2b_pallas import (
-        blake2b_native,
-        from_native,
-        to_native,
-    )
+def test_the_kernel_has_one_body_and_no_variant_flags():
+    """What the chip runs is what every caller gets: beside its three
+    arrays the jitted entry takes ``digest_size``, ``block_items`` and
+    ``interpret`` alone (its static arguments), and the kernel body
+    ``digest_size``, ``unroll`` and ``packed`` — a variant flag cannot
+    come back unseen."""
+    import inspect
 
-    payloads = [b"", b"x" * 7, b"y" * 128, b"z" * 200]
-    mh, ml, lengths = pack_payloads(payloads, nblocks=2)
-    mh_n, ml_n, len_n, B = to_native(
-        jnp.asarray(mh), jnp.asarray(ml), jnp.asarray(lengths)
-    )
-    hh, hl = blake2b_native(mh_n, ml_n, len_n, interpret=True,
-                            vmem_state=True)
-    assert digests_to_bytes(*from_native(hh, hl, B)) == [
-        hashlib.blake2b(p, digest_size=32).digest() for p in payloads
-    ]
+    from dat_replication_protocol_tpu.ops import blake2b_pallas as b2p
 
+    native = inspect.signature(b2p.blake2b_native.__wrapped__)
+    assert list(native.parameters) == [
+        "mh", "ml", "lengths", "digest_size", "block_items", "interpret"]
+    kernel = inspect.signature(b2p._kernel)
+    assert [n for n, p in kernel.parameters.items()
+            if p.kind is p.KEYWORD_ONLY] == ["digest_size", "unroll", "packed"]
 
-@pytest.mark.slow
-def test_state_loads_variants_byte_exact():
-    """The lazy chaining-state view (state_loads) must be byte-exact in
-    every composition with msg_loads/vmem_state (mixed lengths so the
-    active/final masks take both values).
-
-    slow-marked (tier-1 runtime audit, ISSUE 12): ~30 s of interpret
-    COMPILE for a non-default experiment variant no production route
-    sets — the default-path parity stays tier-1 in the fast tests, the
-    variant parity runs in the slow tier."""
-    import hashlib
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from dat_replication_protocol_tpu.ops.blake2b import (
-        digests_to_bytes,
-        pack_payloads,
-    )
-    from dat_replication_protocol_tpu.ops.blake2b_pallas import (
-        blake2b_native,
-        from_native,
-        to_native,
-    )
-
-    rng = np.random.default_rng(4)
-    payloads = [rng.integers(0, 256, int(n), dtype=np.uint8).tobytes()
-                for n in rng.integers(0, 513, 1024)]
-    mh, ml, lens = pack_payloads(payloads, nblocks=4)
-    mh_n, ml_n, len_n, B = to_native(
-        jnp.asarray(mh), jnp.asarray(ml), jnp.asarray(lens)
-    )
-    # only the vmem_state composition here: its per-G ref loads/stores
-    # break the unrolled graph into pieces the CPU interpreter compiles
-    # in ~1 min, while the pure-value unrolled graph that state_loads
-    # alone produces compiles pathologically (>20 min measured).  The
-    # {vmem_state: False, state_loads: True} composition is covered on
-    # the real chip: bench.py's calibration refuses any variant whose
-    # digests differ from the baseline's.
-    kw = {"vmem_state": True, "state_loads": True}
-    hh, hl = blake2b_native(mh_n, ml_n, len_n, interpret=True,
-                            msg_loads=True, **kw)
-    digs = digests_to_bytes(*from_native(hh, hl, B))
-    for i in (0, 1, 511, 1023):
-        exp = hashlib.blake2b(payloads[i], digest_size=32).digest()
-        assert digs[i] == exp, (kw, i)
-
-
-@pytest.mark.slow
-def test_blocks_per_step_byte_exact():
-    """Multi-block grid steps (chaining state in registers between
-    sub-blocks) must match hashlib with mixed lengths, so every item
-    finishes at a different sub-block position within a step.
-
-    slow-marked (tier-1 runtime audit, ISSUE 12): ~55 s of interpret
-    COMPILE for the bps experiment flag no production route sets (the
-    real bps A/B runs on-device via _bps_experiment.py); shrinking the
-    batch does not help — the cost is the unroll, not the data."""
-    import hashlib
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from dat_replication_protocol_tpu.ops.blake2b import (
-        digests_to_bytes,
-        pack_payloads,
-    )
-    from dat_replication_protocol_tpu.ops.blake2b_pallas import (
-        blake2b_native,
-        from_native,
-        to_native,
-    )
-
-    rng = np.random.default_rng(11)
-    payloads = [rng.integers(0, 256, int(n), dtype=np.uint8).tobytes()
-                for n in rng.integers(0, 513, 1024)]
-    mh, ml, lens = pack_payloads(payloads, nblocks=4)
-    mh_n, ml_n, len_n, B = to_native(
-        jnp.asarray(mh), jnp.asarray(ml), jnp.asarray(lens)
-    )
-    # vmem_state composition for the same interpret-compile-time reason
-    # as above; bps=2 only — the interpret compile cost scales with the
-    # blocks-per-step unroll, and bps=4 (whole grid in one step) is
-    # cross-checked against the baseline on the real chip with mixed
-    # lengths by _bps_experiment.py
-    hh, hl = blake2b_native(mh_n, ml_n, len_n, interpret=True,
-                            msg_loads=True, vmem_state=True,
-                            blocks_per_step=2)
-    digs = digests_to_bytes(*from_native(hh, hl, B))
-    for i in (0, 1, 511, 1023):
-        exp = hashlib.blake2b(payloads[i], digest_size=32).digest()
-        assert digs[i] == exp, i
-
-
-def test_g_interleave_byte_exact():
-    """The 4-way lockstep G-stage emission must be byte-exact (it is
-    pure reordering of independent ops; a lane-indexing slip in
-    _g_stage4 would corrupt digests).  interpret forces the unrolled
-    rounds for this flag, so the interleaved path really traces."""
-    import hashlib
-
-    import jax.numpy as jnp
-
-    from dat_replication_protocol_tpu.ops.blake2b import (
-        digests_to_bytes,
-        pack_payloads,
-    )
-    from dat_replication_protocol_tpu.ops.blake2b_pallas import (
-        blake2b_native,
-        from_native,
-        to_native,
-    )
-
-    payloads = [b"", b"x" * 7, b"y" * 129, b"z" * 256]
-    mh, ml, lens = pack_payloads(payloads, nblocks=2)
-    mh_n, ml_n, len_n, B = to_native(
-        jnp.asarray(mh), jnp.asarray(ml), jnp.asarray(lens)
-    )
-    hh, hl = blake2b_native(mh_n, ml_n, len_n, interpret=True,
-                            msg_loads=True, vmem_state=True,
-                            g_interleave=True)
-    assert digests_to_bytes(*from_native(hh, hl, B)) == [
-        hashlib.blake2b(p, digest_size=32).digest() for p in payloads
-    ]
-
-
-# -- the raw-words entry point: the hi/lo split inside the program ----------
 
 @pytest.mark.parametrize(
     "lens, nblocks",

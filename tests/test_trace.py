@@ -1,12 +1,9 @@
-"""Tracing hooks: spans wrap work transparently, trace_to captures."""
-
-import os
-import tempfile
+"""Tracing hooks: spans wrap work transparently."""
 
 import jax.numpy as jnp
 import numpy as np
 
-from dat_replication_protocol_tpu.utils.trace import span, trace_to
+from dat_replication_protocol_tpu.utils.trace import span
 
 
 def test_span_is_transparent_and_reentrant():
@@ -14,11 +11,6 @@ def test_span_is_transparent_and_reentrant():
         with span("inner"):
             x = int(np.asarray(jnp.arange(8).sum()))
     assert x == 28
-
-
-def test_trace_to_none_is_noop():
-    with trace_to(None):
-        assert int(np.asarray(jnp.ones((4,)).sum())) == 4
 
 
 def test_span_factory_binds_once_and_is_cached():
@@ -67,15 +59,3 @@ def test_span_falls_back_to_null_span_when_import_fails(monkeypatch):
     finally:
         monkeypatch.undo()
         trace._reset_span_binding_for_tests()
-
-
-def test_trace_to_captures_profile_dir():
-    with tempfile.TemporaryDirectory() as d:
-        with trace_to(d):
-            with span("traced-work"):
-                np.asarray(jnp.arange(128).sum())
-        # a plugins/profile/<ts>/ tree with at least one artifact
-        found = [
-            os.path.join(r, f) for r, _, fs in os.walk(d) for f in fs
-        ]
-        assert found, "profiler produced no trace artifacts"
