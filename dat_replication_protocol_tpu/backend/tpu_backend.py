@@ -55,6 +55,10 @@ _M_DISPATCHES = _counter("device.dispatch.batches")
 # of that batch's digest.deliver
 _H_FILL = _histogram("digest.batch.fill_s")
 _H_RESIDENCE = _histogram("digest.batch.residence_s")
+# why a batch left the pipeline: its closure said its digests exist, or
+# the in-flight bound / flush() asked for them whether or not they did
+_M_DELIVER_READY = _counter("digest.deliver.ready")
+_M_DELIVER_FORCED = _counter("digest.deliver.forced")
 # bytes of over-threshold blob streams: hashed on the host by design
 # (see _make_stream), so these never reach the device
 _M_HOST_STREAM_BYTES = _counter("device.host.stream.bytes")
@@ -218,6 +222,29 @@ def _make_stream():
     return _HostStream()
 
 
+# submits ask a batch in flight whether its digests exist at most once
+# in this many seconds: the probe is a call into the runtime per bucket,
+# and the hub's dispatcher makes 1,024 submits in a row
+_READY_PROBE_S = 1e-3
+
+
+class _Done:
+    """The ``collect`` of a batch whose digests exist when it is made: an
+    engine that computes at dispatch, or a batch of streams alone."""
+
+    __slots__ = ("_digests",)
+
+    def __init__(self, digests: list):
+        self._digests = digests
+
+    def __call__(self) -> list:
+        return self._digests
+
+    @staticmethod
+    def ready() -> bool:
+        return True
+
+
 class DigestPipeline:
     """Accumulates payloads into batches, dispatches them asynchronously,
     and maps batch slots back to per-item completion callbacks.
@@ -225,10 +252,24 @@ class DigestPipeline:
     This is the completion-queue pattern SURVEY §7 calls out as the hard
     part: per-message callback ordering is preserved while the device sees
     large batches.  Dispatch is **asynchronous**: when a batch fills, the
-    device starts hashing while the host keeps parsing; digests are
-    collected (oldest batch first, entries in submit order within each)
-    when ``max_inflight`` batches are outstanding — the backpressure bound
-    — or at ``flush()``, which drains everything (the finalize barrier).
+    device starts hashing while the host keeps parsing.  ``on_digest``
+    fires oldest batch first, whole batches, entries in submit order
+    within each, on the submitting thread, at the first of:
+
+    * **ready** — a later ``submit`` / ``submit_stream`` / ``dispatch``
+      finds that the oldest batch's closure reports its digests exist
+      (``collect.ready()``, asked at most once a millisecond of submits);
+    * **the in-flight bound** — more than ``max_inflight`` batches are
+      outstanding (backpressure; it also bounds pinned staging memory);
+    * **``flush()``**, which drains everything (the finalize barrier).
+
+    A ``hash_begin`` closure may carry two optional attributes:
+    ``start_d2h()`` (begin the readback without blocking; called once,
+    at the end of the batch's own dispatch) and ``ready() -> bool``
+    (non-blocking: would ``collect()`` return without waiting for the
+    device).  A closure with no ``ready`` is delivered by the bound or by
+    ``flush()`` alone; an engine given as ``hash_batch`` has its result
+    at dispatch and is delivered there.
     """
 
     def __init__(
@@ -245,13 +286,11 @@ class DigestPipeline:
         # a caller's own engine is handed ``bytes`` alone: submit_parts
         # joins for it
         self._joins_parts = hash_begin is not None or hash_batch is not None
+        if hash_begin is None and hash_batch is None:
+            hash_begin = _device_hash_begin_factory()
         if hash_begin is None:
-            if hash_batch is not None:
-                hash_begin = lambda ps: (lambda out=hash_batch(ps): out)  # noqa: E731
-            else:
-                hash_begin = _device_hash_begin_factory() or (
-                    lambda ps: (lambda out=_host_hash_batch(ps): out)
-                )
+            eager = hash_batch or _host_hash_batch
+            hash_begin = lambda ps: _Done(eager(ps))  # noqa: E731
         self._hash_begin = hash_begin
         self._max_batch = max_batch
         # byte cap bounds device/HBM footprint per dispatch — the item cap
@@ -276,6 +315,8 @@ class DigestPipeline:
         self._spare_bytes = 0
         # (entries, collect, batch ordinal, lit dispatch-start time)
         self._inflight: list[tuple] = []
+        # when a submit last asked the oldest of them whether it is ready
+        self._polled = 0.0
         # lit: submit time of the oldest queued item (None: none yet)
         self._fill_t0: Optional[float] = None
         self.dispatches = 0
@@ -287,6 +328,8 @@ class DigestPipeline:
         ``on_digest(tag, digest)`` — a shared bound method + tag costs no
         per-item closure, which matters at the bulk decoder's change
         rates (a lambda per change was ~20% of the digest path)."""
+        if self._inflight:
+            self._poll_ready()
         if _OBS.on and self._fill_t0 is None:
             self._fill_t0 = _monotonic()
         n = len(payload)
@@ -337,6 +380,8 @@ class DigestPipeline:
         """Queue a finished incremental hash (:class:`..ops.blake2b.
         Blake2bStream`-shaped: ``.digest()``/``.length``) for in-order
         digest delivery alongside batched payloads."""
+        if self._inflight:
+            self._poll_ready()
         if _OBS.on:
             if self._fill_t0 is None:
                 self._fill_t0 = _monotonic()
@@ -365,17 +410,14 @@ class DigestPipeline:
     def dispatch(self) -> None:
         """Start hashing everything queued WITHOUT waiting for results.
 
-        If more than ``max_inflight`` batches would be outstanding, the
-        oldest is collected first — bounded in-flight work is the
-        device-side analogue of the reference's pending counter.
-
-        Pipelined readback (ISSUE 7 part 3): the moment a NEWER batch is
-        dispatched, every older in-flight batch's digest D2H is STARTED
-        (``collect.start_d2h``, non-blocking) — so when the in-flight
-        bound forces ``_deliver_oldest`` below, the transfer has been
-        streaming under this batch's compute instead of starting cold
-        inside the deliver, and the next submit never waits on a full
-        link round-trip.
+        The batch's digest readback is started with it
+        (``collect.start_d2h``, non-blocking), so the words are on their
+        way to the host while the device still computes them.  Then
+        every batch in flight that reports ready is delivered, oldest
+        first; and if more than ``max_inflight`` batches are still
+        outstanding, the oldest is collected ready or not — bounded
+        in-flight work is the device-side analogue of the reference's
+        pending counter.
         """
         if not self._entries:
             return
@@ -388,15 +430,17 @@ class DigestPipeline:
         t0 = self._lit_dispatch(len(entries), pending) if _OBS.on else None
         with span("digest.dispatch", batch=batch, items=len(entries),
                   bytes=pending):
-            collect = self._hash_begin(payloads) if payloads else (lambda: [])
+            collect = self._hash_begin(payloads) if payloads else _Done([])
             # the engine has copied (or hashed) them: the batch in flight
             # keeps lengths, and the slabs its parts were views of go
             del payloads
-        self._prefetch_inflight()  # older batches' D2H rides under this
-        # batch's compute (idempotent per closure)
+            start_d2h = getattr(collect, "start_d2h", None)
+            if start_d2h is not None:
+                start_d2h()
         self._inflight.append((entries, collect, batch, t0))
+        self._deliver_ready()
         while len(self._inflight) > self._max_inflight:
-            self._deliver_oldest()
+            self._deliver_oldest(_M_DELIVER_FORCED)
 
     def _lit_dispatch(self, items: int, nbytes: int) -> float:
         """The lit half of a dispatch: the per-batch counters and the
@@ -411,13 +455,28 @@ class DigestPipeline:
             self._fill_t0 = None
         return now
 
-    def _prefetch_inflight(self) -> None:
-        for _, collect, _, _ in self._inflight:
-            start = getattr(collect, "start_d2h", None)
-            if start is not None:
-                start()
+    def _poll_ready(self) -> None:
+        """A submit's look at the batches in flight, rationed by the
+        clock: a submit costs about a microsecond and they come in runs
+        of a thousand."""
+        now = _monotonic()
+        if now - self._polled >= _READY_PROBE_S:
+            self._polled = now
+            self._deliver_ready()
 
-    def _deliver_oldest(self) -> None:
+    def _deliver_ready(self) -> None:
+        """Deliver, oldest first, the batches whose closures say their
+        digests exist; stop at the first that does not, or that cannot
+        say (a caller's own ``hash_begin`` without the probe)."""
+        while self._inflight:
+            ready = getattr(self._inflight[0][1], "ready", None)
+            if ready is None or not ready():
+                return
+            self._deliver_oldest(_M_DELIVER_READY)
+
+    def _deliver_oldest(self, why) -> None:
+        """Collect the oldest batch and run its callbacks; ``why`` is
+        the counter of what asked (lit)."""
         entries, collect, batch, t0 = self._inflight.pop(0)
         with span("digest.collect", batch=batch, items=len(entries)):
             digest_list = collect()
@@ -441,18 +500,19 @@ class DigestPipeline:
                 else:
                     cb(tag, d)
         if _OBS.on:
+            why.inc()
             if t0 is not None:  # dispatched lit
                 _H_RESIDENCE.observe(_monotonic() - t0)
             fold_digest_tallies()
 
     def flush(self) -> None:
         """Dispatch anything queued and deliver ALL outstanding digests in
-        submit order — the flush-before-finalize barrier."""
+        submit order — the flush-before-finalize barrier.  Every
+        readback was started at its batch's dispatch, so the in-order
+        loop waits on transfers already streaming."""
         self.dispatch()
-        self._prefetch_inflight()  # all readbacks stream concurrently;
-        # the in-order delivery loop below then waits on warm transfers
         while self._inflight:
-            self._deliver_oldest()
+            self._deliver_oldest(_M_DELIVER_FORCED)
 
 
 class TpuDecoder(Decoder):

@@ -686,6 +686,8 @@ def blake2b_batch_begin(
     as this returns, while the host goes back to parsing.  ``collect()``
     blocks on the transfers and yields digests in submit order — the
     split the async DigestPipeline uses to overlap parse and hash.
+    Beside it ride ``collect.start_d2h()`` (begin the readback, no
+    wait) and ``collect.ready()`` (no wait: are the digests computed).
 
     Items are grouped into power-of-two block-count buckets; each bucket
     is one padded dispatch at a declared row count (:func:`batch_rows`),
@@ -749,14 +751,19 @@ def blake2b_batch_begin(
             handles.append((idxs, hh[: len(idxs)], hl[: len(idxs)]))
 
     def start_d2h() -> None:
-        # begin the digest readback WITHOUT blocking: by collect() time
-        # the words are local (or in flight under newer batches'
-        # compute).  Idempotent; the DigestPipeline calls this when a
-        # NEWER batch is dispatched so deliver never serializes a cold
-        # D2H behind the next submit (ISSUE 7 part 3).
+        # begin the digest readback WITHOUT blocking: the copies queue
+        # behind the programs, so by collect() time the words are local
+        # or on their way.  Idempotent; the DigestPipeline calls this at
+        # the end of the batch's own dispatch.
         for _, hh, hl in handles:
             hh.copy_to_host_async()
             hl.copy_to_host_async()
+
+    def ready() -> bool:
+        # non-blocking: has the device produced every bucket's digests
+        # (what the staging pool asks of its fences).  True means
+        # collect() waits for no program, at most for a readback's tail.
+        return all(hh.is_ready() and hl.is_ready() for _, hh, hl in handles)
 
     n_items = len(payloads)  # the closures below keep no payload alive
 
@@ -776,6 +783,7 @@ def blake2b_batch_begin(
         return out  # type: ignore[return-value]
 
     collect.start_d2h = start_d2h  # type: ignore[attr-defined]
+    collect.ready = ready  # type: ignore[attr-defined]
     return collect
 
 

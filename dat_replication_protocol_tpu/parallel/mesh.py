@@ -204,7 +204,7 @@ def _sharded_hash_program(mesh: Mesh):
 
 def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
     """Dispatch one cross-session payload batch sharded over the mesh;
-    returns a zero-arg ``collect()`` closure (``.start_d2h`` attached) —
+    returns a zero-arg ``collect()`` closure (``.start_d2h``, ``.ready``) —
     the same async contract as :func:`..ops.blake2b.blake2b_batch_begin`,
     so the hub's shared :class:`~..backend.tpu_backend.DigestPipeline`
     can use either engine interchangeably.
@@ -247,6 +247,9 @@ def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
             hh.copy_to_host_async()
             hl.copy_to_host_async()
 
+    def ready() -> bool:
+        return all(hh.is_ready() and hl.is_ready() for _, hh, hl in handles)
+
     def collect() -> list[bytes]:
         out: list[bytes | None] = [None] * len(payloads)
         for idxs, hh, hl in handles:
@@ -257,6 +260,7 @@ def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
         return out  # type: ignore[return-value]
 
     collect.start_d2h = start_d2h  # type: ignore[attr-defined]
+    collect.ready = ready  # type: ignore[attr-defined]
     return collect
 
 

@@ -308,9 +308,10 @@ def test_donated_batch_path_byte_exact(monkeypatch):
         assert blake2b_batch(payloads) == ref
 
 
-def test_pipeline_prefetches_d2h_before_deliver():
-    """Part 3 of the tentpole: dispatching batch N+1 starts batch N's
-    digest readback (start_d2h) BEFORE any deliver blocks on it."""
+def test_pipeline_starts_d2h_with_the_launch():
+    """A batch's digest readback (start_d2h) is started at the end of
+    its own dispatch — before the next batch is dispatched and before
+    anything collects it."""
     from dat_replication_protocol_tpu.backend.tpu_backend import (
         DigestPipeline,
     )
@@ -327,11 +328,7 @@ def test_pipeline_prefetches_d2h_before_deliver():
             return [hashlib.blake2b(p, digest_size=32).digest()
                     for p in payloads]
 
-        def start_d2h():
-            if ("start_d2h", batch_id) not in events:
-                events.append(("start_d2h", batch_id))
-
-        collect.start_d2h = start_d2h
+        collect.start_d2h = lambda: events.append(("start_d2h", batch_id))
         return collect
 
     pipe = DigestPipeline(hash_begin=hash_begin, max_batch=1,
@@ -341,39 +338,49 @@ def test_pipeline_prefetches_d2h_before_deliver():
         pipe.submit(b"payload-%d" % i, got.append)
     pipe.flush()
     assert len(got) == 3
-    # batch 0's readback started when batch 1 was dispatched — well
-    # before anything collected it
-    assert events.index(("start_d2h", 0)) < events.index(("collect", 0))
-    assert events.index(("start_d2h", 0)) > events.index(("dispatch", 1)) - 2
-    # every batch's readback was started before its collect
     for b in range(3):
+        # once a batch, directly behind its launch
+        assert events.count(("start_d2h", b)) == 1
+        assert events.index(("start_d2h", b)) \
+            == events.index(("dispatch", b)) + 1
         assert events.index(("start_d2h", b)) < events.index(("collect", b))
 
 
-def test_dispatch_span_opens_before_prior_deliver_closes(obs_enabled):
-    """The acceptance trace evidence: with the pipelined readback, the
-    digest.dispatch span of batch N+1 OPENS before the digest.collect
-    span of batch N closes (h2d rides under compute, readback under the
-    next submit)."""
+@pytest.mark.parametrize("probe", [False, True], ids=["no-probe", "ready"])
+def test_collect_span_against_the_next_dispatch_spans(obs_enabled, probe):
+    """Where a batch's digest.collect falls among the digest.dispatch
+    spans.  A closure that cannot say it is done waits for the in-flight
+    bound: batch 0 is collected after the dispatch of batch 2 opened.
+    One that reports ready is collected before the next dispatch opens."""
     from dat_replication_protocol_tpu.backend.tpu_backend import (
         DigestPipeline,
     )
     from dat_replication_protocol_tpu.obs.tracing import SPANS
 
-    pipe = DigestPipeline(max_batch=1, max_inflight=2)
+    def hash_begin(payloads):
+        def collect():
+            return [hashlib.blake2b(p, digest_size=32).digest()
+                    for p in payloads]
+
+        if probe:
+            collect.ready = lambda: True
+        return collect
+
+    pipe = DigestPipeline(hash_begin=hash_begin, max_batch=1,
+                          max_inflight=2)
     got = []
     for i in range(4):
         pipe.submit(b"p%d" % i, got.append)
     pipe.flush()
     assert len(got) == 4
     dispatches = SPANS.spans("digest.dispatch")
-    delivers = SPANS.spans("digest.collect")
-    assert len(dispatches) == 4 and len(delivers) == 4
-    # deliver of batch 0 happens inside dispatch of batch 2 (inflight
-    # bound 2): dispatch[2] opened before deliver[0] closed
-    d2_open = dispatches[2]["ts"]
-    d0_close = delivers[0]["ts"] + delivers[0]["dur"]
-    assert d2_open <= d0_close
+    collects = SPANS.spans("digest.collect")
+    assert len(dispatches) == 4 and len(collects) == 4
+    c0_close = collects[0]["ts"] + collects[0]["dur"]
+    if probe:
+        assert c0_close <= dispatches[1]["ts"]
+    else:
+        assert dispatches[2]["ts"] <= c0_close
 
 
 def test_feed_h2d_overlap_counter(obs_enabled):

@@ -185,3 +185,43 @@ def test_sharded_hash_begin_matches_hashlib_across_buckets():
     got = collect()
     assert got == [hashlib.blake2b(p, digest_size=32).digest()
                    for p in payloads]
+
+
+def test_sharded_hash_begin_closure_answers_the_ready_probe():
+    """The mesh engine's closure offers the same non-blocking probe as
+    the single-device engine's, so the hub's pipeline delivers a sharded
+    batch when the mesh has hashed it: here with no flush and no later
+    dispatch, by the probe of a submit alone."""
+    import time
+
+    from dat_replication_protocol_tpu.backend.tpu_backend import (
+        DigestPipeline,
+    )
+
+    mesh = pmesh.make_mesh(8)
+    payloads = [b"tiny-%d" % i for i in range(5)]
+    want = [hashlib.blake2b(p, digest_size=32).digest() for p in payloads]
+    collect = pmesh.sharded_hash_begin(mesh, payloads)
+    assert isinstance(collect.ready(), bool)  # never blocks, never raises
+    deadline = time.monotonic() + 60.0
+    # true once EVERY device holds its part: collect() may return sooner
+    # (it fetches one replica), the probe only ever errs towards waiting
+    while not collect.ready():
+        assert time.monotonic() < deadline, "the batch never turned ready"
+        time.sleep(0.002)
+    assert collect() == want and collect.ready()
+
+    pipe = DigestPipeline(
+        hash_begin=lambda ps: pmesh.sharded_hash_begin(mesh, ps),
+        max_batch=1 << 20, max_inflight=2)
+    got = []
+    for p in payloads:
+        pipe.submit(p, got.append)
+    pipe.dispatch()
+    deadline = time.monotonic() + 60.0
+    while not got:
+        assert time.monotonic() < deadline, "the batch never turned ready"
+        time.sleep(0.002)  # past the probe's ration
+        pipe.submit(b"later", lambda d: None)
+    assert got == want and pipe.dispatches == 1
+    pipe.flush()

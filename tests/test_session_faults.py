@@ -235,7 +235,12 @@ def test_sweep_fused_pipeline_digests_exactly_once(seed, monkeypatch):
     digests = [e for e in events if e[0] == "digest"]
     keys = [(k, s) for _, k, s, _ in digests]
     assert len(keys) == len(set(keys)), "duplicate digest delivery"
-    assert events == expected  # values byte-identical, order preserved
+    # values byte-identical, order preserved: of the digests and of the
+    # frames.  Where a digest falls between frames is the device's
+    # timing (a batch is delivered once its closure reports ready)
+    assert digests == [e for e in expected if e[0] == "digest"]
+    assert [e for e in events if e[0] != "digest"] \
+        == [e for e in expected if e[0] != "digest"]
 
 
 # -- soak: 200 seeds (slow) -------------------------------------------------

@@ -59,7 +59,7 @@ def test_pipeline_byte_cap_autodispatches():
     pl.submit(b"z" * 60, got.append)
     assert pl.dispatches == 0
     pl.submit(b"z" * 60, got.append)
-    assert pl.dispatches == 1  # device work started, delivery deferred
+    assert pl.dispatches == 1  # the byte cap closed the batch
     pl.flush()
     assert len(got) == 2
 
