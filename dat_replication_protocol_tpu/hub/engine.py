@@ -64,6 +64,10 @@ __all__ = [
 # hub telemetry (OBSERVABILITY.md `hub.*` catalog)
 _M_SESSIONS = _gauge("hub.sessions")
 _M_PARKED = _gauge("hub.parked.bytes")
+# high-water mark of hub.parked.bytes while lit; against
+# hub.parked.budget_bytes (the collector's) it is how near the hub came
+# to closing admission (half) and to shedding (the whole)
+_M_PARKED_PEAK = _gauge("hub.parked.peak_bytes")
 _M_ADMITTED = _counter("hub.admitted")
 _M_REJECTED = _counter("hub.rejected")
 _M_SHED = _counter("hub.shed")
@@ -76,6 +80,9 @@ _H_LATENCY = _histogram("hub.dispatch.latency")
 # plus wait can be held against wall time.  Waits are counted, never
 # bracketed by a stage span (OBSERVABILITY.md)
 _H_WAIT = _histogram("hub.dispatch.wait_s")
+# distinct sessions a composed batch drew from (a count, not seconds)
+_H_SESSIONS = _histogram(
+    "hub.dispatch.sessions", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 1024))
 
 # dispatcher/waiter guarded-fallback period: wakeups are event-driven
 # (condition notifies); the bound only matters if one is ever lost
@@ -555,7 +562,7 @@ class ReplicationHub:
                 if self._oldest_ts is None:
                     self._oldest_ts = time.monotonic()
                 if _OBS.on:
-                    _M_PARKED.set(self._parked_bytes)
+                    self._lit_parked_grew_locked()
                     st.marks.append([time.monotonic(), n])
                 self._maybe_shed_locked()
                 self._check_session_alive_locked(st)
@@ -587,7 +594,7 @@ class ReplicationHub:
                         if self._oldest_ts is None:
                             self._oldest_ts = time.monotonic()
                         if _OBS.on:
-                            _M_PARKED.set(self._parked_bytes)
+                            self._lit_parked_grew_locked()
                             st.marks.append([time.monotonic(), n])
                         self._maybe_shed_locked()
                         self._check_session_alive_locked(st)
@@ -600,6 +607,14 @@ class ReplicationHub:
                     st.cv.wait(_WAKE_FALLBACK)
                     continue
             self._deliver(st, ready)
+
+    def _lit_parked_grew_locked(self) -> None:
+        """Lit only: the two submit paths are where parked bytes grow,
+        so they are where the high-water mark can move."""
+        parked = self._parked_bytes
+        _M_PARKED.set(parked)
+        if parked > _M_PARKED_PEAK.value:
+            _M_PARKED_PEAK.set(parked)
 
     def _flush_session(self, st: _SessionState) -> None:
         """Block until every item this session submitted *before this
@@ -712,6 +727,8 @@ class ReplicationHub:
                     with self._lock:
                         batch = self._compose_locked()
                         engine_flush = self._flush_needed_locked()
+                    if batch and _OBS.on:
+                        _H_SESSIONS.observe(len({id(e[0]) for e in batch}))
                 t0 = time.monotonic()
                 turn_bytes = 0
                 if batch:
@@ -1029,6 +1046,7 @@ class ReplicationHub:
         gauges: dict = {}
         with self._lock:
             gauges["hub.sessions"] = float(len(self._sessions))
+            gauges["hub.parked.budget_bytes"] = float(self.parked_budget)
             for key in self._sessions:
                 st = self._session_state(key)
                 label = f"{{session={key}}}"
