@@ -283,8 +283,8 @@ class DigestPipeline:
         # engines: ``hash_begin(payloads) -> collect()`` is the async
         # interface; a plain ``hash_batch`` callable (tests, custom
         # engines) is wrapped to compute eagerly at dispatch time
-        # a caller's own engine is handed ``bytes`` alone: submit_parts
-        # joins for it
+        # a caller's own engine is handed ``bytes`` alone: submit joins
+        # for it what came in pieces or as a view
         self._joins_parts = hash_begin is not None or hash_batch is not None
         if hash_begin is None and hash_batch is None:
             hash_begin = _device_hash_begin_factory()
@@ -324,10 +324,17 @@ class DigestPipeline:
 
     def submit(self, payload: bytes, on_digest: Callable[[bytes], None],
                tag=None) -> None:
-        """Queue one payload.  ``tag`` (when not None) is passed back as
+        """Queue one payload: ``bytes``, or — from a feeder that holds it
+        as it arrived (:meth:`submit_parts`, the hub's dispatcher) — one
+        view or a :class:`..utils.payload.PayloadParts`, which the pack
+        copies piece by piece; a caller's own engine gets them joined.
+        ``tag`` (when not None) is passed back as
         ``on_digest(tag, digest)`` — a shared bound method + tag costs no
         per-item closure, which matters at the bulk decoder's change
         rates (a lambda per change was ~20% of the digest path)."""
+        if self._joins_parts and type(payload) is not bytes:
+            payload = b"".join(payload.parts) \
+                if type(payload) is PayloadParts else bytes(payload)
         if self._inflight:
             self._poll_ready()
         if _OBS.on and self._fill_t0 is None:
@@ -355,14 +362,10 @@ class DigestPipeline:
         last one that no submitted view covers (headers, other frames)
         count as queued.  A queue then pins at most ``max_batch_bytes``
         and the two slabs at its ends — the first, shared with the batch
-        before, and the one still being filled."""
-        if self._joins_parts:
-            whole = len(parts) == 1 and type(parts[0]) is bytes
-            self.submit(parts[0] if whole else b"".join(parts),
-                        on_digest, tag)
-            return
+        before, and the one still being filled.  (A caller's own engine
+        has the pieces joined by :meth:`submit`, and pins nothing.)"""
         for part in parts:
-            if type(part) is memoryview:
+            if type(part) is memoryview and not self._joins_parts:
                 if part.obj is not self._slab:
                     self._pending_bytes += self._slab_spare
                     self._spare_bytes += self._slab_spare
@@ -531,10 +534,11 @@ class TpuDecoder(Decoder):
                  stream_threshold: int = DEFAULT_STREAM_THRESHOLD, **kwargs):
         super().__init__(**kwargs)
         self._pipeline = pipeline if pipeline is not None else DigestPipeline()
-        # a pipeline that takes a payload in pieces copies each into the
-        # staging row: no join.  One that does not (the hub's session
-        # facade parks payloads against a byte budget, which a pinned
-        # slab would escape) gets ONE bytes per blob
+        # a pipeline that takes a payload in pieces (DigestPipeline, the
+        # hub's session facade: both charge the slabs the views pin
+        # against their byte bounds) copies each into the staging row:
+        # no join.  A caller's own without the entry gets ONE bytes per
+        # blob
         self._submit_parts = getattr(self._pipeline, "submit_parts", None)
         self._digest_cbs: list[OnDigest] = []
         self._change_seq = 0

@@ -16,6 +16,12 @@ engine over it) and multiplexes every registered session onto it:
   in-pipeline + undelivered completions) exceeds its window — a slow
   consumer stalls only its own window; the dispatcher never runs user
   callbacks, so it can never be parked by one.
+* **Parked bytes are charged bytes.**  A blob parks as the pieces it
+  arrived in — views of the receive slabs, copied for the first time by
+  the dispatcher's pack — and a view pins its whole slab.  An entry is
+  charged its payload and the slab bytes its views pin besides
+  (:meth:`HubSession.submit_parts`); the window, admission and the shed
+  policy all read the charged figure.
 * **Weighted-fair batching.**  Each cross-session batch is composed
   round-robin with per-session quotas proportional to ``weight``, then
   greedily filled (work-conserving): a heavy session cannot monopolize
@@ -51,6 +57,8 @@ from ..obs.metrics import (
     gauge as _gauge,
     histogram as _histogram,
 )
+from ..session.decoder import _M_DEC_BLOB_COPIED
+from ..utils.payload import PayloadParts, buffer_address
 from ..utils.trace import span
 
 __all__ = [
@@ -145,7 +153,9 @@ class _SessionState:
         self.weight = weight
         self.nowait = nowait
         self.cv = threading.Condition(lock)
-        self.q: deque = deque()   # (kind, item, cb, tag, nbytes)
+        # (kind, item, cb, tag, nbytes): nbytes is what the entry is
+        # CHARGED — its payload, and the slab spare its views pin
+        self.q: deque = deque()
         # lit: [submit time, items still queued] per submitted run,
         # oldest first — the batch fill clock's view of this queue
         self.marks: deque = deque()
@@ -175,19 +185,31 @@ class _SessionState:
         return self.q_items + self.out_items + self.comp_items
 
 
+# HubSession's slab account before its first view: (slab, address,
+# size, offset past the newest view)
+_NO_SLAB = (None, 0, 0, 0)
+
+
 class HubSession:
     """A session's handle on the hub — and a drop-in ``pipeline`` for
     :class:`~..backend.tpu_backend.TpuDecoder` / ``TpuEncoder``: the
-    same ``submit`` / ``submit_stream`` / ``flush`` surface as
-    :class:`~..backend.tpu_backend.DigestPipeline`, with the work
-    coalesced across sessions behind it.  Completions are delivered on
-    the session's OWN thread (inside ``submit``/``flush``), in submit
-    order, so a callback that blocks — the sidecar's reply backpressure
-    — parks only this session."""
+    same ``submit`` / ``submit_parts`` / ``submit_stream`` / ``flush``
+    surface as :class:`~..backend.tpu_backend.DigestPipeline`, with the
+    work coalesced across sessions behind it.  A payload parks as it
+    was handed over — ``bytes``, or the pieces it arrived in — and the
+    dispatcher passes it to the shared pipeline untouched.  Completions
+    are delivered on the session's OWN thread (inside
+    ``submit``/``flush``), in submit order, so a callback that blocks —
+    the sidecar's reply backpressure — parks only this session."""
 
     def __init__(self, hub: "ReplicationHub", state: _SessionState):
         self._hub = hub
         self._state = state
+        # submit_parts' account of the slab its newest parked view lies
+        # in (the submitting thread's alone): the slab, its address and
+        # size, and the offset just past that view.  What lies beyond is
+        # not known to be spare until the views move on
+        self._slab = _NO_SLAB
 
     @property
     def key(self) -> str:
@@ -202,6 +224,56 @@ class HubSession:
             self._state,
             (("payload", payload, on_digest, tag, len(payload)),),
             len(payload))
+
+    def submit_parts(self, parts, on_digest: Callable, tag=None) -> None:
+        """Park one payload that arrived in pieces — ``bytes`` objects
+        or views of receive slabs, in order — without joining them: the
+        dispatcher's pack is the first place its bytes are copied, and
+        the pieces are dropped there.
+
+        A view pins its whole slab, so the entry is charged what its
+        views pin besides the payload: the slab bytes in front of each
+        view that no parked view of this session covers (headers, other
+        frames), and, when the views move on to another slab, what was
+        left of the last one behind its newest view.  A session then
+        pins at most what it is charged and the slabs at its two ends —
+        the tail of the newest, unknown until the views move on, and
+        the head of the oldest, where a delivered entry's views lay.
+
+        A payload whose views would pin more spare than they carry
+        (an attachment among change frames) is joined here instead: one
+        copy, counted in ``decoder.blob.copied.bytes``, and no slab
+        held for it."""
+        carried = left = gaps = 0
+        slab, at, size, end = self._slab
+        for part in parts:
+            n = len(part)
+            carried += n
+            if n and type(part) is memoryview:
+                a = buffer_address(part)
+                if part.obj is not slab or a < at + end:
+                    # another slab (or the same memory written anew)
+                    left += size - end
+                    slab = part.obj
+                    at, size, end = (buffer_address(slab),
+                                     memoryview(slab).nbytes, 0)
+                gaps += a - at - end
+                end = a - at + n
+        if gaps > carried:
+            item = b"".join(parts)
+            if _OBS.on:
+                _M_DEC_BLOB_COPIED.inc(carried)
+            charged = carried  # no view parks: the account stays put
+        else:
+            if len(parts) == 1:
+                item = parts[0]  # whole already: bytes, or one view
+            else:
+                item = PayloadParts(parts) if parts else b""
+            self._slab = (slab, at, size, end)
+            charged = carried + left + gaps
+        self._hub._submit_run(
+            self._state, (("payload", item, on_digest, tag, charged),),
+            charged)
 
     def submit_many(self, payloads, on_digest: Callable,
                     tag_base: int = 0) -> None:
@@ -269,6 +341,7 @@ class HubSession:
     def close(self) -> None:
         """Unregister; queued work is dropped, in-flight completions are
         discarded on arrival.  Idempotent."""
+        self._slab = _NO_SLAB
         self._hub._unregister(self._state)
 
     def stats(self) -> dict:
@@ -499,16 +572,7 @@ class ReplicationHub:
             if st.gone:
                 return
             st.gone = True
-            # queued + undelivered completions leave the parked set now;
-            # in-pipeline bytes leave as their completions route back
-            self._q_items -= st.q_items
-            self._q_bytes -= st.q_bytes
-            self._parked_bytes -= st.q_bytes + st.comp_bytes
-            st.q.clear()
-            st.marks.clear()
-            st.q_items = st.q_bytes = 0
-            st.comp.clear()
-            st.comp_items = st.comp_bytes = 0
+            self._drop_parked_locked(st)
             if self._sessions.get(st.key) is st:
                 del self._sessions[st.key]
             st.cv.notify_all()
@@ -522,6 +586,26 @@ class ReplicationHub:
                   **{k: v for k, v in done_stats.items()
                      if k in ("submitted", "delivered", "submitted_bytes",
                               "dispatches")})
+
+    def _drop_queued_locked(self, st: _SessionState) -> None:
+        """Nothing will dispatch what ``st`` has queued: the entries,
+        and the slabs their views pin, leave the parked set now."""
+        self._q_items -= st.q_items
+        self._q_bytes -= st.q_bytes
+        self._parked_bytes -= st.q_bytes
+        st.q.clear()
+        st.marks.clear()
+        st.q_items = st.q_bytes = 0
+
+    def _drop_parked_locked(self, st: _SessionState) -> None:
+        """A session that stopped listening (closed or shed): queued
+        entries and undelivered completions leave the parked set now;
+        in-pipeline bytes leave as their (discarded) completions route
+        back."""
+        self._drop_queued_locked(st)
+        self._parked_bytes -= st.comp_bytes
+        st.comp.clear()
+        st.comp_items = st.comp_bytes = 0
 
     # -- session-side paths (run on the session's own thread) ---------------
 
@@ -730,25 +814,8 @@ class ReplicationHub:
                     if batch and _OBS.on:
                         _H_SESSIONS.observe(len({id(e[0]) for e in batch}))
                 t0 = time.monotonic()
-                turn_bytes = 0
-                if batch:
-                    if self._batch_oldest is not None:
-                        # lit: the batch's fill clock started in the
-                        # sessions' queues, not at the pipeline's door
-                        mark = getattr(self._pipeline, "mark_fill", None)
-                        if mark is not None:
-                            mark(self._batch_oldest)
-                    with span("hub.submit", items=len(batch)):
-                        for entry_st, kind, item, cb, tag, nbytes in batch:
-                            routed = (entry_st, cb, tag, nbytes)
-                            if kind == "payload":
-                                self._pipeline.submit(item, self._route,
-                                                      routed)
-                            else:
-                                self._pipeline.submit_stream(
-                                    item, self._route, routed)
-                            turn_bytes += nbytes
-                    self._pipeline.dispatch()
+                items = len(batch)
+                turn_bytes = self._hand_over(batch) if items else 0
                 with self._lock:
                     drain_idle = (self._q_items == 0
                                   and self._pipeline.inflight > 0)
@@ -760,14 +827,14 @@ class ReplicationHub:
                 if self._routed:
                     with span("hub.distribute", items=len(self._routed)):
                         self._distribute_routed()
-                if batch or engine_flush:
+                if items or engine_flush:
                     latency = time.monotonic() - t0
                     self._lat_ring.append(latency)
                     if _OBS.on:
                         _H_LATENCY.observe(latency)
-                        if batch:
+                        if items:
                             _M_BATCHES.inc()
-                            _M_ITEMS.inc(len(batch))
+                            _M_ITEMS.inc(items)
                             _M_BYTES.inc(turn_bytes)
                     ordered = sorted(self._lat_ring)
                     p99 = ordered[min(len(ordered) - 1,
@@ -784,6 +851,31 @@ class ReplicationHub:
                 for key in list(self._sessions):
                     self._session_state(key).cv.notify_all()
                 self._work.notify_all()
+
+    def _hand_over(self, batch: list) -> int:
+        """A composed batch into the pipeline, and its dispatch; returns
+        the bytes the batch was charged.  The entries are consumed: a
+        payload parked as views of receive slabs is copied for the
+        first time by the pipeline's pack, and nothing of the hub's —
+        not the batch, not this frame — refers to it after that."""
+        if self._batch_oldest is not None:
+            # lit: the batch's fill clock started in the sessions'
+            # queues, not at the pipeline's door
+            mark = getattr(self._pipeline, "mark_fill", None)
+            if mark is not None:
+                mark(self._batch_oldest)
+        turn_bytes = 0
+        with span("hub.submit", items=len(batch)):
+            for entry_st, kind, item, cb, tag, nbytes in batch:
+                routed = (entry_st, cb, tag, nbytes)
+                if kind == "payload":
+                    self._pipeline.submit(item, self._route, routed)
+                else:
+                    self._pipeline.submit_stream(item, self._route, routed)
+                turn_bytes += nbytes
+        batch.clear()
+        self._pipeline.dispatch()
+        return turn_bytes
 
     def _idle_wait_locked(self) -> None:
         """One bounded wait for work.  Lit, its seconds are counted
@@ -948,16 +1040,7 @@ class ReplicationHub:
         held = st.parked_bytes
         st.shed = reason
         st.shed_parked = held
-        # queued + undelivered leave the parked set now; in-pipeline
-        # bytes leave as their (discarded) completions route back
-        self._q_items -= st.q_items
-        self._q_bytes -= st.q_bytes
-        self._parked_bytes -= st.q_bytes + st.comp_bytes
-        st.q.clear()
-        st.marks.clear()
-        st.q_items = st.q_bytes = 0
-        st.comp.clear()
-        st.comp_items = st.comp_bytes = 0
+        self._drop_parked_locked(st)
         st.cv.notify_all()
         if _OBS.on:
             _M_SHED.inc()
@@ -1071,6 +1154,13 @@ class ReplicationHub:
             thread = self._thread
         if thread is not None:
             thread.join(timeout=5)
+        with self._lock:
+            # the dispatcher is gone; completions already routed stay
+            # for their sessions' last poll
+            for key in list(self._sessions):
+                self._drop_queued_locked(self._session_state(key))
+            if _OBS.on:
+                _M_PARKED.set(self._parked_bytes)
         _REGISTRY.unregister_collector("hub", self._collector_fn)
 
     def __enter__(self) -> "ReplicationHub":

@@ -3,6 +3,8 @@ and the staging row."""
 
 from __future__ import annotations
 
+import numpy as np
+
 
 class PayloadParts:
     """One payload as the pieces it arrived in, in order: ``bytes``
@@ -21,3 +23,10 @@ class PayloadParts:
 
     def __len__(self) -> int:
         return self.nbytes
+
+
+def buffer_address(buf) -> int:
+    """Where a buffer's first byte lies in memory: a view's address
+    less its slab's is the view's offset in the slab, which a
+    ``memoryview`` does not say."""
+    return np.frombuffer(buf, np.uint8).__array_interface__["data"][0]

@@ -449,8 +449,10 @@ def test_hub_dark_turn_names_no_wait_clock():
     assert "span" in loop.co_names
     wait = hub_engine.ReplicationHub._idle_wait_locked
     assert _gated_on_obs(wait, "_H_WAIT")
-    consts = {c for code in _codes(loop) for c in code.co_consts
-              if isinstance(c, str)}
+    hand_over = hub_engine.ReplicationHub._hand_over.__code__
+    assert "_H_WAIT" not in hand_over.co_names
+    consts = {c for turn in (loop, hand_over) for code in _codes(turn)
+              for c in code.co_consts if isinstance(c, str)}
     assert {"hub.compose", "hub.submit", "hub.distribute"} <= consts
 
 
