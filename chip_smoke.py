@@ -480,17 +480,30 @@ def check_on_device(stage: str, rep: dict, items: int, big_bytes: int,
         return
     d = rep["device"]
     need(stage, d["platform"] == "tpu"
-         and d["engine"] in ("device-batch", "mesh-sharded"),
+         and d["engine"] in ("device-batch", "device-batch-mesh"),
          f"sidecar resolved {d}")
     need(stage, rep["h2d_bytes"] > 0 and rep["d2h_bytes"] > 0,
          "no H2D/D2H bytes counted")
     need(stage, rep["host_engine_bytes"] == 0,
          f"{rep['host_engine_bytes']} bytes went through the host engine")
-    if d["engine"] == "device-batch":
-        in_buckets = sum(r["items"] for r in rep["blake2b_buckets"].values())
-        need(stage, in_buckets == items,
-             f"{in_buckets} items in device buckets, {items} batched items "
-             f"sent")
+    # both engines end in the one bucket table (since ISSUE 35 the mesh
+    # engine is the served one laid over the chips)
+    in_buckets = sum(r["items"] for r in rep["blake2b_buckets"].values())
+    need(stage, in_buckets == items,
+         f"{in_buckets} items in device buckets, {items} batched items "
+         f"sent")
+    if d["engine"] == "device-batch-mesh":
+        # the mesh arm once hashed with the XLA scan on every chip and
+        # noted no bucket at all; that must not come back unseen
+        engines = {k.split(":")[0] for k in rep["blake2b_buckets"]}
+        need(stage, engines == {"pallas"},
+             f"engines that served the mesh hub's buckets: "
+             f"{sorted(engines)} ({rep['blake2b_buckets']})")
+        n = d["mesh_devices"]
+        odd = {k: r for k, r in rep["blake2b_buckets"].items()
+               if r["padded_items"] % (n * 32)}
+        need(stage, not odd,
+             f"bucket rows not a whole tile on each of {n} chips: {odd}")
 
 
 def refuse_without_tpu(stage: str, device: dict, dry: bool) -> None:
@@ -565,7 +578,7 @@ def stage_hub(seed: int, sizes: dict, dry: bool, mesh: bool = False) -> dict:
         device = sc.wait_ready()
         refuse_without_tpu(stage, device, dry)
         if mesh and not dry:
-            need(stage, device["engine"] == "mesh-sharded"
+            need(stage, device["engine"] == "device-batch-mesh"
                  and device["mesh_devices"] > 1, f"sidecar resolved {device}")
         # connect every session first: the hub refuses NEW sessions once
         # half its parked budget is in use
@@ -821,7 +834,7 @@ def child_mesh(seed: int, sizes: dict, dry: bool) -> None:
     import __graft_entry__ as graft
 
     from dat_replication_protocol_tpu.parallel import make_mesh
-    from dat_replication_protocol_tpu.parallel.mesh import sharded_hash_begin
+    from dat_replication_protocol_tpu.parallel.mesh import sharded_hash_engine
 
     n = device["device_count"]
     while n & (n - 1):
@@ -839,7 +852,7 @@ def child_mesh(seed: int, sizes: dict, dry: bool) -> None:
                                mesh, jax.sharding.PartitionSpec("data")))
     homes = {s.device.id for s in shard.addressable_shards}
     assert len(homes) == n, f"shards sit on {len(homes)} of {n} devices"
-    assert sharded_hash_begin(mesh, payloads)() == [_h(p) for p in payloads]
+    assert sharded_hash_engine(mesh)(payloads)() == [_h(p) for p in payloads]
     _child_epilogue({"device": device, "mesh_devices": n,
                      "shard_devices": sorted(homes)}, t0)
 

@@ -269,7 +269,11 @@ class DigestPipeline:
     (non-blocking: would ``collect()`` return without waiting for the
     device).  A closure with no ``ready`` is delivered by the bound or by
     ``flush()`` alone; an engine given as ``hash_batch`` has its result
-    at dispatch and is delivered there.
+    at dispatch and is delivered there.  The ``hash_begin`` callable
+    itself may carry ``takes_parts = True``: its payloads then arrive as
+    they were submitted (``bytes``, one view, ``PayloadParts``) and it
+    joins them where it copies them; without the mark a caller's own
+    engine gets ``bytes``.
     """
 
     def __init__(
@@ -283,9 +287,14 @@ class DigestPipeline:
         # engines: ``hash_begin(payloads) -> collect()`` is the async
         # interface; a plain ``hash_batch`` callable (tests, custom
         # engines) is wrapped to compute eagerly at dispatch time
-        # a caller's own engine is handed ``bytes`` alone: submit joins
-        # for it what came in pieces or as a view
-        self._joins_parts = hash_begin is not None or hash_batch is not None
+        # whether an engine takes a payload in pieces is observed of
+        # the engine (``hash_begin.takes_parts``: the served batch
+        # engine, alone or laid over a mesh, says so); a caller's own
+        # without the mark is handed ``bytes`` alone: submit joins for
+        # it what came in pieces or as a view
+        self._joins_parts = (
+            hash_begin is not None or hash_batch is not None
+        ) and not getattr(hash_begin, "takes_parts", False)
         if hash_begin is None and hash_batch is None:
             hash_begin = _device_hash_begin_factory()
         if hash_begin is None:

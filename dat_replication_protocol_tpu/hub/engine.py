@@ -355,10 +355,12 @@ class HubSession:
 
 
 def _mesh_hash_begin_factory(n_devices: Optional[int] = None):
-    """The cross-session mesh engine: shard the coalesced hash batch
-    over the device mesh with batch-dim ``NamedSharding`` (SNIPPETS.md
-    idiom).  ``n_devices=None`` takes the largest power of two of the
-    visible devices (the mesh layer's constraint).
+    """The cross-session mesh engine: the served batch engine with the
+    coalesced batch's rows laid over the device mesh
+    (:func:`..parallel.mesh.sharded_hash_engine`: batch-dim
+    ``NamedSharding``, every chip hashing its shard, no exchange).
+    ``n_devices=None`` takes the largest power of two of the visible
+    devices (the mesh layer's constraint).
 
     Returns ``(devices, hash_begin)``, or None only where the routing
     layer observes a host platform
@@ -387,8 +389,8 @@ def _mesh_hash_begin_factory(n_devices: Optional[int] = None):
     if _OBS.on:
         from ..obs.device import note_engine as _note_engine
 
-        _note_engine("digest.hash", "mesh-sharded", devices=n)
-    return n, lambda payloads: pmesh.sharded_hash_begin(m, payloads)
+        _note_engine("digest.hash", "device-batch-mesh", devices=n)
+    return n, pmesh.sharded_hash_engine(m)
 
 
 class ReplicationHub:
@@ -1130,6 +1132,8 @@ class ReplicationHub:
         with self._lock:
             gauges["hub.sessions"] = float(len(self._sessions))
             gauges["hub.parked.budget_bytes"] = float(self.parked_budget)
+            if self.mesh_devices:
+                gauges["hub.mesh.devices"] = float(self.mesh_devices)
             for key in self._sessions:
                 st = self._session_state(key)
                 label = f"{{session={key}}}"

@@ -554,7 +554,17 @@ def _lay_parts(flat: memoryview, off: int, payload: PayloadParts) -> None:
         off = end
 
 
-def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
+def dealt_rows(n_items: int, per: int, shards: int) -> np.ndarray:
+    """The row of each item when a bucket's items are dealt round
+    ``shards`` equal shards of ``per`` rows: item ``i`` goes to shard
+    ``i % shards``, its slot ``i // shards`` — so every chip of a mesh
+    holds payload from the first few items on, not the first chip all
+    of a small batch."""
+    i = np.arange(n_items)
+    return (i % shards) * per + i // shards
+
+
+def stage_payloads(payloads, buf: np.ndarray, shards: int = 1) -> np.ndarray:
     """Lay ``payloads`` into the rows of ``buf`` ((rows, nblocks*128)
     uint8, contents arbitrary): one ``memcpy`` per payload — per piece
     of one held as its pieces (:class:`..utils.payload.PayloadParts`:
@@ -562,6 +572,9 @@ def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
     the row's tail and the rows beyond ``len(payloads)`` (batch
     padding), which is the zero-padding contract of
     :func:`blake2b_packed`.  Returns the ``(rows,)`` uint32 lengths.
+    Item ``i`` lies in row ``i``; with ``shards`` > 1 (the rows divided
+    over a mesh's chips) in row :func:`dealt_rows` gives it, the same
+    copies and as many bytes zeroed.
 
     THE routine that puts payload bytes into staging rows:
     :func:`blake2b_batch_begin` ships ``buf`` viewed ``<u4`` as it is
@@ -579,8 +592,11 @@ def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
             f"nblocks={width // BLOCK_BYTES} < required "
             f"{-(-longest // BLOCK_BYTES)}")
     lengths = np.zeros((rows,), dtype=np.uint32)
-    lengths[:n_items] = lens
     flat = memoryview(buf.reshape(-1))
+    if shards > 1:
+        _lay_dealt(payloads, lens, buf, flat, lengths, shards)
+        return lengths
+    lengths[:n_items] = lens
     off = 0
     if width <= _FILL_WHOLE_MAX:
         buf.fill(0)
@@ -602,6 +618,37 @@ def stage_payloads(payloads, buf: np.ndarray) -> np.ndarray:
             off += width
         buf[n_items:] = 0
     return lengths
+
+
+def _lay_dealt(payloads, lens, buf, flat, lengths, shards: int) -> None:
+    """:func:`stage_payloads` for rows divided into ``shards``.  The
+    padding rows are four blocks, one a shard, and are zeroed by ONE
+    numpy fill, BEFORE the copies: a large fill releases the interpreter
+    lock, and taking it back from a busy thread (the edge loop) costs up
+    to a switch interval each time — four fills read 32 ms against 17
+    for one beside a busy thread (PERF.md section 6, PR 35)."""
+    rows, width = buf.shape
+    per = rows // shards
+    n_items = len(payloads)
+    at = dealt_rows(n_items, per, shards)
+    lengths[at] = lens
+    whole = width <= _FILL_WHOLE_MAX
+    if whole:
+        buf.fill(0)
+    else:
+        # every shard's rows from the last slot that not every shard
+        # fills: the items dealt that slot are laid over the zeros
+        full_slots = n_items // shards
+        buf.reshape(shards, per, width)[:, full_slots:, :] = 0
+    for r, p in zip(at.tolist(), payloads):
+        off = r * width
+        n = len(p)
+        if type(p) is PayloadParts:
+            _lay_parts(flat, off, p)
+        else:
+            flat[off:off + n] = p
+        if n < width and not whole:
+            buf[r, n:] = 0
 
 
 def pack_payloads(payloads, nblocks: int | None = None):
@@ -677,8 +724,48 @@ def batch_rows(n_items: int, nblocks: int) -> int:
     return max(declared_rows(nblocks)[0], _bucket_nblocks(n_items))
 
 
+def shard_rows(n_items: int, nblocks: int, n_devices: int) -> int:
+    """A chip's rows when a bucket is laid over ``n_devices`` chips:
+    the declared policy applied per chip — the smallest declared row
+    count that holds the most items any chip is dealt
+    (:func:`dealt_rows`: ``ceil(n_items / n_devices)``).  A shard is
+    never under the kernel's smallest tile, and a bucket of a given
+    slot width meets the same handful of shapes whatever its item
+    count."""
+    return batch_rows(-(-n_items // n_devices), nblocks)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_words_program(mesh, use_pallas: bool, donate: bool,
+                           digest_size: int):
+    """The bucket's one program laid over ``mesh`` (1-D): every chip
+    runs the single-device words program — the Pallas kernel on a TPU,
+    the scan elsewhere — on its shard of the rows, inputs and outputs
+    sharded over the batch axis, no collective.  Cached per mesh so that
+    a bucket shape traces once; its name, not ``jit_blake2b*``, is what
+    tells a sharded run from a one-chip run in a device trace."""
+    from jax.sharding import PartitionSpec as P
+
+    if use_pallas:
+        from .blake2b_pallas import blake2b_words_pallas as body
+    else:
+        body = blake2b_words
+    rows = P(mesh.axis_names[0])
+
+    def mesh_blake2b_words(words, lengths):
+        return jax.shard_map(
+            lambda w, n: body(w, n, digest_size), mesh=mesh,
+            in_specs=(rows, rows), out_specs=(rows, rows),
+            check_vma=False)(words, lengths)
+
+    return _jit_site(
+        "ops.blake2b.mesh_words",
+        jax.jit(mesh_blake2b_words, donate_argnums=(0,) if donate else ()))
+
+
 def blake2b_batch_begin(
-    payloads, digest_size: int = DIGEST_SIZE, use_pallas: bool | None = None
+    payloads, digest_size: int = DIGEST_SIZE, use_pallas: bool | None = None,
+    mesh=None,
 ):
     """Dispatch batched hashing; return a zero-arg ``collect()`` closure.
 
@@ -703,17 +790,60 @@ def blake2b_batch_begin(
     on the device, inside the bucket's one program
     (:func:`split_words`).  Staging buffers come from a small
     process-wide pool (:class:`_StagePool`).
+
+    ``mesh`` (a 1-D :class:`jax.sharding.Mesh`; None: one device) lays
+    every bucket over the mesh's chips — the same staging, the same
+    spans and bucket table, one engine in two layouts: the row count is
+    ``n`` chips x a per-chip declared count (:func:`shard_rows`), items
+    are dealt round the chips (:func:`dealt_rows`: every chip hashes
+    payload, whatever the batch), the one ``device_put`` carries a
+    batch-dim ``NamedSharding``, and each chip runs the words program on
+    its shard with no exchange (:func:`_sharded_words_program`).  The
+    digests come back whole and the items' rows are picked on the host.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     donate = donation_supported()
-    if use_pallas:
-        if donate:
-            from .blake2b_pallas import blake2b_words_pallas_donated as words_fn
+    # the two layouts differ in four places, gathered here: the rows a
+    # bucket is staged at, how the staging is shipped, how the program
+    # is called, and which of its rows are the items' (``at``)
+    if mesh is None:
+        n_dev = 1
+        over: dict = {}  # what the spans say beside items and nblocks
+        if use_pallas:
+            if donate:
+                from .blake2b_pallas import (
+                    blake2b_words_pallas_donated as words_fn)
+            else:
+                from .blake2b_pallas import blake2b_words_pallas as words_fn
         else:
-            from .blake2b_pallas import blake2b_words_pallas as words_fn
+            words_fn = blake2b_words_donated if donate else blake2b_words
+
+        def put(words, lengths):
+            return jax.device_put(words), lengths
+
+        def run(words_d, lengths, n):
+            hh, hl = words_fn(words_d, jnp.asarray(lengths), digest_size)
+            return hh, hh[:n], hl[:n]
     else:
-        words_fn = blake2b_words_donated if donate else blake2b_words
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        n_dev = mesh.devices.size
+        over = {"devices": n_dev}
+        by_rows = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
+        words_fn = _sharded_words_program(mesh, use_pallas, donate,
+                                          digest_size)
+
+        def put(words, lengths):
+            # ONE call ships every chip its shard of the rows and of
+            # the lengths
+            return jax.device_put((words, lengths), by_rows)
+
+        def run(words_d, lengths, n):
+            # fetched whole, the items' rows picked in collect(): a
+            # slice of a sharded array is a program per item count
+            hh, hl = words_fn(words_d, lengths)
+            return hh, hh, hl
     engine = "pallas" if use_pallas else "xla-scan"
     if _OBS.on:
         _note_engine("blake2b.batch", engine, items=len(payloads))
@@ -725,14 +855,16 @@ def blake2b_batch_begin(
     for nb, idxs in buckets.items():
         # rows beyond the items are empty payloads: valid, and their
         # digests are dropped in collect()
-        Bp = batch_rows(len(idxs), nb)
+        per = shard_rows(len(idxs), nb, n_dev)
+        Bp = n_dev * per
+        at = None if mesh is None else dealt_rows(len(idxs), per, n_dev)
         if _OBS.on:
             _BUCKETS.note(engine, nb, len(idxs), Bp)
         # one copy per item into a (Bp, nb*128) byte buffer, shipped as
         # raw <u4 words: the hi/lo split is the program's first step
-        with span("digest.pack", items=len(idxs), nblocks=nb):
+        with span("digest.pack", items=len(idxs), nblocks=nb, **over):
             buf = _STAGE_POOL.take((Bp, nb * BLOCK_BYTES))
-            lengths = stage_payloads([payloads[i] for i in idxs], buf)
+            lengths = stage_payloads([payloads[i] for i in idxs], buf, n_dev)
             words = buf.view("<u4")
         if _OBS.on:
             _M_H2D.inc(words.nbytes + lengths.nbytes)
@@ -742,20 +874,21 @@ def blake2b_batch_begin(
         # successive batches recycle staging HBM instead of growing the
         # live set.  The span is the HOST's time inside the call, not
         # the link's.
-        with span("digest.h2d", items=len(idxs), nblocks=nb):
-            words_d = jax.device_put(words)
-        with span("digest.launch", items=len(idxs), nblocks=nb):
-            hh, hl = words_fn(words_d, jnp.asarray(lengths), digest_size)
-            # hh ready => the program ran => the transfer out of buf is over
-            _STAGE_POOL.give(buf, hh)
-            handles.append((idxs, hh[: len(idxs)], hl[: len(idxs)]))
+        with span("digest.h2d", items=len(idxs), nblocks=nb, **over):
+            words_d, lengths = put(words, lengths)
+        with span("digest.launch", items=len(idxs), nblocks=nb, **over):
+            fence, hh, hl = run(words_d, lengths, len(idxs))
+            # fence ready => the program ran => the transfer out of buf
+            # is over
+            _STAGE_POOL.give(buf, fence)
+            handles.append((idxs, hh, hl, at))
 
     def start_d2h() -> None:
         # begin the digest readback WITHOUT blocking: the copies queue
         # behind the programs, so by collect() time the words are local
         # or on their way.  Idempotent; the DigestPipeline calls this at
         # the end of the batch's own dispatch.
-        for _, hh, hl in handles:
+        for _, hh, hl, _ in handles:
             hh.copy_to_host_async()
             hl.copy_to_host_async()
 
@@ -763,21 +896,24 @@ def blake2b_batch_begin(
         # non-blocking: has the device produced every bucket's digests
         # (what the staging pool asks of its fences).  True means
         # collect() waits for no program, at most for a readback's tail.
-        return all(hh.is_ready() and hl.is_ready() for _, hh, hl in handles)
+        return all(hh.is_ready() and hl.is_ready()
+                   for _, hh, hl, _ in handles)
 
     n_items = len(payloads)  # the closures below keep no payload alive
 
     def collect() -> list[bytes]:
         out: list[bytes | None] = [None] * n_items
-        for idxs, hh, hl in handles:
+        for idxs, hh, hl, at in handles:
             if _OBS.on:
                 # two (B, 8) u32 halves fetched per bucket = 64 B/item
                 _M_D2H.inc(64 * len(idxs))
             # the one place the host legitimately waits for the device
-            with span("digest.d2h_wait", items=len(idxs)):
+            with span("digest.d2h_wait", items=len(idxs), **over):
                 hh = np.asarray(hh)
                 hl = np.asarray(hl)
-            with span("digest.unpack", items=len(idxs)):
+            with span("digest.unpack", items=len(idxs), **over):
+                if at is not None:
+                    hh, hl = hh[at], hl[at]
                 for i, d in zip(idxs, digests_to_bytes(hh, hl, digest_size)):
                     out[i] = d
         return out  # type: ignore[return-value]
@@ -785,6 +921,12 @@ def blake2b_batch_begin(
     collect.start_d2h = start_d2h  # type: ignore[attr-defined]
     collect.ready = ready  # type: ignore[attr-defined]
     return collect
+
+
+# what a pipeline observes of an engine (DigestPipeline): this one takes
+# a payload as it arrived — bytes, a view, PayloadParts — and the pack
+# is where the pieces are joined
+blake2b_batch_begin.takes_parts = True  # type: ignore[attr-defined]
 
 
 def blake2b_batch(

@@ -29,19 +29,12 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..obs.device import jit_site as _jit_site
-from ..obs.metrics import OBS as _OBS
-from ..obs.metrics import counter as _counter
 from ..ops import merkle
-from ..ops.blake2b import blake2b_packed
+from ..ops.blake2b import blake2b_batch_begin, blake2b_packed
 from ..ops.u64 import U32
 
 
 DATA_AXIS = "data"
-
-# the mesh engine's share of the device-transfer counters the
-# single-device batch edge keeps (ops/blake2b.py)
-_M_H2D = _counter("device.h2d.bytes")
-_M_D2H = _counter("device.d2h.bytes")
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
@@ -177,91 +170,18 @@ def digest_root_step(mesh: Mesh, mh, ml, lengths):
     return leaf_hh, leaf_hl, root_hh, root_hl, total
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_hash_program(mesh: Mesh):
-    """Jitted hash-only sharded step, cached per mesh: the cross-session
-    digest batch (ISSUE 8) needs no Merkle fold or collectives at all —
-    every chip hashes its shard of the batch axis and the results stay
-    sharded, so the whole program is communication-free."""
-
-    def step(mh, ml, lengths):
-        return blake2b_packed(mh, ml, lengths)
-
-    sharded = P(DATA_AXIS)
-    return _jit_site(
-        "parallel.mesh.sharded_hash",
-        jax.jit(
-            jax.shard_map(
-                step,
-                mesh=mesh,
-                in_specs=(sharded, sharded, sharded),
-                out_specs=(sharded, sharded),
-                check_vma=False,
-            )
-        ),
-    )
-
-
-def sharded_hash_begin(mesh: Mesh, payloads, digest_size: int = 32):
-    """Dispatch one cross-session payload batch sharded over the mesh;
-    returns a zero-arg ``collect()`` closure (``.start_d2h``, ``.ready``) —
-    the same async contract as :func:`..ops.blake2b.blake2b_batch_begin`,
-    so the hub's shared :class:`~..backend.tpu_backend.DigestPipeline`
-    can use either engine interchangeably.
-
-    Items are bucketed by power-of-two block count (bounded compile
-    count, same policy as the single-device engine); each bucket's batch
-    axis is padded to ``n_devices * 2**k`` and uploaded with a batch-dim
-    :class:`~jax.sharding.NamedSharding` so every chip receives only its
-    shard over the interconnect and hashes it locally — the multiplexed
-    sessions' combined digest work is what can fill a mesh that any
-    single session's batch rarely could.
-    """
-    from ..utils.num import next_pow2
-
-    from ..ops.blake2b import BLOCK_BYTES, digests_to_bytes, pack_payloads
-
-    n = mesh.devices.size
-    spec = batch_sharding(mesh)
-    buckets: dict[int, list[int]] = {}
-    for i, p in enumerate(payloads):
-        nb = next_pow2(max(1, -(-len(p) // BLOCK_BYTES)))
-        buckets.setdefault(nb, []).append(i)
-    fn = _sharded_hash_program(mesh)
-    handles = []
-    for nb, idxs in buckets.items():
-        batch = [payloads[i] for i in idxs]
-        Bp = n * next_pow2(-(-len(batch) // n))
-        batch += [b""] * (Bp - len(batch))
-        mh, ml, lengths = pack_payloads(batch, nblocks=nb)
-        if _OBS.on:
-            _M_H2D.inc(mh.nbytes + ml.nbytes + lengths.nbytes)
-        mh_d = jax.device_put(mh, spec)
-        ml_d = jax.device_put(ml, spec)
-        len_d = jax.device_put(lengths, spec)
-        hh, hl = fn(mh_d, ml_d, len_d)
-        handles.append((idxs, hh[: len(idxs)], hl[: len(idxs)]))
-
-    def start_d2h() -> None:
-        for _, hh, hl in handles:
-            hh.copy_to_host_async()
-            hl.copy_to_host_async()
-
-    def ready() -> bool:
-        return all(hh.is_ready() and hl.is_ready() for _, hh, hl in handles)
-
-    def collect() -> list[bytes]:
-        out: list[bytes | None] = [None] * len(payloads)
-        for idxs, hh, hl in handles:
-            if _OBS.on:
-                _M_D2H.inc(64 * len(idxs))  # two (B, 8) u32 halves
-            for i, d in zip(idxs, digests_to_bytes(hh, hl, digest_size)):
-                out[i] = d
-        return out  # type: ignore[return-value]
-
-    collect.start_d2h = start_d2h  # type: ignore[attr-defined]
-    collect.ready = ready  # type: ignore[attr-defined]
-    return collect
+def sharded_hash_engine(mesh: Mesh):
+    """The served batch engine laid over ``mesh``, as a ``hash_begin``
+    for :class:`~..backend.tpu_backend.DigestPipeline`: the hub's
+    cross-session digest batch (ISSUE 8) needs no Merkle fold and no
+    collective, so it is :func:`..ops.blake2b.blake2b_batch_begin`
+    itself — pooled staging, one copy per piece, the Pallas kernel on a
+    chip, the stage spans and the bucket table — given the mesh to lay
+    each bucket's rows over.  Like that engine it takes a payload in
+    pieces (``takes_parts``), so the pipeline joins nothing for it."""
+    begin = functools.partial(blake2b_batch_begin, mesh=mesh)
+    begin.takes_parts = blake2b_batch_begin.takes_parts
+    return begin
 
 
 @functools.lru_cache(maxsize=None)

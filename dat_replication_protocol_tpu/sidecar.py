@@ -1411,7 +1411,10 @@ def main(argv=None) -> int:
                              parked_budget=args.hub_parked_budget)
         set_active_hub(hub)
         if hub.mesh_devices:
-            device_rec = dict(device_rec, engine="mesh-sharded",
+            # the served batch engine laid over the host's chips (not
+            # the private scan engine a mesh hub ran before ISSUE 35,
+            # which called itself "mesh-sharded")
+            device_rec = dict(device_rec, engine="device-batch-mesh",
                               mesh_devices=hub.mesh_devices)
     set_device_record(device_rec)
     print("sidecar: device " + " ".join(

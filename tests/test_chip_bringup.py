@@ -379,16 +379,44 @@ def test_check_on_device_refuses_each_doctored_condition(doctor, dry,
         assert str(e.value).startswith("stage=hub: ")
 
 
-def test_check_on_device_leaves_mesh_buckets_to_the_mesh():
-    """The mesh engine keeps no bucket table: only the single-device
-    engine's items are held to the count sent."""
+@pytest.mark.parametrize(
+    "buckets, refused",
+    [
+        (None, None),
+        # what the mesh arm was before ISSUE 35: no bucket noted at all
+        ({}, "0 items in device buckets"),
+        # the silent scan: every digest right, the kernel never reached
+        ({"xla-scan:8192": {"dispatches": 2, "items": 2000,
+                            "padded_items": 2048},
+          "pallas:16": {"dispatches": 1, "items": 48,
+                        "padded_items": 4096}}, "engines that served"),
+        # a shard under the kernel's tile on some chip
+        ({"pallas:8192": {"dispatches": 2, "items": 2000,
+                          "padded_items": 2048},
+          "pallas:16": {"dispatches": 1, "items": 48,
+                        "padded_items": 64}}, "not a whole tile"),
+    ],
+    ids=["sound", "no-bucket-noted", "scan-on-a-chip", "shard-under-a-tile"],
+)
+def test_check_on_device_holds_the_mesh_arm_to_the_pallas_buckets(
+        buckets, refused):
+    """Since ISSUE 35 the mesh engine is the served one laid over the
+    chips: its items are held to the count sent like any engine's, its
+    bucket rows must all be `pallas:*`, and a row count is whole tiles
+    on every chip."""
     import chip_smoke
 
     rep = _sound_report()
-    rep["device"]["engine"] = "mesh-sharded"
-    rep["blake2b_buckets"] = {}
-    chip_smoke.check_on_device("mesh", rep, items=2048,
-                               big_bytes=128 << 20, dry=False)
+    rep["device"].update(engine="device-batch-mesh", mesh_devices=4,
+                         device_count=4)
+    rep["blake2b_buckets"]["pallas:16"]["padded_items"] = 4096
+    if buckets is not None:
+        rep["blake2b_buckets"] = buckets
+    expect = (contextlib.nullcontext() if refused is None else
+              pytest.raises(chip_smoke.SmokeFailure, match=refused))
+    with expect:
+        chip_smoke.check_on_device("mesh", rep, items=2048,
+                                   big_bytes=128 << 20, dry=False)
 
 
 _MAIN_WITH_A_STAGE_THAT_RAISES = """

@@ -20,6 +20,7 @@ from dat_replication_protocol_tpu.hub import (
     SessionShed,
 )
 from dat_replication_protocol_tpu.parallel import mesh as pmesh
+from dat_replication_protocol_tpu.utils.payload import PayloadParts
 from dat_replication_protocol_tpu.wire.framing import TYPE_BLOB, TYPE_CHANGE
 
 HARD_TIMEOUT = 30
@@ -72,8 +73,10 @@ def test_a_blob_in_any_form_gets_its_digest_in_submit_order(form, engine,
                                                             monkeypatch):
     """Views of two and of three slabs, one view, `bytes`: the same
     digest, between the change rows it was submitted between.  An engine
-    that cannot take pieces — a caller's `hash_batch`, the mesh's
-    `hash_begin` — is handed joined `bytes`, by the dispatcher."""
+    that cannot take pieces — a caller's `hash_batch` — is handed joined
+    `bytes`, by the dispatcher; the mesh's engine is the served one laid
+    over the chips and says it takes pieces (`takes_parts`), so it is
+    handed the blob as the hub parked it and its pack is the one copy."""
     seen = []
     if engine == "hash_batch":
         def hash_batch(ps):
@@ -82,14 +85,19 @@ def test_a_blob_in_any_form_gets_its_digest_in_submit_order(form, engine,
         hub = ReplicationHub(hash_batch=hash_batch, linger_s=0.0)
     elif engine == "mesh":
         monkeypatch.setenv("DAT_DEVICE_HASH", "1")
-        real = pmesh.sharded_hash_begin
+        real = pmesh.sharded_hash_engine
 
-        def spy(m, ps):
-            seen.extend(type(p) for p in ps)
-            return real(m, ps)
-        monkeypatch.setattr(pmesh, "sharded_hash_begin", spy)
-        hub = ReplicationHub(mesh="auto", linger_s=0.0)
-        assert hub.mesh_devices == 8
+        def spied_engine(m):
+            begin = real(m)
+
+            def spy(ps):
+                seen.extend(type(p) for p in ps)
+                return begin(ps)
+            spy.takes_parts = begin.takes_parts
+            return spy
+        monkeypatch.setattr(pmesh, "sharded_hash_engine", spied_engine)
+        hub = ReplicationHub(mesh=4, linger_s=0.0)
+        assert hub.mesh_devices == 4
     else:
         hub = ReplicationHub(linger_s=0.0)
     got, want = [], []
@@ -109,8 +117,15 @@ def test_a_blob_in_any_form_gets_its_digest_in_submit_order(form, engine,
     finally:
         hub.close()
     assert got == want
-    if engine != "default":
+    if engine == "hash_batch":
         assert seen and set(seen) == {bytes}
+    elif engine == "mesh":
+        # the pieces it parked: the rows are bytes, the blob is what the
+        # session submitted — never a joined copy of a blob in pieces
+        parked_as = {"two-slabs": PayloadParts, "three-slabs": PayloadParts,
+                     "one-view": memoryview}.get(form)
+        assert [t for t in seen if t is not bytes] \
+            == ([parked_as] * 3 if parked_as else [])
 
 
 # -- the join rule, and its witness --------------------------------------------

@@ -169,27 +169,30 @@ def test_sharded_sketch_matches_single_device():
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_sharded_hash_begin_matches_hashlib_across_buckets():
-    """ISSUE 8: the hub's cross-session batch sharded over the mesh
-    (batch-dim NamedSharding) — digests byte-identical to hashlib in
-    submit order, across block-count buckets and non-multiple batch
-    sizes (padding rows must not perturb real items)."""
-    mesh = pmesh.make_mesh(8)
+def _engine(ndev):
+    return pmesh.sharded_hash_engine(pmesh.make_mesh(ndev))
+
+
+def test_sharded_engine_matches_hashlib_across_buckets():
+    """ISSUE 8, ISSUE 35: the hub's cross-session batch laid over the
+    mesh (batch-dim NamedSharding) by the served engine itself — digests
+    byte-identical to hashlib in submit order, across block-count
+    buckets and non-multiple batch sizes (padding rows must not perturb
+    real items)."""
     payloads = (
         [b"tiny-%d" % i for i in range(5)]            # nblocks=1, B%8 != 0
         + [bytes([i]) * 300 for i in range(7)]        # nblocks=4 bucket
         + [b""]                                       # empty payload edge
     )
-    collect = pmesh.sharded_hash_begin(mesh, payloads)
+    collect = _engine(8)(payloads)
     collect.start_d2h()  # idempotent prefetch, same contract as ops
     got = collect()
-    assert got == [hashlib.blake2b(p, digest_size=32).digest()
-                   for p in payloads]
+    assert got == [_digest(p) for p in payloads]
 
 
-def test_sharded_hash_begin_closure_answers_the_ready_probe():
-    """The mesh engine's closure offers the same non-blocking probe as
-    the single-device engine's, so the hub's pipeline delivers a sharded
+def test_sharded_engine_closure_answers_the_ready_probe():
+    """The mesh layout's closure offers the same non-blocking probe as
+    the single-device layout's, so the hub's pipeline delivers a sharded
     batch when the mesh has hashed it: here with no flush and no later
     dispatch, by the probe of a submit alone."""
     import time
@@ -198,10 +201,10 @@ def test_sharded_hash_begin_closure_answers_the_ready_probe():
         DigestPipeline,
     )
 
-    mesh = pmesh.make_mesh(8)
+    begin = _engine(8)
     payloads = [b"tiny-%d" % i for i in range(5)]
-    want = [hashlib.blake2b(p, digest_size=32).digest() for p in payloads]
-    collect = pmesh.sharded_hash_begin(mesh, payloads)
+    want = [_digest(p) for p in payloads]
+    collect = begin(payloads)
     assert isinstance(collect.ready(), bool)  # never blocks, never raises
     deadline = time.monotonic() + 60.0
     # true once EVERY device holds its part: collect() may return sooner
@@ -211,9 +214,8 @@ def test_sharded_hash_begin_closure_answers_the_ready_probe():
         time.sleep(0.002)
     assert collect() == want and collect.ready()
 
-    pipe = DigestPipeline(
-        hash_begin=lambda ps: pmesh.sharded_hash_begin(mesh, ps),
-        max_batch=1 << 20, max_inflight=2)
+    pipe = DigestPipeline(hash_begin=begin, max_batch=1 << 20,
+                          max_inflight=2)
     got = []
     for p in payloads:
         pipe.submit(p, got.append)
@@ -225,3 +227,154 @@ def test_sharded_hash_begin_closure_answers_the_ready_probe():
         pipe.submit(b"later", lambda d: None)
     assert got == want and pipe.dispatches == 1
     pipe.flush()
+
+
+def test_the_pipeline_observes_whether_its_engine_takes_pieces():
+    """`DigestPipeline` joins a payload in pieces for a caller's own
+    `hash_begin` (API.md's promise) and not for one that carries
+    `takes_parts`: the served engine, alone or laid over a mesh."""
+    from dat_replication_protocol_tpu.backend.tpu_backend import (
+        DigestPipeline,
+    )
+    from dat_replication_protocol_tpu.utils.payload import PayloadParts
+
+    begin = _engine(2)
+    assert begin.takes_parts is True
+    assert blake2b.blake2b_batch_begin.takes_parts is True
+    pieces = [b"abc" * 50, memoryview(b"def" * 70)]
+    whole = b"".join(bytes(x) for x in pieces)
+    for mark, want_type in ((True, PayloadParts), (False, bytes)):
+        seen = []
+
+        def own(ps, seen=seen):
+            seen.extend(type(p) for p in ps)
+            return begin(ps)
+        if mark:
+            own.takes_parts = True
+        pipe = DigestPipeline(hash_begin=own, max_batch=1 << 20)
+        got = []
+        pipe.submit_parts(list(pieces), got.append)
+        pipe.submit(memoryview(whole), got.append)
+        pipe.flush()
+        assert got == [_digest(whole)] * 2
+        assert seen == [want_type, memoryview if mark else bytes]
+
+
+@pytest.mark.parametrize("ndev", [2, 4, 8])
+@pytest.mark.parametrize("items", [1, 3, 4, 5, 31, 33, 128, 129])
+def test_the_share_is_tied_to_the_whole(items, ndev):
+    """Item for item, in submit order: the engine laid over a mesh gives
+    what the same engine gives on one device and what hashlib gives —
+    two slot widths mixed in one call, and payloads as `bytes`, as one
+    view and as `PayloadParts` alike."""
+    from dat_replication_protocol_tpu.utils.payload import PayloadParts
+
+    rng = np.random.default_rng([35, items, ndev])
+    raw = [rng.bytes(int(n)) for n in rng.integers(0, 700, items)]
+    raw[0] = rng.bytes(129)         # two buckets whatever the draw
+    raw[-1] = rng.bytes(90) if items > 1 else raw[-1]
+    forms = []
+    for k, p in enumerate(raw):
+        if k % 3 == 1:
+            forms.append(memoryview(p))
+        elif k % 3 == 2 and len(p) > 2:
+            forms.append(PayloadParts([p[:len(p) // 3],
+                                       memoryview(p)[len(p) // 3:]]))
+        else:
+            forms.append(p)
+    want = [_digest(p) for p in raw]
+    assert _engine(ndev)(forms)() == want
+    assert blake2b.blake2b_batch_begin(forms)() == want
+
+
+@pytest.mark.parametrize("width", [64, 16384], ids=["fill-whole", "wide"])
+@pytest.mark.parametrize("items, shards, per", [
+    (1, 4, 32), (3, 4, 32), (4, 4, 32), (5, 4, 32), (15, 4, 32),
+    (128, 4, 32), (129, 4, 64), (7, 2, 32), (33, 8, 32)])
+def test_staging_deals_the_items_round_the_shards(items, shards, per, width):
+    """Over a mesh item `i` lies in shard `i % n`, slot `i // n`: every
+    chip holds payload as soon as there are that many items (a batch of
+    15 blobs is 4+4+4+3, not 15 on the first chip), each row is what
+    the one-device staging lays, and every other byte of a dirty buffer
+    is zeroed."""
+    from dat_replication_protocol_tpu.utils.payload import PayloadParts
+
+    rng = np.random.default_rng([35, items, shards, width])
+    raw = [rng.bytes(int(n)) for n in rng.integers(0, width + 1, items)]
+    raw[0] = rng.bytes(width)
+    forms = [PayloadParts([p[:len(p) // 2], memoryview(p)[len(p) // 2:]])
+             if k % 3 == 2 else memoryview(p) if k % 3 == 1 else p
+             for k, p in enumerate(raw)]
+    rows = shards * per
+    dealt = np.full((rows, width), 0xAB, dtype=np.uint8)
+    plain = np.full((rows, width), 0xCD, dtype=np.uint8)
+    lengths = blake2b.stage_payloads(forms, dealt, shards)
+    want = blake2b.stage_payloads(forms, plain)
+    at = blake2b.dealt_rows(items, per, shards)
+    assert len(set(at.tolist())) == items
+    assert (dealt[at] == plain[:items]).all()
+    assert (lengths[at] == want[:items]).all()
+    rest = np.setdiff1d(np.arange(rows), at)
+    assert not dealt[rest].any() and not lengths[rest].any()
+    held = np.bincount(at // per, minlength=shards)
+    assert held.max() - held.min() <= 1
+    assert held.max() == -(-items // shards) <= per
+    assert (held > 0).sum() == min(items, shards)
+
+
+def test_row_policy_of_a_one_mib_slot_over_four_chips():
+    """Over item counts 1 ... 1,024 a 1 MiB slot on 4 chips meets the
+    declared set of shapes — a handful, not one per power of two from
+    4 — and no chip's shard is under the kernel's smallest tile nor
+    over a full one."""
+    nb, ndev = 8192, 4
+    shapes = set()
+    for items in range(1, 1025):
+        per = blake2b.shard_rows(items, nb, ndev)
+        assert per in blake2b.declared_rows(nb)
+        assert blake2b.MIN_TILE_ITEMS <= per <= 1024
+        assert per >= -(-items // ndev)      # dealt round the chips, they fit
+        assert per == 32 or ndev * (per // 2) < items  # and the smallest
+        shapes.add((ndev * per, nb))
+    assert sorted(shapes) == [(128, nb), (256, nb), (512, nb), (1024, nb)]
+    # a feed row's slot has ONE shape a mesh, as it has on one device
+    assert {blake2b.shard_rows(i, 16, ndev) for i in (1, 500, 4096)} \
+        == {1024}
+
+
+def test_sharded_program_has_no_collective_and_its_own_name():
+    """The lowered sharded program exchanges nothing between devices,
+    and is not named `jit_blake2b*` (the one-chip reader of the
+    benchmark must not match it)."""
+    mesh = pmesh.make_mesh(4)
+    fn = blake2b._sharded_words_program(mesh, False, False, 32)
+    lowered = fn.lower(
+        jax.ShapeDtypeStruct((128, 64), np.uint32),
+        jax.ShapeDtypeStruct((128,), np.uint32))
+    assert "@jit_mesh_blake2b_words" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.startswith("HloModule jit_mesh_blake2b_words")
+    for op in ("all-gather", "all-reduce", "collective-permute",
+               "all-to-all", "reduce-scatter"):
+        assert op not in text
+    # a chip's program sees its shard alone
+    assert "u32[32,64]" in text.splitlines()[0]
+
+
+def test_sharded_engine_keeps_the_spans_and_the_bucket_table(obs_enabled):
+    """Lit, the mesh layout opens the served stage spans with a
+    `devices` attribute and notes its bucket under the key the readers
+    parse, `padded_items` being the rows over ALL chips."""
+    from dat_replication_protocol_tpu.obs import device, tracing
+
+    payloads = [b"p" * 200 for _ in range(5)]
+    assert _engine(4)(payloads)() == [_digest(p) for p in payloads]
+    rows = device.BUCKETS.snapshot()
+    assert rows == {"xla-scan:2": {
+        "dispatches": 1, "items": 5,
+        "padded_items": 4 * blake2b.declared_rows(2)[0]}}
+    for name in ("digest.pack", "digest.h2d", "digest.launch",
+                 "digest.d2h_wait", "digest.unpack"):
+        (rec,) = tracing.SPANS.spans(name)
+        assert rec["fields"]["devices"] == 4, rec
+        assert rec["fields"]["items"] == 5, rec

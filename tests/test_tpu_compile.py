@@ -20,7 +20,7 @@ MIB = 1 << 20
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     from jax.experimental import topologies
@@ -36,9 +36,22 @@ def one_chip():
     # one: keep these out of the persistent cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topo.devices), ("data",))
 
 
 def _compiled(one_chip, rows, nblocks):
@@ -64,4 +77,41 @@ def test_each_declared_shape_compiles_for_the_v5e(one_chip, nblocks, rows):
     assert mem.argument_size_in_bytes < staged + MIB
     # the split halves and their transposes, and HBM's 128-lane padding
     # of a tile under 128 rows: never the 32x of an (8, rows/8) tile
+    assert mem.temp_size_in_bytes <= 6 * staged + 4 * MIB
+
+
+@pytest.mark.parametrize("nblocks, per_chip", [(8192, 32), (8192, 64),
+                                               (16, 1024)],
+                         ids=lambda v: str(v))
+def test_the_sharded_program_compiles_for_a_four_chip_host(four_chips,
+                                                           nblocks,
+                                                           per_chip):
+    """ISSUE 35: the mesh hub's program — the served Pallas words
+    program under `shard_map` — is partitioned by the v5e compiler: each
+    chip gets its shard of the rows and the kernel, nothing
+    is exchanged between chips, and no scan over the message blocks is
+    left in it."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    assert per_chip in b2.declared_rows(nblocks)
+    rows = four_chips.devices.size * per_chip
+    by_rows = NamedSharding(four_chips, P("data"))
+    fn = b2._sharded_words_program(four_chips, True, True, 32).__wrapped__
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((rows, nblocks * 32), jnp.uint32,
+                             sharding=by_rows),
+        jax.ShapeDtypeStruct((rows,), jnp.uint32, sharding=by_rows),
+    ).compile()
+    text = compiled.as_text()
+    head = text.splitlines()[0]
+    assert head.startswith("HloModule jit_mesh_blake2b_words")
+    assert f"u32[{per_chip},{nblocks * 32}]" in head   # a chip's shard
+    assert "tpu_custom_call" in text
+    for op in ("all-gather", "all-reduce", "collective-permute",
+               "all-to-all", "reduce-scatter", " while("):
+        assert op not in text, op
+    mem = compiled.memory_analysis()      # bytes on each device
+    staged = per_chip * nblocks * 128
+    assert mem.argument_size_in_bytes < staged + MIB
     assert mem.temp_size_in_bytes <= 6 * staged + 4 * MIB
