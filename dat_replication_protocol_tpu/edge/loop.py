@@ -53,7 +53,9 @@ from ..obs.metrics import (
 from ..session.pump import (
     PUMP_BUF,
     EdgePump,
+    RecvFan,
     effective_pump_route,
+    recv_feed,
     recv_step,
     send_step,
 )
@@ -91,8 +93,17 @@ EDGE_TICK = 0.05
 # work so a connect flood cannot starve live sessions' I/O
 ACCEPT_BURST = 64
 
+# helper threads a loop keeps for the receives of a turn's bulk sessions
+# (RecvFan).  From a sizing on the chip's host (PERF.md §6, PR 36): eight
+# loopback senders of 1 MiB blobs, one receiving loop
+RX_HELPERS = 3
+
 _M_SESSIONS = _counter("sidecar.sessions")
 _M_STALLS = _counter("sidecar.stalls")
+# bytes the loop's read phase received (lit turns), and of them the
+# bytes whose receive ran on a helper: how often the fan-out engages
+_M_RX = _counter("edge.rx.bytes")
+_M_RX_FANNED = _counter("edge.rx.fanned.bytes")
 
 # edge.served/admitted/rejected/shed are exported by the loop's
 # registry COLLECTOR (labeled by loop name, read straight off the
@@ -196,6 +207,9 @@ class EdgeLoop:
         # election, per group): claimed at admit, released by a source
         # that published nothing
         self._src_claims: dict[str, bool] = {g: False for g in self._fanouts}
+        # the receive helpers: started by the first turn that has two
+        # bulk sessions to read, ended in _shutdown
+        self._rx_fan: Optional[RecvFan] = None
         self._wake_r, self._wake_w = os.pipe()
         os.set_blocking(self._wake_r, False)
         os.set_blocking(self._wake_w, False)
@@ -257,6 +271,11 @@ class EdgeLoop:
 
     def _shutdown(self) -> None:
         self.profiler.detach()
+        if self._rx_fan is not None:
+            # before any descriptor closes: a receive still in flight
+            # (the loop left mid-turn) ends on its own fd
+            self._rx_fan.close()
+            self._rx_fan = None
         _REGISTRY.unregister_collector("edge", self._collector_fn)
         for sess in list(self._table.values()):
             try:
@@ -298,12 +317,15 @@ class EdgeLoop:
     def _dark_turn(self) -> None:
         events = self._sel.select(self._tick)
         now = time.monotonic()
+        fanned = self._fan_reads(events)
         for skey, mask in events:
             tag = skey.data
             if tag == "accept":
                 self._accept_burst()
             elif tag == "wake":
                 self._drain_wake()
+            elif tag in fanned:
+                self._io_turn(tag, mask & ~selectors.EVENT_READ, now)
             else:
                 self._io_turn(tag, mask, now)
         self._sweep(time.monotonic())
@@ -317,6 +339,7 @@ class EdgeLoop:
         events = self._sel.select(self._tick)
         now = time.monotonic()
         prof.poll_done(now, len(events))
+        fanned = self._fan_reads(events, prof)
         for skey, mask in events:
             tag = skey.data
             if tag == "accept":
@@ -325,6 +348,8 @@ class EdgeLoop:
                 prof.phase("accept", time.monotonic() - t0)
             elif tag == "wake":
                 self._drain_wake()
+            elif tag in fanned:
+                self._io_turn(tag, mask & ~selectors.EVENT_READ, now, prof)
             else:
                 self._io_turn(tag, mask, now, prof)
         self._sweep(time.monotonic(), prof)
@@ -503,6 +528,7 @@ class EdgeLoop:
                         rx = self._read_turn(sess, now)
                     prof.account("read", sess.key,
                                  time.monotonic() - t0, rx)
+                    _M_RX.inc(rx)
                 else:
                     self._read_turn(sess, now)
             if mask & selectors.EVENT_WRITE and not sess.dead:
@@ -526,17 +552,86 @@ class EdgeLoop:
             self._update_mask(sess)
 
     def _read_turn(self, sess: EdgeSession, now: float) -> int:
-        dec = sess.machine.dec
-        if sess.rx_eof or dec.destroyed or not self._read_gate_open(sess):
+        if not self._may_read(sess):
             return 0
-        nbytes, eof = recv_step(sess.pump, dec, sess.tap)
+        return self._fed(sess, *recv_step(sess.pump, sess.machine.dec,
+                                          sess.tap))
+
+    def _fed(self, sess: EdgeSession, nbytes: int, eof: bool) -> int:
         if eof:
             sess.rx_eof = True
+            dec = sess.machine.dec
             if not dec.destroyed and not dec.finished:
                 dec.end()
         if nbytes or eof:
             sess.tx_ready = True  # machine hooks may have queued reply
         return nbytes
+
+    def _fan_reads(self, events,
+                   prof: Optional[LoopProfiler] = None) -> frozenset:
+        """Receive this turn's bulk sessions side by side: where two or
+        more readable sessions' last receive came back with a full
+        slice (``EdgePump.bulk``) and may read now, start every one's
+        receive on the helpers, then feed each on THIS thread as it
+        completes — the inline read's own steps (gate, ``recv_feed``,
+        ``_session_error``) with the kernel's copies overlapped.  A
+        helper never touches a decoder, a machine, the hub, the
+        selector or the table; each session has one receive in flight
+        and is fed before this returns, so order, gate, window, budget
+        and teardown see what the inline read shows them.  Returns the
+        sessions read here (their turn skips the inline read); small
+        reads and a lone bulk session stay inline."""
+        ready = []
+        if len(events) > 1:
+            for skey, mask in events:
+                sess = skey.data  # "accept" / "wake" / a session
+                if (mask & selectors.EVENT_READ
+                        and sess.__class__ is EdgeSession
+                        and sess.pump is not None and sess.pump.bulk
+                        and self._may_read(sess)):
+                    ready.append(sess)
+        if len(ready) < 2:
+            return frozenset()
+        fan = self._rx_fan
+        if fan is None:
+            fan = self._rx_fan = RecvFan(RX_HELPERS)
+        if prof is None:
+            for sess in ready:
+                fan.start(sess, sess.pump)
+            for _ in ready:
+                self._feed_next(fan)
+            return frozenset(ready)
+        with _annotation("edge.read"):
+            t0 = time.monotonic()
+            for sess in ready:
+                fan.start(sess, sess.pump)
+            for _ in ready:
+                sess, rx = self._feed_next(fan)
+                t1 = time.monotonic()
+                # the loop thread's own seconds: its wait for this
+                # receive (the first one's is the turn's real wait)
+                # plus the feed
+                prof.account("read", sess.key, t1 - t0, rx)
+                _M_RX.inc(rx)
+                _M_RX_FANNED.inc(rx)
+                t0 = t1
+        return frozenset(ready)
+
+    def _feed_next(self, fan: RecvFan) -> tuple:
+        # bounded: every started receive is dat_pump_recv_scan on an
+        # O_NONBLOCK descriptor (the EdgePump contract, set at
+        # admission) — it returns without sleeping, so this waits for
+        # a memory copy on another core, never for a peer
+        # datlint: allow-blocking-reachable(wait)
+        sess, fetched = fan.wait_one()
+        try:
+            if isinstance(fetched, BaseException):
+                raise fetched
+            return sess, self._fed(sess, *recv_feed(
+                sess.pump, sess.machine.dec, fetched, sess.tap))
+        except Exception as e:
+            self._session_error(sess, e)
+            return sess, 0
 
     def _probe_subscriber(self, sess: EdgeSession) -> None:
         # the threaded run_subscriber's EOF/misroute probe, event-driven
@@ -576,6 +671,10 @@ class EdgeLoop:
             except OSError:
                 pass
         return accepted
+
+    def _may_read(self, sess: EdgeSession) -> bool:
+        return (not sess.rx_eof and not sess.machine.dec.destroyed
+                and self._read_gate_open(sess))
 
     def _read_gate_open(self, sess: EdgeSession) -> bool:
         m = sess.machine
@@ -796,8 +895,7 @@ class EdgeLoop:
                 want |= selectors.EVENT_READ  # EOF/misroute probe
             else:
                 m = sess.machine
-                if (not sess.rx_eof and not m.dec.destroyed
-                        and self._read_gate_open(sess)):
+                if self._may_read(sess):
                     want |= selectors.EVENT_READ
                 if sess.tx_blocked and not sess.tx_done \
                         and not m.enc.destroyed:

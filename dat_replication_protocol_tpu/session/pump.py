@@ -46,6 +46,7 @@ model and the batch-size sweep.
 from __future__ import annotations
 
 import os
+import queue
 import threading
 from time import perf_counter as _perf
 from typing import Callable, Optional
@@ -65,7 +66,8 @@ from .transport import WAKE_FALLBACK, recv_over, send_over, \
 __all__ = [
     "effective_pump_route", "recv_pump", "send_pump", "pump_reader",
     "pump_writer", "io_for_socket", "send_spans_nb", "probe_caps",
-    "EdgePump", "recv_step", "send_step",
+    "EdgePump", "RecvFan", "recv_step", "recv_fetch", "recv_feed",
+    "send_step",
 ]
 
 # receive slab geometry: cap bounds one pump call's batch (and the
@@ -482,7 +484,8 @@ class EdgePump:
     thread pumps (the route that runs is always a route that
     exists)."""
 
-    __slots__ = ("fd", "cap", "recv_st", "pending", "gather", "native")
+    __slots__ = ("fd", "cap", "recv_st", "pending", "gather", "native",
+                 "bulk")
 
     def __init__(self, fd: int, cap: int = PUMP_BUF):
         self.fd = fd
@@ -491,56 +494,142 @@ class EdgePump:
         self.recv_st = _RecvState(cap) if self.native else None
         self.gather = SpanGather(cap=1) if self.native else None
         self.pending: Optional[memoryview] = None  # unsent reply tail
+        # what the last native receive observed of the socket: it came
+        # back with a full PUMP_SLICE or more, so the peer keeps the
+        # kernel's buffer full — the edge loop's cue to run this
+        # session's next receive beside its neighbours' (recv_fetch on
+        # a helper thread) instead of in a row
+        self.bulk = False
 
 
 def recv_step(pump: EdgePump, decoder: Decoder, tap=None) -> tuple:
     """ONE bounded receive turn: drain what the kernel already
     buffered on ``pump.fd`` into ``decoder``, never waiting.  Returns
     ``(nbytes, eof)``; ``(0, False)`` means would-block (wait for the
-    selector's next READ event).  Native route: one
-    ``dat_pump_recv_scan`` batch (its first ``read`` returns
-    ``-EAGAIN`` on the non-blocking fd instead of sleeping) feeding
-    ``decoder.write_indexed``; Python route: ``os.read`` until
+    selector's next READ event).  Native route: :func:`recv_fetch`
+    then :func:`recv_feed`, in a row; Python route: ``os.read`` until
     ``EAGAIN``, EOF, decoder stall, or the ``PUMP_BUF`` turn budget —
     a faulted neighbor can cost this session at most one slab of
     latency per turn."""
     if pump.native:
-        st = pump.recv_st
-        buf = np.empty(st.cap, dtype=np.uint8)  # fresh: see _RecvState
-        t0 = _perf()
-        r = native.pump_recv_scan(pump.fd, buf, PUMP_SLICE, st.starts,
-                                  st.lens, st.ids, st.stats)
-        if r is None:  # library vanished mid-session (tests reset)
-            pump.native = False
-            return recv_step(pump, decoder, tap)
-        nbytes, nframes, consumed, _err = r
-        if _OBS.on:
-            _H_NATIVE.observe(_perf() - t0)
-        if nbytes in (-11, -4):  # EAGAIN / EINTR: retry next turn
-            return (0, False)
-        if nbytes < 0:
-            raise OSError(-nbytes, os.strerror(-nbytes))
-        if nbytes == 0:
-            return (0, True)
-        if _OBS.on:
-            _note_batch(nbytes, st.stats)
-            _lit_rx(decoder, nbytes)
-        data = memoryview(buf[:nbytes])  # as recv_pump: the prefix's own
-        if tap is not None:
-            # the broadcast tee (FanoutServer.publish): an append +
-            # O(1) mark under the server lock — never blocks the loop
-            # datlint: allow-callback-escape
-            tap(data)
-        try:
-            decoder.write_indexed(data, st.starts, st.lens, st.ids,
-                                  nframes, consumed)
-        except DecoderDestroyedError:
-            pass  # the loop's teardown predicate sees dec.destroyed
-        return (nbytes, False)
+        return recv_feed(pump, decoder, recv_fetch(pump), tap)
     res = _recv_step_py(pump, decoder, tap)
     if _OBS.on and res[0]:
         _lit_rx(decoder, res[0])
     return res
+
+
+def recv_fetch(pump: EdgePump) -> tuple:
+    """The RECEIVE half of the native :func:`recv_step`: a fresh slab
+    and one ``dat_pump_recv_scan`` batch into it (its first ``read``
+    returns ``-EAGAIN`` on the non-blocking fd instead of sleeping),
+    the interpreter lock released for the whole call.  Touches the
+    descriptor, the slab and the session's own :class:`_RecvState` and
+    nothing else, so the edge loop may run it on a helper thread
+    (one receive in flight a session, fed before the next begins).
+    Returns what :func:`recv_feed` takes: ``(slab, result, seconds)``."""
+    st = pump.recv_st
+    buf = np.empty(st.cap, dtype=np.uint8)  # fresh: see _RecvState
+    t0 = _perf()
+    r = native.pump_recv_scan(pump.fd, buf, PUMP_SLICE, st.starts,
+                              st.lens, st.ids, st.stats)
+    return buf, r, _perf() - t0
+
+
+def recv_feed(pump: EdgePump, decoder: Decoder, fetched: tuple,
+              tap=None) -> tuple:
+    """The FEED half of the native :func:`recv_step`, on the thread
+    that owns the decoder: a transport error raises here, EOF and
+    would-block are told apart here, then the tap, the lit counters
+    and ``decoder.write_indexed``.  Returns ``(nbytes, eof)``."""
+    buf, r, seconds = fetched
+    if r is None:  # library vanished mid-session (tests reset)
+        pump.native = False
+        pump.bulk = False
+        return recv_step(pump, decoder, tap)
+    st = pump.recv_st
+    nbytes, nframes, consumed, _err = r
+    pump.bulk = nbytes >= PUMP_SLICE
+    if _OBS.on:
+        _H_NATIVE.observe(seconds)
+    if nbytes in (-11, -4):  # EAGAIN / EINTR: retry next turn
+        return (0, False)
+    if nbytes < 0:
+        raise OSError(-nbytes, os.strerror(-nbytes))
+    if nbytes == 0:
+        return (0, True)
+    if _OBS.on:
+        _note_batch(nbytes, st.stats)
+        _lit_rx(decoder, nbytes)
+    data = memoryview(buf[:nbytes])  # as recv_pump: the prefix's own
+    if tap is not None:
+        # the broadcast tee (FanoutServer.publish): an append +
+        # O(1) mark under the server lock — never blocks the loop
+        # datlint: allow-callback-escape
+        tap(data)
+    try:
+        decoder.write_indexed(data, st.starts, st.lens, st.ids,
+                              nframes, consumed)
+    except DecoderDestroyedError:
+        pass  # the loop's teardown predicate sees dec.destroyed
+    return (nbytes, False)
+
+
+# RecvFan.close's bound on each helper's exit: a helper is at most one
+# non-blocking receive away from its sentinel
+_FAN_JOIN_TIMEOUT = 5.0
+
+
+class RecvFan:
+    """A few helper threads that run :func:`recv_fetch` for the edge
+    loop, so that one turn's bulk sessions are received side by side:
+    the kernel's socket copies and the frame scan need no interpreter,
+    only a core each.  A helper touches what ``recv_fetch`` touches —
+    a descriptor, a slab, the session's ``_RecvState`` — and hands the
+    result back untouched; whatever it raised is handed back too and
+    raised by the caller.  Every descriptor is ``O_NONBLOCK`` (the
+    :class:`EdgePump` contract), so a started receive returns without
+    sleeping and :meth:`wait_one` is bounded by construction."""
+
+    __slots__ = ("_jobs", "_done", "_threads")
+
+    def __init__(self, helpers: int):
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        self._done: queue.SimpleQueue = queue.SimpleQueue()
+        self._threads = [
+            threading.Thread(target=self._run, name=f"edge-rx-{i}",
+                             daemon=True) for i in range(helpers)]
+        for t in self._threads:
+            t.start()
+
+    def _run(self) -> None:
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            token, pump = job
+            try:
+                fetched = recv_fetch(pump)
+            except BaseException as e:  # the caller's to raise
+                fetched = e
+            self._done.put((token, fetched))
+
+    def start(self, token, pump: EdgePump) -> None:
+        """Begin one receive on ``pump``; ``token`` comes back with it."""
+        self._jobs.put((token, pump))
+
+    def wait_one(self) -> tuple:
+        """``(token, fetched)`` of the next started receive to complete;
+        ``fetched`` is :func:`recv_feed`'s argument, or the exception
+        the helper's call raised."""
+        return self._done.get()
+
+    def close(self) -> None:
+        """End the helpers once their started receives are through."""
+        for _ in self._threads:
+            self._jobs.put(None)
+        for t in self._threads:
+            t.join(_FAN_JOIN_TIMEOUT)
 
 
 def _recv_step_py(pump: EdgePump, decoder: Decoder, tap=None) -> tuple:

@@ -8,14 +8,21 @@ changed the mechanism and nothing observable.
 """
 
 import hashlib
+import selectors
 import socket
 import threading
 import time
 
+import pytest
+
 import dat_replication_protocol_tpu as protocol
 from dat_replication_protocol_tpu.edge import EdgeLoop, QOS_PRESETS, \
     serve_edge
-from dat_replication_protocol_tpu.hub import ReplicationHub
+from dat_replication_protocol_tpu.edge import loop as edge_loop
+from dat_replication_protocol_tpu.hub import ReplicationHub, SessionShed
+from dat_replication_protocol_tpu.runtime import native
+from dat_replication_protocol_tpu.session import pump as pump_mod
+from dat_replication_protocol_tpu.wire.framing import TYPE_BLOB, frame
 
 from test_wire_fixtures import CHANGE_PAYLOAD, SESSION_1, SESSION_4
 
@@ -456,3 +463,358 @@ def test_edge_stats_fd_snapshot_carries_edge_aggregate(obs_enabled):
         sidecar.set_active_hub(None)
         sidecar.set_active_edge(None)
         hub.close()
+
+
+# -- a turn's bulk sessions received side by side (ISSUE 36) -----------------
+
+needs_native = pytest.mark.skipif(
+    not native.available(), reason="native library unavailable")
+
+
+def _read_out(enc) -> bytes:
+    parts = []
+    while True:
+        d = enc.read(1 << 20)
+        if not d:
+            return b"".join(parts)
+        parts.append(bytes(d))
+
+
+def _stream(i: int, cut: bool = False) -> tuple:
+    """Session ``i``'s bytes and what they must come back as:
+    ``(wire, expect)`` with ``expect[kind]`` the digests in submit
+    order.  Blobs of a few hundred KB (each straddles receive slabs),
+    two zero-length blobs (raw frames: the encoder refuses them), a
+    run of changes on either side; ``cut`` ends the wire mid-blob."""
+    enc = protocol.encode()
+    records = []
+    for j in range(30):
+        rec = {"key": f"s{i}k{j}", "change": j, "from": 0, "to": 1,
+               "value": bytes([i, j]) * (j + 1)}
+        records.append(rec)
+        enc.change(rec)
+    wire = _read_out(enc)
+    blobs = [bytes([i + 1, j + 1]) * (90_000 + 7_001 * j) for j in range(6)]
+    blobs[2:2] = [b""]
+    blobs.append(b"")
+    wire += b"".join(frame(TYPE_BLOB, b) for b in blobs)
+    expect = {
+        "blob": [hashlib.blake2b(b, digest_size=32).digest()
+                 for b in blobs],
+        "change": [hashlib.blake2b(protocol.wire.encode_change(r),
+                                   digest_size=32).digest()
+                   for r in records]}
+    if cut:
+        return wire + frame(TYPE_BLOB, b"q" * 50_000)[:20_000], expect
+    enc = protocol.encode()
+    enc.finalize()
+    return wire + _read_out(enc), expect
+
+
+def _feed_stream(i: int) -> tuple:
+    """A change feed: rows of a hundred bytes, trickled."""
+    enc = protocol.encode()
+    records = [{"key": f"f{i}r{j}", "change": j, "from": 0, "to": 1,
+                "value": bytes([j % 251]) * 100} for j in range(200)]
+    for rec in records:
+        enc.change(rec)
+    enc.finalize()
+    return _read_out(enc), {"blob": [], "change": [
+        hashlib.blake2b(protocol.wire.encode_change(r),
+                        digest_size=32).digest() for r in records]}
+
+
+def _by_kind(reply: list) -> dict:
+    out = {"change": [], "blob": []}
+    for ch in reply:
+        kind, seq = ch.key.split("-")
+        assert int(seq) == len(out[kind]), "per-kind order"
+        out[kind].append(ch.value)
+    return out
+
+
+def _serve_streams(loop, wires: dict, pace: dict = None) -> dict:
+    """Every wire through ``loop`` at once, one client thread each;
+    returns the decoded replies (``None`` where the reply was cut)."""
+    port, t = _start_loop(loop)
+    replies: dict = {}
+    go = threading.Barrier(len(wires))
+
+    def client(i, wire):
+        c = socket.create_connection(("127.0.0.1", port), timeout=10)
+        c.settimeout(20)
+        raw = []
+        rx = threading.Thread(
+            target=lambda: raw.append(_recv_all_or_reset(c)), daemon=True)
+        rx.start()
+        go.wait(10)
+        step = (pace or {}).get(i)
+        try:
+            if step is None:
+                c.sendall(wire)
+            else:
+                for off in range(0, len(wire), step):
+                    c.sendall(wire[off:off + step])
+                    time.sleep(0.0005)
+            c.shutdown(socket.SHUT_WR)
+        except OSError:
+            pass  # the loop tore this session down under the send
+        rx.join(20)
+        c.close()
+        replies[i] = raw[0] if raw else None
+
+    threads = [threading.Thread(target=client, args=(i, w), daemon=True)
+               for i, w in wires.items()]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(30)
+        assert not th.is_alive(), "client HANG"
+    t.join(timeout=15)
+    assert not t.is_alive(), "loop HANG"
+    return replies
+
+
+def _recv_all_or_reset(sock) -> bytes:
+    parts = []
+    while True:
+        try:
+            d = sock.recv(1 << 16)
+        except OSError:
+            return b"".join(parts)
+        if not d:
+            return b"".join(parts)
+        parts.append(d)
+
+
+def _decode_prefix(raw: bytes) -> list:
+    """The complete records of a reply that may end anywhere."""
+    out = []
+    dec = protocol.decode()
+    dec.change(lambda ch, done: (out.append(ch), done()))
+    dec.on_error(lambda _e: None)
+    dec.write(raw)
+    return out
+
+
+def _fan_counters(metrics) -> tuple:
+    c = metrics.snapshot()["counters"]
+    return c.get("edge.rx.bytes", 0), c.get("edge.rx.fanned.bytes", 0)
+
+
+def _no_helper_threads() -> bool:
+    return not any(th.name.startswith("edge-rx-")
+                   for th in threading.enumerate())
+
+
+@needs_native
+@pytest.mark.parametrize("route", ["fanned", "inline", "python"])
+def test_edge_fanned_and_inline_receive_agree(route, monkeypatch,
+                                              obs_enabled):
+    """The same byte streams — 8 sessions of changes, zero-length
+    blobs and blobs that straddle slabs, one of them a trickled change
+    feed, one ending mid-frame — through the fanned receive, the
+    inline one and the Python route: identical deliveries and digests,
+    in order per session.  The slice is shrunk so that a few hundred KB
+    a session is bulk; only the fanned route leaves the loop thread."""
+    monkeypatch.setenv("DAT_PUMP", "python" if route == "python"
+                       else "native")
+    monkeypatch.setattr(pump_mod, "PUMP_SLICE", 1 << 15)
+    if route == "inline":
+        monkeypatch.setattr(EdgeLoop, "_fan_reads",
+                            lambda self, events, prof=None: frozenset())
+    wires, expects = {}, {}
+    for i in range(8):
+        wires[i], expects[i] = (_feed_stream(i) if i == 3
+                                else _stream(i, cut=(i == 5)))
+    hub = ReplicationHub(linger_s=0.002)
+    qos_of = lambda n, peer, mode: \
+        "latency" if n % 4 == 0 else "throughput"  # noqa: E731
+    loop = EdgeLoop(hub, qos_of=qos_of, max_sessions=8, tick=0.01)
+    try:
+        replies = _serve_streams(loop, wires, pace={3: 2_000})
+    finally:
+        hub.close()
+    for i in range(8):
+        if i == 5:
+            # EOF mid-frame: a structured teardown; what was answered
+            # before it is a prefix of the truth, never a wrong digest
+            got = _by_kind(_decode_prefix(replies[i]))
+            for kind in got:
+                assert got[kind] == expects[i][kind][:len(got[kind])]
+            continue
+        assert _by_kind(_decode_reply(replies[i])) == expects[i], \
+            f"session {i} on route {route}"
+    rx, fanned = _fan_counters(obs_enabled)
+    assert rx >= sum(len(w) for w in wires.values()) - len(wires[5])
+    if route == "fanned":
+        assert 0 < fanned <= rx
+    else:
+        assert fanned == 0
+    assert loop._rx_fan is None and _no_helper_threads()
+
+
+@needs_native
+@pytest.mark.parametrize("traffic", ["small-reads", "lone-bulk"])
+def test_edge_small_reads_and_a_lone_bulk_session_stay_inline(
+        traffic, monkeypatch, obs_enabled):
+    """Adapting, not a knob: sessions whose reads stay under a slice,
+    and a bulk session with no bulk neighbour, never pay a thread
+    hand-off — the helpers are not even started."""
+    monkeypatch.setenv("DAT_PUMP", "native")
+    started = []
+    real_fan = pump_mod.RecvFan
+    monkeypatch.setattr(
+        edge_loop, "RecvFan",
+        lambda n: (started.append(n), real_fan(n))[1])
+    if traffic == "small-reads":
+        streams = {i: _feed_stream(i) for i in range(6)}
+        pace = {i: 4_000 for i in streams}
+    else:
+        monkeypatch.setattr(pump_mod, "PUMP_SLICE", 1 << 15)
+        streams = {0: _stream(0), 1: _feed_stream(1), 2: _feed_stream(2)}
+        pace = {1: 1_000, 2: 1_000}
+    hub = ReplicationHub(linger_s=0.002)
+    loop = EdgeLoop(hub, max_sessions=len(streams), tick=0.01)
+    try:
+        replies = _serve_streams(loop, {i: s[0] for i, s in streams.items()},
+                                 pace=pace)
+    finally:
+        hub.close()
+    for i, (_wire, expect) in streams.items():
+        assert _by_kind(_decode_reply(replies[i])) == expect
+    rx, fanned = _fan_counters(obs_enabled)
+    assert rx == sum(len(s[0]) for s in streams.values())
+    assert fanned == 0 and started == []
+
+
+def _parked_sessions(loop, n: int, payload: bytes) -> tuple:
+    """``n`` hub sessions admitted by hand-driven turns, each with
+    ``payload`` waiting in its socket; returns (clients, sessions)."""
+    port = loop.bind("127.0.0.1", 0)
+    clients = [socket.create_connection(("127.0.0.1", port), timeout=10)
+               for _ in range(n)]
+    deadline = time.monotonic() + 10
+    while len(loop._table) < n and time.monotonic() < deadline:
+        loop._dark_turn()
+    sessions = sorted(loop._table.values(), key=lambda s: s.n)
+    assert len(sessions) == n
+    for c in clients:
+        c.sendall(payload)
+    time.sleep(0.05)
+    return clients, sessions
+
+
+@needs_native
+@pytest.mark.parametrize("gate", ["decoder-stalled", "hub-window-full"])
+def test_edge_closed_gate_is_not_received_that_turn(gate, monkeypatch):
+    """The read gate is checked on the loop thread before a receive is
+    handed out: a session whose decoder stalled, or whose hub window
+    is full, keeps its bytes in the kernel this turn — and with it
+    gone from a turn of two, the other is read inline."""
+    monkeypatch.setenv("DAT_PUMP", "native")
+    hub = ReplicationHub(linger_s=0.002)
+    loop = EdgeLoop(hub, tick=0.01)
+    payload = frame(TYPE_BLOB, b"g" * 40_000)
+    clients = []
+    try:
+        clients, (s0, s1, s2) = _parked_sessions(loop, 3, payload)
+        for s in (s0, s1, s2):
+            s.pump.bulk = True
+        if gate == "decoder-stalled":
+            monkeypatch.setattr(s1.machine.dec, "writable", lambda: False)
+        else:
+            monkeypatch.setattr(s1.machine.hub_session, "window_room",
+                                lambda: False)
+        events = [(selectors.SelectorKey(s.conn, s.fd, s.mask, s),
+                   selectors.EVENT_READ) for s in (s0, s1, s2)]
+        fanned = loop._fan_reads(events)
+        assert fanned == {s0, s2}
+        assert s0.machine.dec.bytes == s2.machine.dec.bytes == len(payload)
+        assert s1.machine.dec.bytes == 0
+        # a turn of two with one gated: nobody is fanned, nothing read
+        s0.pump.bulk = True
+        clients[0].sendall(payload)
+        time.sleep(0.05)
+        assert loop._fan_reads(events[:2]) == frozenset()
+        assert s0.machine.dec.bytes == len(payload)
+        assert s1.machine.dec.bytes == 0
+    finally:
+        for c in clients:
+            c.close()
+        loop._shutdown()
+        hub.close()
+    assert _no_helper_threads()
+
+
+@needs_native
+@pytest.mark.parametrize("fault", ["transport-error", "shed"])
+def test_edge_fault_with_receives_in_flight_tears_down_that_session_alone(
+        fault, monkeypatch, obs_enabled):
+    """A transport error coming back from a helper's call, and a shed
+    raised by a feed while the neighbours' receives are still in
+    flight, destroy that session's two directions and nobody else's;
+    the loop's shutdown leaves no helper behind."""
+    from dat_replication_protocol_tpu.obs.events import EVENTS
+
+    monkeypatch.setenv("DAT_PUMP", "native")
+    monkeypatch.setattr(pump_mod, "PUMP_SLICE", 1 << 15)
+    victim = {}  # the third session admitted, once the table holds it
+
+    if fault == "transport-error":
+        real_fetch = pump_mod.recv_fetch
+
+        def fetch(pump):
+            buf, r, seconds = real_fetch(pump)
+            if threading.current_thread().name.startswith("edge-rx-") \
+                    and victim.get("fd") == pump.fd:
+                victim["hit"] = True
+                return buf, (-104, 0, 0, 0), seconds  # ECONNRESET
+            return buf, r, seconds
+
+        monkeypatch.setattr(pump_mod, "recv_fetch", fetch)
+    else:
+        real_feed = edge_loop.recv_feed
+
+        def feed(pump, dec, fetched, tap=None):
+            if victim.get("fd") == pump.fd:
+                victim["hit"] = True
+                raise SessionShed("victim", "parked-budget", 1)
+            return real_feed(pump, dec, fetched, tap)
+
+        monkeypatch.setattr(edge_loop, "recv_feed", feed)
+
+    real_fan_reads = EdgeLoop._fan_reads
+
+    def fan_reads(self, events, prof=None):
+        if "fd" not in victim:
+            for s in self._table.values():
+                if s.n == 3:
+                    victim["fd"] = s.fd
+        return real_fan_reads(self, events, prof)
+
+    monkeypatch.setattr(EdgeLoop, "_fan_reads", fan_reads)
+    streams = {i: _stream(i) for i in range(6)}
+    hub = ReplicationHub(linger_s=0.002)
+    loop = EdgeLoop(hub, max_sessions=6, tick=0.01)
+    try:
+        replies = _serve_streams(loop, {i: s[0] for i, s in streams.items()})
+    finally:
+        hub.close()
+    assert victim.get("hit"), "the fault never fired on a fanned receive"
+    records = [e["fields"] for e in EVENTS.events("sidecar.session")]
+    assert len(records) == 6
+    bad = [r for r in records if not r["ok"]]
+    assert len(bad) == 1 and bad[0]["session"].startswith("c3:")
+    whole = 0
+    for i, (_wire, expect) in streams.items():
+        got = _by_kind(_decode_prefix(replies[i]))
+        if got == expect:
+            whole += 1
+        else:
+            for kind in got:
+                assert got[kind] == expect[kind][:len(got[kind])]
+    # the victim's own reply is whole only where the fault met its last
+    # receive, everything already answered
+    assert whole >= 5, "a neighbour's reply was cut"
+    assert _no_helper_threads()

@@ -407,3 +407,208 @@ def test_hub_snapshot_carries_pump_route(monkeypatch):
         assert hub.snapshot()["pump_route"] == "python"
     finally:
         hub.close()
+
+
+# -- the edge turn's two halves (ISSUE 36) -----------------------------------
+
+
+def _changes_wire(lo: int, hi: int) -> bytes:
+    enc = protocol.encode()
+    for i in range(lo, hi):
+        enc.change({"key": f"k{i}", "change": i, "from": 0, "to": 1,
+                    "value": b"v" * (i * 7)})
+    parts = []
+    while True:
+        d = enc.read(1 << 20)
+        if not d:
+            return b"".join(parts)
+        parts.append(bytes(d))
+
+
+def _mixed_wire() -> tuple:
+    """Changes, zero-length blobs (raw frames: the encoder refuses
+    them), blobs wider than a receive slab, changes again:
+    ``(wire, blobs, n_changes)``."""
+    blobs = [b"", b"\x01" * 70_000, b"", bytes(range(256)) * 900, b"tail"]
+    wire = (_changes_wire(0, 40)
+            + b"".join(frame(TYPE_BLOB, data) for data in blobs)
+            + _changes_wire(40, 60))
+    return wire, blobs, 60
+
+
+def _drain_one(how: str, ep, dec, fan) -> tuple:
+    if how == "step":
+        return pump.recv_step(ep, dec)
+    if how == "halves":
+        return pump.recv_feed(ep, dec, pump.recv_fetch(ep))
+    fan.start("tok", ep)
+    token, fetched = fan.wait_one()
+    assert token == "tok"
+    return pump.recv_feed(ep, dec, fetched)
+
+
+@pytest.mark.parametrize("how", ["step", "halves", "fan"])
+def test_recv_step_is_fetch_then_feed(monkeypatch, how):
+    """recv_step, its two halves called in a row, and the halves with
+    the receive on a helper thread deliver the same frames in the same
+    order from the same bytes — slabs far smaller than the blobs, so
+    every blob straddles several."""
+    monkeypatch.setenv("DAT_PUMP", "native")
+    wire, blobs, n_changes = _mixed_wire()
+    a, b = socket.socketpair()
+    b.setblocking(False)
+    fan = pump.RecvFan(2) if how == "fan" else None
+    try:
+        dec = Decoder()
+        got: list = []
+        dec.change(lambda ch, done: (got.append(("c", ch.key)), done()))
+        dec.blob(lambda blob, done: blob.collect(
+            lambda data: (got.append(("b", bytes(data))), done())))
+        ep = pump.EdgePump(b.fileno(), cap=16 << 10)
+        sender = threading.Thread(
+            target=lambda: (a.sendall(wire), a.shutdown(socket.SHUT_WR)),
+            daemon=True)
+        sender.start()
+        total, eof = 0, False
+        deadline = time.monotonic() + 20
+        while not eof and time.monotonic() < deadline:
+            n, eof = _drain_one(how, ep, dec, fan)
+            total += n
+        sender.join(10)
+        assert eof and total == len(wire)
+        dec.end()
+        assert dec.finished
+        assert [g for g in got if g[0] == "b"] == [("b", d) for d in blobs]
+        assert [g[1] for g in got if g[0] == "c"] == [
+            f"k{i}" for i in range(n_changes)]
+        # changes 0-39 before the blobs, 40-59 after: one stream order
+        assert got.index(("b", b"tail")) < got.index(("c", "k40"))
+    finally:
+        if fan is not None:
+            fan.close()
+        a.close()
+        b.close()
+
+
+def test_recv_feed_observes_bulk_and_tells_errors_apart(monkeypatch):
+    """The feed half is where a helper's raw result becomes the turn's
+    outcome: would-block, EOF, a transport error raised on the feeding
+    thread, a vanished library — and `bulk` says whether the receive
+    came back with a full slice."""
+    monkeypatch.setenv("DAT_PUMP", "native")
+    monkeypatch.setattr(pump, "PUMP_SLICE", 4096)
+    a, b = socket.socketpair()
+    b.setblocking(False)
+    try:
+        dec = Decoder()
+        got = []
+        dec.blob(lambda blob, done: blob.collect(
+            lambda data: (got.append(len(data)), done())))
+        ep = pump.EdgePump(b.fileno(), cap=1 << 16)
+        assert ep.bulk is False
+        assert pump.recv_feed(ep, dec, pump.recv_fetch(ep)) == (0, False)
+        a.sendall(frame(TYPE_BLOB, b"x" * 100))
+        n, eof = pump.recv_feed(ep, dec, pump.recv_fetch(ep))
+        assert n > 100 and not eof and ep.bulk is False and got == [100]
+        a.sendall(frame(TYPE_BLOB, b"y" * 20_000))
+        n, eof = pump.recv_feed(ep, dec, pump.recv_fetch(ep))
+        assert n > 20_000 and ep.bulk is True and got == [100, 20_000]
+        buf = np.empty(16, dtype=np.uint8)
+        with pytest.raises(OSError) as ei:
+            pump.recv_feed(ep, dec, (buf, (-104, 0, 0, 0), 0.0))
+        assert ei.value.errno == 104 and ep.bulk is False
+        assert pump.recv_feed(ep, dec, (buf, (0, 0, 0, 0), 0.0)) == (0, True)
+        # the library gone between the two halves: the python arm, and
+        # never bulk again
+        ep.bulk = True
+        a.sendall(frame(TYPE_BLOB, b"z" * 7))
+        n, eof = pump.recv_feed(ep, dec, (buf, None, 0.0))
+        assert n > 7 and ep.native is False and ep.bulk is False
+        assert got == [100, 20_000, 7]
+    finally:
+        a.close()
+        b.close()
+
+
+def test_recv_fan_hands_back_what_a_helper_raised_and_closes():
+    fan = pump.RecvFan(2)
+    names = {t.name for t in threading.enumerate()}
+    assert {"edge-rx-0", "edge-rx-1"} <= names
+    fan.start("bad", object())  # no recv_st: AttributeError on the helper
+    token, fetched = fan.wait_one()
+    assert token == "bad" and isinstance(fetched, AttributeError)
+    a, b = socket.socketpair()
+    try:
+        b.setblocking(False)
+        ep = pump.EdgePump(b.fileno())
+        b_fd = b.fileno()
+        b.close()  # the descriptor dies: an errno, not an exception
+        fan.start("dead", ep)
+        token, (buf, r, seconds) = fan.wait_one()
+        assert token == "dead" and r[0] == -9 and b_fd == ep.fd  # EBADF
+    finally:
+        a.close()
+    fan.close()
+    assert not any(t.name.startswith("edge-rx-")
+                   for t in threading.enumerate())
+
+
+def test_recv_fan_stress_keeps_every_stream_whole(monkeypatch):
+    """Sixteen streams over four helpers under a short switch interval:
+    the per-session state a helper writes (slab, index arrays, stats)
+    never leaks into a neighbour's feed — every decoder gets its own
+    blobs, whole and in order."""
+    import sys
+
+    monkeypatch.setenv("DAT_PUMP", "native")
+    n = 16
+    wires, wants = [], []
+    for i in range(n):
+        blobs = [bytes([i, j]) * (3_000 + 997 * ((i + j) % 7))
+                 for j in range(40)]
+        wants.append(blobs)
+        wires.append(b"".join(frame(TYPE_BLOB, b) for b in blobs))
+    pairs = [socket.socketpair() for _ in range(n)]
+    fan = pump.RecvFan(4)
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        gots, decs, eps = [], [], []
+        for a, b in pairs:
+            b.setblocking(False)
+            got: list = []
+            dec = Decoder()
+            dec.blob(lambda blob, done, got=got: blob.collect(
+                lambda data: (got.append(bytes(data)), done())))
+            gots.append(got)
+            decs.append(dec)
+            eps.append(pump.EdgePump(b.fileno(), cap=8 << 10))
+        senders = [threading.Thread(
+            target=lambda a=a, w=w: (a.sendall(w),
+                                     a.shutdown(socket.SHUT_WR)),
+            daemon=True) for (a, _b), w in zip(pairs, wires)]
+        for t in senders:
+            t.start()
+        live = set(range(n))
+        deadline = time.monotonic() + 30
+        while live and time.monotonic() < deadline:
+            for i in live:
+                fan.start(i, eps[i])
+            for _ in range(len(live)):
+                i, fetched = fan.wait_one()
+                _nbytes, eof = pump.recv_feed(eps[i], decs[i], fetched)
+                if eof:
+                    live.discard(i)
+        assert not live, "streams still open at the deadline"
+        for t in senders:
+            t.join(10)
+            assert not t.is_alive()
+        for i in range(n):
+            decs[i].end()
+            assert decs[i].finished and gots[i] == wants[i], f"stream {i}"
+    finally:
+        sys.setswitchinterval(was)
+        fan.close()
+        for a, b in pairs:
+            a.close()
+            b.close()
