@@ -523,9 +523,14 @@ class EdgeLoop:
                 if sess.kind == "subscriber":
                     self._probe_subscriber(sess)
                 elif prof is not None:
+                    # on a turn that takes the second clock, the
+                    # thread's CPU inside the wall's two reads
                     t0 = time.monotonic()
+                    c0 = time.thread_time() if prof.cpu_turn else None
                     with _annotation("edge.read"):
                         rx = self._read_turn(sess, now)
+                    if c0 is not None:
+                        prof.read_cpu(time.thread_time() - c0)
                     prof.account("read", sess.key,
                                  time.monotonic() - t0, rx)
                     _M_RX.inc(rx)
@@ -603,6 +608,7 @@ class EdgeLoop:
             return frozenset(ready)
         with _annotation("edge.read"):
             t0 = time.monotonic()
+            c0 = time.thread_time() if prof.cpu_turn else None
             for sess in ready:
                 fan.start(sess, sess.pump)
             for _ in ready:
@@ -615,6 +621,10 @@ class EdgeLoop:
                 _M_RX.inc(rx)
                 _M_RX_FANNED.inc(rx)
                 t0 = t1
+            if c0 is not None:
+                # of them, the seconds it was on its CPU (the wait for
+                # a helper is off it): one pair of reads a turn
+                prof.read_cpu(time.thread_time() - c0)
         return frozenset(ready)
 
     def _feed_next(self, fan: RecvFan) -> tuple:

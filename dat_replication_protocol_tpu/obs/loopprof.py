@@ -57,7 +57,7 @@ from collections import deque
 from typing import Optional
 
 from .metrics import OBS as _OBS, counter as _counter, \
-    histogram as _histogram
+    cpu_clock_visit as _cpu_clock_visit, histogram as _histogram
 from .tracing import SPANS as _SPANS, _span_ids
 
 __all__ = ["LoopProfiler", "PHASES", "SAMPLE_EVERY", "TOP_K"]
@@ -86,6 +86,13 @@ _BEHIND_FRACTION = 0.5
 _H_POLL = _histogram("edge.turn.poll_wait_s")
 _H_ACCEPT = _histogram("edge.turn.accept_s")
 _H_READ = _histogram("edge.turn.read_s")
+# the read phase's second clock: the LOOP THREAD's CPU seconds inside
+# the same reads that feed edge.turn.read_s, on the turns that take it
+# (LoopProfiler.cpu_turn: one in CPU_CLOCK_EVERY).  Mean wall minus
+# mean cpu is the loop off its CPU in the phase: waiting for a
+# helper's receive (RecvFan.wait_one) or to take the interpreter lock
+# back
+_H_READ_CPU = _histogram("edge.turn.read_cpu_s")
 _H_HUB = _histogram("edge.turn.hub_drain_s")
 _H_TX = _histogram("edge.turn.tx_s")
 _H_OVERLOAD = _histogram("edge.turn.overload_ladder_s")
@@ -115,7 +122,7 @@ class LoopProfiler:
 
     Turn protocol, called by the lit dispatcher::
 
-        prof.turn_begin(t0)          # before select()
+        prof.turn_begin(t0)          # before select(); sets cpu_turn
         prof.poll_done(t1, nready)   # select() returned
         prof.phase("accept", dt)     # un-attributed phase work
         prof.account("read", key, dt, nbytes)  # per-session phase work
@@ -136,6 +143,9 @@ class LoopProfiler:
         self.lag_max_s = 0.0
         self.in_work = False
         self.running = False
+        # whether this turn's sites take the thread's CPU clock beside
+        # the wall clock (metrics.cpu_clock_visit of the turn's ordinal)
+        self.cpu_turn = False
         # turn-in-progress state (loop thread only)
         self._t0 = 0.0            # turn start (before select)
         self._work_t0 = 0.0       # select returned; work begins
@@ -143,6 +153,8 @@ class LoopProfiler:
         self._ready_since: Optional[float] = None
         self._phases: dict[str, float] = {}
         self._sessions: dict[str, list] = {}
+        # the read phase's thread CPU seconds; None: not clocked
+        self._read_cpu: Optional[float] = None
         # change-only span tiling state
         self._anchor: Optional[float] = None
         self._idle_turns = 0
@@ -169,6 +181,7 @@ class LoopProfiler:
 
     def turn_begin(self, t0: float) -> None:
         self._t0 = t0
+        self.cpu_turn = _cpu_clock_visit(self.turns)
         if self._anchor is None:
             self._anchor = t0
 
@@ -182,6 +195,13 @@ class LoopProfiler:
         """Accumulate un-attributed phase work for this turn.  ``name``
         is a :data:`PHASES` literal at the call site."""
         self._phases[name] = self._phases.get(name, 0.0) + seconds
+
+    def read_cpu(self, seconds: float) -> None:
+        """Accumulate the loop thread's CPU seconds inside read-phase
+        work that :meth:`account` times by the wall clock — called on a
+        turn whose :attr:`cpu_turn` is set, by a site that read
+        ``time.thread_time()`` inside its wall clock's reads."""
+        self._read_cpu = (self._read_cpu or 0.0) + seconds
 
     def account(self, name: str, session: str, seconds: float,
                 nbytes: int) -> None:
@@ -215,6 +235,8 @@ class LoopProfiler:
             h = _PHASE_HIST.get(name)
             if h is not None and sec > 0.0:
                 h.observe(sec)
+                if h is _H_READ and self._read_cpu is not None:
+                    _H_READ_CPU.observe(self._read_cpu)
         active = lag > 0.0 or bool(phases) or bool(self._sessions)
         if not active:
             # idle turn: coalesce into the NEXT active span so the
@@ -246,6 +268,7 @@ class LoopProfiler:
         self._idle_turns = 0
         self._idle_poll_s = 0.0
         self._phases = {}
+        self._read_cpu = None
         self._sessions = {}
 
     def flush(self, now: float) -> None:

@@ -39,6 +39,8 @@ from typing import Optional, Sequence
 
 __all__ = [
     "OBS",
+    "CPU_CLOCK_EVERY",
+    "cpu_clock_visit",
     "REGISTRY",
     "Counter",
     "Gauge",
@@ -74,6 +76,27 @@ class _Gate:
 
 
 OBS = _Gate()
+
+# The lit second clock — a thread's CPU seconds beside a region's wall
+# seconds (utils.trace spans, session.pump.recv_fetch, the edge loop's
+# read phase) — is read on ONE VISIT IN THIS MANY of each region,
+# starting with the first.  ``time.thread_time()`` is a real system
+# call with the interpreter lock held: ~0.3 us on bare Linux, but 6 us
+# alone and 20 us beside busy threads on a sandboxed host (gVisor, the
+# TPU machines'), where read at every visit it took 12% off the lit
+# rate of the busiest cell (PERF.md section 6, PR 37).  The wall clock
+# stays on every visit; a reader compares the two MEANS.
+CPU_CLOCK_EVERY = 8
+
+
+def cpu_clock_visit(n: int) -> bool:
+    """Whether visit ``n`` (0, 1, 2 ...) of a region takes the CPU
+    clock: the first does, and one in ``CPU_CLOCK_EVERY`` over any
+    run of visits, picked by a Weyl sequence (the fractional part of
+    ``n`` x the golden ratio) so that no period of the traffic — eight
+    sessions read in turn, a pack every second span — lines up with
+    the choice."""
+    return (n * 0x9E3779B1) & 0xFFFFFFFF < (1 << 32) // CPU_CLOCK_EVERY
 
 
 def enable(frames: bool = True) -> None:

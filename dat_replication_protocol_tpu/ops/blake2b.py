@@ -823,8 +823,15 @@ def blake2b_batch_begin(
             return jax.device_put(words), lengths
 
         def run(words_d, lengths, n):
-            hh, hl = words_fn(words_d, jnp.asarray(lengths), digest_size)
-            return hh, hh[:n], hl[:n]
+            # the launch's three parts, each a child span: a second
+            # host-to-device transfer, the jit call, and two slice
+            # programs (a jax op each, a program per item count)
+            with span("digest.launch.lengths"):
+                lengths_d = jnp.asarray(lengths)
+            with span("digest.launch.program"):
+                hh, hl = words_fn(words_d, lengths_d, digest_size)
+            with span("digest.launch.slice"):
+                return hh, hh[:n], hl[:n]
     else:
         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -841,8 +848,11 @@ def blake2b_batch_begin(
 
         def run(words_d, lengths, n):
             # fetched whole, the items' rows picked in collect(): a
-            # slice of a sharded array is a program per item count
-            hh, hl = words_fn(words_d, lengths)
+            # slice of a sharded array is a program per item count;
+            # the lengths went up with the words: the jit call is the
+            # launch's one child here
+            with span("digest.launch.program"):
+                hh, hl = words_fn(words_d, lengths)
             return hh, hh, hl
     engine = "pallas" if use_pallas else "xla-scan"
     if _OBS.on:

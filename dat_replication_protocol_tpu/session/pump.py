@@ -45,16 +45,17 @@ model and the batch-size sweep.
 
 from __future__ import annotations
 
+import itertools
 import os
 import queue
 import threading
-from time import perf_counter as _perf
+from time import perf_counter as _perf, thread_time as _thread_time
 from typing import Callable, Optional
 
 import numpy as np
 
 from ..obs.metrics import OBS as _OBS, counter as _counter, \
-    histogram as _histogram
+    cpu_clock_visit as _cpu_clock_visit, histogram as _histogram
 from ..obs import wirecost as _wirecost
 from ..runtime import native
 from ..utils.trace import span
@@ -89,10 +90,21 @@ _M_SAVED = _counter("transport.pump.syscalls_saved")
 _M_BYTES = _counter("transport.pump.bytes")
 _M_GATHER_BYTES = _counter("transport.pump.gather.bytes")
 _M_FALLBACK = _counter("transport.pump.route.python")
-# time spent inside one native pump call — the GIL is released for the
-# whole span, so this histogram IS the GIL-released time the batching
-# buys back from the interpreter
+# time spent inside one native pump call, receive or send — the GIL is
+# released inside the call and TAKEN BACK before it returns, so beside
+# another busy thread (the hub dispatcher, the edge loop's feeds) a
+# reading ends when the caller has the lock again: released time plus
+# the wait for the lock, not released time alone
 _H_NATIVE = _histogram("transport.pump.native.seconds")
+# the two clocks of ONE region, recv_fetch's native receive, on
+# whichever thread ran it (a RecvFan helper, or the edge loop inline):
+# wall seconds and that thread's CPU seconds — the kernel's socket
+# copies and the frame scan — the wall clock on every lit receive, the
+# CPU clock on one in CPU_CLOCK_EVERY.  Mean wall minus mean cpu is the
+# caller's wait to have the interpreter lock back after the receive
+_H_FETCH = _histogram("pump.fetch.seconds")
+_H_FETCH_CPU = _histogram("pump.fetch.cpu_seconds")
+_fetches = itertools.count()  # lit receives; next() is atomic
 
 
 def effective_pump_route() -> str:
@@ -527,13 +539,28 @@ def recv_fetch(pump: EdgePump) -> tuple:
     descriptor, the slab and the session's own :class:`_RecvState` and
     nothing else, so the edge loop may run it on a helper thread
     (one receive in flight a session, fed before the next begins).
+    Lit, the call's two clocks feed ``pump.fetch.seconds`` /
+    ``pump.fetch.cpu_seconds`` from the thread that made it.
     Returns what :func:`recv_feed` takes: ``(slab, result, seconds)``."""
     st = pump.recv_st
     buf = np.empty(st.cap, dtype=np.uint8)  # fresh: see _RecvState
+    # one gate check a slab; dark, no CPU clock is read — and lit, on
+    # one receive in CPU_CLOCK_EVERY, inside the wall clock's reads
+    lit = _OBS.on
+    clocked = lit and _cpu_clock_visit(next(_fetches))
     t0 = _perf()
+    if clocked:
+        c0 = _thread_time()
     r = native.pump_recv_scan(pump.fd, buf, PUMP_SLICE, st.starts,
                               st.lens, st.ids, st.stats)
-    return buf, r, _perf() - t0
+    if clocked:
+        cpu = _thread_time() - c0
+    seconds = _perf() - t0
+    if lit:
+        _H_FETCH.observe(seconds)
+        if clocked:
+            _H_FETCH_CPU.observe(cpu)
+    return buf, r, seconds
 
 
 def recv_feed(pump: EdgePump, decoder: Decoder, fetched: tuple,

@@ -20,8 +20,14 @@ path is bracketed with::
   of one device batch shares the pipeline's dispatch ordinal.  (3) With
   the gate on, the duration is added to the histogram
   ``span.<name>.seconds``, whose ``count``/``sum`` ride every
-  ``--stats-fd`` snapshot.  With the gate off the fields are dropped
-  and the bound factory's annotation is returned directly.
+  ``--stats-fd`` snapshot, and on one visit in
+  ``CPU_CLOCK_EVERY`` of each name, the first included, the THREAD'S
+  CPU seconds inside the span (``time.thread_time()``, read inside the
+  wall clock's two reads) go on the record as field ``cpu`` and into
+  the twin histogram ``span.<name>.cpu_seconds``.  Mean wall minus
+  mean cpu is the span's off-CPU time, and rule 1 below says what
+  that can be.  With the gate off the fields are dropped, no clock is
+  read and the bound factory's annotation is returned directly.
 * :func:`annotation` — the first third alone, for a site whose lit
   record another recorder already keeps (the edge loop's
   ``LoopProfiler``).
@@ -29,7 +35,13 @@ path is bracketed with::
 Two rules keep the idle-gap attribution honest (OBSERVABILITY.md): no
 span brackets a wait on another thread or on a peer, and none brackets
 a session, connection or loop lifetime — sites are per batch, per pump
-slab or per lit loop turn, never per item or per frame.
+slab or per lit loop turn, never per item or per frame.  The first
+rule's corollary: inside a stage span a thread is off its CPU only
+while it waits to take the interpreter lock back or is blocked inside
+the runtime.  ``digest.d2h_wait`` (the one sanctioned wait for the
+device: off-CPU nearly all of it) and ``digest.pack`` (no blocking
+runtime call: off-CPU is the lock alone) calibrate the reading in every
+lit run.
 
 JAX is imported lazily: the session layer must stay importable (and
 fast) in processes that never touch a device.
@@ -40,11 +52,14 @@ fast) in processes that never touch a device.
 
 from __future__ import annotations
 
+import itertools
 import sys
 import threading
+from time import thread_time as _thread_time
 
 from ..obs import tracing as _obs_tracing
-from ..obs.metrics import OBS as _OBS, histogram as _histogram
+from ..obs.metrics import OBS as _OBS, \
+    cpu_clock_visit as _cpu_clock_visit, histogram as _histogram
 
 
 class _NullSpan:
@@ -83,10 +98,20 @@ def _reset_span_binding_for_tests() -> None:
     _span_factory = None
 
 
-# span name -> its `span.<name>.seconds` histogram, bound at the
-# name's first lit use (a dict read under the GIL afterwards; the
-# registry keeps registrations across reset(), so handles stay valid)
+# span name -> (its `span.<name>.seconds` histogram, its
+# `span.<name>.cpu_seconds` histogram, the count of its lit visits),
+# bound together at the name's first lit use (a dict read under the
+# GIL afterwards; the registry keeps registrations across reset(), so
+# handles stay valid)
 _span_hists: dict = {}
+
+
+def _bind_span_hists(name: str) -> tuple:
+    bound = _span_hists[name] = (
+        _histogram(f"span.{name}.seconds"),
+        _histogram(f"span.{name}.cpu_seconds"), itertools.count())
+    return bound
+
 
 # the `batch` field of the innermost lit span that carries one, per
 # thread: what a stage opened inside it inherits
@@ -94,11 +119,16 @@ _tls = threading.local()
 
 
 class _JoinedSpan:
-    """The profiler annotation plus the two lit readings of the same
+    """The profiler annotation plus the lit readings of the same
     region: one obs span record (``src="jax"``, parent link, fields,
-    inherited ``batch``) and one ``span.<name>.seconds`` observation."""
+    inherited ``batch``) and one ``span.<name>.seconds`` observation;
+    on a visit that takes the second clock (module docstring), also
+    the thread's ``cpu`` seconds on the record and one
+    ``span.<name>.cpu_seconds`` observation.  The CPU clock is read
+    INSIDE the wall clock's reads, so a record's ``cpu`` passes its
+    ``dur`` by the clock's grain at most."""
 
-    __slots__ = ("_span", "_inner", "_outer_batch")
+    __slots__ = ("_span", "_inner", "_outer_batch", "_hists", "_cpu0")
 
     def __init__(self, name: str, inner, fields: dict | None = None):
         self._span = _obs_tracing.trace_span(name, src="jax",
@@ -122,21 +152,28 @@ class _JoinedSpan:
             self._span.__exit__(*sys.exc_info())
             _tls.batch = outer
             raise
+        name = self._span.name
+        hists = self._hists = _span_hists.get(name) \
+            or _bind_span_hists(name)
+        self._cpu0 = _thread_time() \
+            if _cpu_clock_visit(next(hists[2])) else None
         return self
 
     def __exit__(self, *exc):
+        cpu0 = self._cpu0
+        cpu = None if cpu0 is None else _thread_time() - cpu0
         try:
             return self._inner.__exit__(*exc) or False
         finally:
             span_ = self._span
+            if cpu is not None:
+                span_.fields["cpu"] = cpu
             span_.__exit__(*exc)
             _tls.batch = self._outer_batch
             if span_.dur is not None:
-                hist = _span_hists.get(span_.name)
-                if hist is None:
-                    hist = _span_hists[span_.name] = _histogram(
-                        f"span.{span_.name}.seconds")
-                hist.observe(span_.dur)
+                self._hists[0].observe(span_.dur)
+                if cpu is not None:
+                    self._hists[1].observe(cpu)
 
 
 def annotation(name: str):

@@ -652,6 +652,18 @@ def test_edge_fanned_and_inline_receive_agree(route, monkeypatch,
     else:
         assert fanned == 0
     assert loop._rx_fan is None and _no_helper_threads()
+    # ISSUE 37: the read phase's two clocks on every route, and on the
+    # native ones the receives' own pair, from whichever thread ran them
+    hists = obs_enabled.snapshot()["histograms"]
+    pairs = [("edge.turn.read_s", "edge.turn.read_cpu_s")]
+    if route != "python":
+        pairs.append(("pump.fetch.seconds", "pump.fetch.cpu_seconds"))
+    else:
+        assert hists["pump.fetch.seconds"]["count"] == 0
+    for wall, cpu in pairs:
+        # the wall clock on every visit, the CPU clock on one in a few
+        assert hists[wall]["count"] >= hists[cpu]["count"] > 0, wall
+        assert 0.0 < hists[cpu]["sum"] <= hists[wall]["sum"], wall
 
 
 @needs_native

@@ -14,14 +14,23 @@ Three contracts under test:
   *exactly* 0.0 (the selector's wait is sanctioned, not lag), a stalled
   turn reads its overrun, the live view extrapolates mid-turn, and the
   watermark board exports ``edge.loop.lag{loop=}`` only while live.
+* **The read phase's second clock** (ISSUE 37) — the loop thread's CPU
+  seconds inside the reads that feed ``edge.turn.read_s``, as
+  ``edge.turn.read_cpu_s``: on the turns that take it (one in
+  ``CPU_CLOCK_EVERY``, the first included) one observation beside its
+  wall twin's, never more seconds than it, by the lit twin only.
 """
 
+import dis
 import socket
 import threading
 import time
 
+import pytest
+
 from dat_replication_protocol_tpu.edge import EdgeLoop
 from dat_replication_protocol_tpu.hub import ReplicationHub
+from dat_replication_protocol_tpu.obs import loopprof as loopprof_mod
 from dat_replication_protocol_tpu.obs.loopprof import LoopProfiler, PHASES
 from dat_replication_protocol_tpu.obs.tracing import SPANS
 
@@ -152,6 +161,94 @@ def test_gate_off_records_nothing():
     finally:
         metrics.OBS.on = was_on
         WATERMARKS.untrack_loop(loop.profiler.name)
+
+
+# -- the read phase's second clock (ISSUE 37) --------------------------------
+
+def _read_pair(metrics) -> tuple:
+    h = metrics.snapshot()["histograms"]
+    return h["edge.turn.read_s"], h["edge.turn.read_cpu_s"]
+
+
+@pytest.mark.parametrize("turns, want_counts, want_wall, want_cpu", [
+    # one turn, two sessions read: ONE observation of each clock
+    ([[("read", 0.004, 0.003), ("read", 0.002, 0.0005)]],
+     (1, 1), 0.006, 0.0035),
+    # a turn that only transmitted feeds neither
+    ([[("tx", 0.004, None)]], (0, 0), 0.0, 0.0),
+    # two turns, the site took the CPU clock on the first alone: the
+    # wall twin has both, the CPU twin one, and nothing leaks over
+    ([[("read", 0.004, 0.004), ("tx", 0.001, None)],
+      [("read", 0.010, None)]], (2, 1), 0.014, 0.004),
+    # a read that spent no CPU at all (all of it waiting) still pairs
+    ([[("read", 0.005, 0.0)]], (1, 1), 0.005, 0.0),
+])
+def test_read_cpu_twin_is_observed_with_its_wall_twin(
+        obs_enabled, turns, want_counts, want_wall, want_cpu):
+    prof = LoopProfiler("unit-cpu", tick=0.05)
+    t = 100.0
+    for phases in turns:
+        prof.turn_begin(t)
+        prof.poll_done(t + 0.001, 1)
+        for name, seconds, cpu in phases:
+            prof.account(name, "s1", seconds, 10)
+            if cpu is not None:
+                prof.read_cpu(cpu)
+        t += 0.02
+        prof.turn_done(t, sessions=1)
+    wall, cpu = _read_pair(obs_enabled)
+    assert (wall["count"], cpu["count"]) == want_counts
+    assert wall["sum"] == pytest.approx(want_wall)
+    assert cpu["sum"] == pytest.approx(want_cpu)
+    assert cpu["sum"] <= wall["sum"]
+
+
+def test_the_profiler_says_which_turns_take_the_second_clock():
+    from dat_replication_protocol_tpu.obs.metrics import CPU_CLOCK_EVERY
+
+    prof = LoopProfiler("unit-beat", tick=0.05)
+    taken = []
+    for i in range(10 * CPU_CLOCK_EVERY):
+        prof.turn_begin(float(i))
+        taken.append(prof.cpu_turn)
+        prof.poll_done(i + 0.001, 0)
+        prof.turn_done(i + 0.002)
+    assert taken[0] is True and 7 <= sum(taken) <= 13
+
+
+def test_a_served_loop_feeds_both_clocks_of_its_read_phase(obs_enabled,
+                                                           monkeypatch):
+    """Through the lit dispatcher itself, every turn made to take the
+    second clock: each turn that read observed both clocks, and the
+    thread's CPU seconds fit inside its wall seconds."""
+    monkeypatch.setattr(loopprof_mod, "_cpu_clock_visit", lambda n: True)
+    hub = ReplicationHub(linger_s=0.002)
+    loop = EdgeLoop(hub, max_sessions=3, tick=0.01, profile_every=1)
+    try:
+        _run_sessions(loop, 3)
+    finally:
+        hub.close()
+    wall, cpu = _read_pair(obs_enabled)
+    assert wall["count"] == cpu["count"] >= 3
+    assert 0.0 < cpu["sum"] <= wall["sum"]
+
+
+def test_only_the_lit_branches_read_the_cpu_clock():
+    """``_dark_turn`` names no clock but the one it had; the shared
+    per-session turns read ``thread_time`` after their ``prof`` test
+    only, in bytecode order."""
+    assert "thread_time" not in EdgeLoop._dark_turn.__code__.co_names
+    assert "thread_time" not in EdgeLoop._lit_turn.__code__.co_names
+    for fn in (EdgeLoop._io_turn, EdgeLoop._fan_reads):
+        seen_prof = False
+        reads = 0
+        for ins in dis.get_instructions(fn):
+            if ins.argval == "prof":
+                seen_prof = True
+            if ins.argval == "thread_time":
+                assert seen_prof, fn.__qualname__
+                reads += 1
+        assert reads == 2, fn.__qualname__
 
 
 # -- lag semantics (unit level: the profiler drives itself) ------------------

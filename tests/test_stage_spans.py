@@ -14,13 +14,20 @@ Under test here:
   their totals at quiescence are what they were;
 * ``OBS.frames``: ``--stats-fd`` alone records no per-frame instant,
   ``--trace-jsonl`` still does;
-* the dark path of every new site: a ``span()`` call and nothing else.
+* the dark path of every new site: a ``span()`` call and nothing else;
+* ISSUE 37: ``digest.launch`` split into child spans where the work
+  happens, and every stage span's second clock — the thread's CPU
+  seconds as field ``cpu`` and ``span.<name>.cpu_seconds``, on one
+  visit in ``CPU_CLOCK_EVERY`` of a name — on spans that sleep and
+  spans that burn; ``recv_fetch``'s pair of clocks.
 """
 
 import dis
 import hashlib
 import inspect
+import itertools
 import os
+import socket
 import subprocess
 import sys
 import time
@@ -132,13 +139,16 @@ def test_each_bucket_stages_once_and_ships_what_it_staged(obs_enabled,
     assert got.get("digest.stage.reuse", 0) == 3 * (rounds - 1)
 
 
-def test_batch_is_inherited_only_inside_a_span_that_carries_it(obs_enabled):
+def test_batch_is_inherited_only_inside_a_span_that_carries_it(obs_enabled,
+                                                               monkeypatch):
+    monkeypatch.setattr(trace_mod, "_span_hists", {})  # first visits
     with trace_mod.span("outer.stage", batch=7, items=2):
         with trace_mod.span("inner.stage", items=1):
             pass
     with trace_mod.span("later.stage"):
         pass
     inner, = SPANS.spans("inner.stage")
+    assert 0.0 <= inner["fields"].pop("cpu") <= inner["dur"]
     assert inner["fields"] == {"src": "jax", "items": 1, "batch": 7}
     assert "batch" not in SPANS.spans("later.stage")[0]["fields"]
 
@@ -466,6 +476,207 @@ def test_edge_dark_twin_has_no_stage_site():
         assert names & {"edge.read", "edge.hub_drain", "edge.tx"}
     assert trace_mod.annotation("edge.read").__class__ is \
         trace_mod._span_factory
+
+
+# -- (f) ISSUE 37: digest.launch's children and the second clock --------------
+
+LAUNCH_CHILDREN = ("digest.launch.lengths", "digest.launch.program",
+                   "digest.launch.slice")
+
+
+def test_launch_children_nest_under_digest_launch(obs_enabled, monkeypatch):
+    """One chip: the second transfer, the jit call and the two slices
+    are each a child of ``digest.launch``, carry the batch's ordinal,
+    and their wall seconds fit inside the parent's (what is left is the
+    parent's self time: the pool's ``give`` and the bookkeeping)."""
+    monkeypatch.setenv("DAT_DEVICE_HASH", "1")
+    monkeypatch.setattr(trace_mod, "_span_hists", {})  # first visits
+    pipe = DigestPipeline(max_batch=4)
+    got = []
+    payloads = [b"launch-%d" % i for i in range(4)]
+    for p in payloads:
+        pipe.submit(p, got.append)
+    pipe.flush()
+    assert got == [_blake(p) for p in payloads]
+    launch, = SPANS.spans("digest.launch")
+    kids = {n: SPANS.spans(n) for n in LAUNCH_CHILDREN}
+    assert {n: len(r) for n, r in kids.items()} == \
+        {n: 1 for n in LAUNCH_CHILDREN}
+    for n, (rec,) in kids.items():
+        assert rec["parent"] == launch["id"], n
+        assert rec["fields"]["batch"] == launch["fields"]["batch"] == 1, n
+        assert launch["ts"] <= rec["ts"], n
+        assert _hist(f"span.{n}.seconds")["count"] == 1
+        assert _hist(f"span.{n}.cpu_seconds")["count"] == 1
+    assert sum(r[0]["dur"] for r in kids.values()) <= launch["dur"]
+    order = [kids[n][0]["ts"] for n in LAUNCH_CHILDREN]
+    assert order == sorted(order)
+
+
+def test_the_mesh_arm_launches_with_the_program_alone(obs_enabled,
+                                                      monkeypatch):
+    """Over a mesh the lengths ride the one ``device_put`` and the
+    digests are cut on the host: ``.program`` is the launch's one
+    child, and ``.lengths`` / ``.slice`` observe nothing."""
+    from dat_replication_protocol_tpu.parallel import mesh as pmesh
+
+    monkeypatch.setattr(trace_mod, "_span_hists", {})  # first visits
+    payloads = [b"mesh-%d" % i for i in range(6)]
+    assert blake2b_mod.blake2b_batch_begin(
+        payloads, mesh=pmesh.make_mesh(4))() == _host_batch(payloads)
+    launch, = SPANS.spans("digest.launch")
+    program, = SPANS.spans("digest.launch.program")
+    assert program["parent"] == launch["id"]
+    assert program["dur"] <= launch["dur"]
+    assert not SPANS.spans("digest.launch.lengths")
+    assert not SPANS.spans("digest.launch.slice")
+    assert _hist("span.digest.launch.program.cpu_seconds")["count"] == 1
+    # (the registry keeps a name once registered: counts, not names)
+    for gone in ("lengths", "slice"):
+        assert _hist(f"span.digest.launch.{gone}.seconds")["count"] == 0
+
+
+def test_cpu_is_within_wall_on_every_span_and_every_pair(obs_enabled,
+                                                         monkeypatch):
+    """Batches through the served pipeline: the first visit of every
+    stage takes the second clock and one visit in ``CPU_CLOCK_EVERY``
+    after it; a record that carries ``cpu`` carries no more than its
+    ``dur``; every ``span.*.seconds`` histogram has its ``cpu_seconds``
+    twin, fed on those visits alone."""
+    every = obs_metrics.CPU_CLOCK_EVERY
+    rounds = 4 * every
+    monkeypatch.setenv("DAT_DEVICE_HASH", "1")
+    monkeypatch.setattr(trace_mod, "_span_hists", {})
+    pipe = DigestPipeline(max_batch=2)
+    got = []
+    for i in range(2 * rounds):
+        pipe.submit(b"pair-%d" % i, got.append)
+    pipe.flush()
+    assert len(got) == 2 * rounds
+    recs = [r for r in SPANS.spans() if r["fields"].get("src") == "jax"]
+    names = set(DIGEST_STAGES) | set(LAUNCH_CHILDREN)
+    assert {r["span"] for r in recs} >= names
+    for name in names:
+        mine = [r for r in recs if r["span"] == name]
+        assert len(mine) == rounds, name
+        assert "cpu" in mine[0]["fields"], name    # the first visit
+        clocked = [r for r in mine if "cpu" in r["fields"]]
+        assert rounds // every - 2 <= len(clocked) <= rounds // every + 2
+        for r in clocked:
+            assert 0.0 <= r["fields"]["cpu"] <= r["dur"], name
+        wall = _hist(f"span.{name}.seconds")
+        cpu = _hist(f"span.{name}.cpu_seconds")
+        assert wall["count"] == rounds and cpu["count"] == len(clocked)
+        assert cpu["sum"] == pytest.approx(
+            sum(r["fields"]["cpu"] for r in clocked))
+        assert cpu["sum"] <= sum(r["dur"] for r in clocked)
+
+
+def test_the_second_clock_is_taken_on_one_visit_in_a_few():
+    """``cpu_clock_visit``: the first visit, one in ``CPU_CLOCK_EVERY``
+    over any long run, and never on a fixed beat — eight sessions read
+    in turn must not always clock the same one."""
+    every = obs_metrics.CPU_CLOCK_EVERY
+    picks = [n for n in range(100 * every)
+             if obs_metrics.cpu_clock_visit(n)]
+    assert picks[0] == 0
+    assert 90 <= len(picks) <= 110
+    gaps = {b - a for a, b in zip(picks, picks[1:])}
+    assert len(gaps) > 1 and max(gaps) <= 2 * every
+    for period in (2, 3, 4, 7, 8, 16):
+        assert len({n % period for n in picks}) == period, period
+
+
+def _sleep_20ms():
+    time.sleep(0.020)
+
+
+def _burn_20ms():
+    # on the thread's own CPU clock, so that a loaded machine cannot
+    # starve the loop of the CPU it is meant to use
+    end = time.thread_time() + 0.020
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("body, least, most", [
+    (_sleep_20ms, 0.0, 0.005),      # off its CPU: the wall moves alone
+    (_burn_20ms, 0.015, None),      # on it: cpu follows, and stays
+])                                  # inside the wall
+def test_the_second_clock_tells_work_from_waiting(obs_enabled, monkeypatch,
+                                                  body, least, most):
+    monkeypatch.setattr(trace_mod, "_span_hists", {})  # a first visit
+    with trace_mod.span("clock.probe"):
+        body()
+    rec, = SPANS.spans("clock.probe")
+    cpu, wall = rec["fields"]["cpu"], rec["dur"]
+    assert wall >= 0.019
+    assert least <= cpu <= (wall if most is None else most)
+    assert _hist("span.clock.probe.cpu_seconds")["sum"] == \
+        pytest.approx(cpu)
+    assert _hist("span.clock.probe.seconds")["sum"] == pytest.approx(wall)
+
+
+def test_dark_spans_read_no_cpu_clock_and_register_no_twin(monkeypatch):
+    """Gate off: ``span()`` is the bare annotation — no ``cpu_seconds``
+    histogram comes to be and the CPU clock is never read."""
+    assert not obs_metrics.OBS.on
+    reads = []
+    monkeypatch.setattr(trace_mod, "_thread_time",
+                        lambda: reads.append(1) or 0.0)
+    hists = set(obs_metrics.snapshot()["histograms"])
+    s = trace_mod.span("dark.probe", items=1)
+    assert type(s) is (trace_mod._span_factory
+                       or trace_mod._bind_span_factory())
+    with s:
+        pass
+    assert reads == []
+    after = set(obs_metrics.snapshot()["histograms"])
+    assert after == hists
+    assert "span.dark.probe.cpu_seconds" not in after
+    assert "_thread_time" not in trace_mod.span.__code__.co_names
+
+
+@pytest.mark.parametrize("lit", [False, True])
+def test_recv_fetch_reads_the_cpu_clock_only_when_lit(request, monkeypatch,
+                                                      lit):
+    """The receive half's pair of clocks: dark, one gate check and no
+    CPU clock; lit, ``pump.fetch.seconds`` on every receive — also the
+    one that found nothing — and on the receives that take the second
+    clock (the first of them here) two reads of it inside the wall
+    clock's and one ``pump.fetch.cpu_seconds`` observation."""
+    if lit:
+        request.getfixturevalue("obs_enabled")
+    assert obs_metrics.OBS.on is lit
+    monkeypatch.setenv("DAT_PUMP", "native")
+    reads = []
+    real = time.thread_time
+    monkeypatch.setattr(pump_mod, "_thread_time",
+                        lambda: reads.append(1) or real())
+    monkeypatch.setattr(pump_mod, "_fetches", itertools.count())
+    before = {n: _hist(n)["count"]
+              for n in ("pump.fetch.seconds", "pump.fetch.cpu_seconds")}
+    a, b = socket.socketpair()
+    b.setblocking(False)
+    try:
+        ep = pump_mod.EdgePump(b.fileno(), cap=1 << 16)
+        if not ep.native:
+            pytest.skip("no native pump library on this machine")
+        a.sendall(b"\x00" * 4096)
+        for want in (4096, -11):    # a slab, then would-block
+            _buf, r, seconds = pump_mod.recv_fetch(ep)
+            assert r[0] == want and seconds >= 0.0
+    finally:
+        a.close()
+        b.close()
+    # of two receives the first takes the second clock (visits 0, 1)
+    assert len(reads) == (2 if lit else 0)
+    wall, cpu = (_hist(n) for n in before)
+    assert wall["count"] - before["pump.fetch.seconds"] == (2 if lit else 0)
+    assert cpu["count"] - before["pump.fetch.cpu_seconds"] == \
+        (1 if lit else 0)
+    if lit:
+        assert 0.0 <= cpu["sum"] <= wall["sum"]
 
 
 def test_backend_opens_no_second_span_system_at_its_sites():
