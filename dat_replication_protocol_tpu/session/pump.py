@@ -16,7 +16,9 @@ the byte loops through the C extension instead:
   plus the finished frame index.  Python sees only coalesced units:
   columnar ChangeBatch runs, blob extents as memoryviews, control
   frames individually (exactly what the decoder's bulk dispatch already
-  surfaces).
+  surfaces).  On a connection whose receives come back full, a helper
+  thread receives the next slabs while the session thread feeds this
+  one (``READAHEAD``).
 * **Send** (:func:`send_pump`): megabyte pulls from the encoder pushed
   through ``dat_pump_send``'s gather loop (sendmmsg batches, writev
   fallback, partial acceptance resumed natively).
@@ -36,18 +38,22 @@ checkpoints, and structured errors — enforced by the chaos parity
 sweep (tests/test_pump_parity.py); the Python pump stays the portable
 reference, never a second protocol.
 
-Backpressure is the transport module's contract verbatim: the receive
-pump stops calling into the kernel while the decoder stalls (the
-kernel socket buffer absorbs the window), the send pump stops pulling
-while the transport blocks.  PERF.md "Wire pump" has the syscall cost
+Backpressure is the transport module's contract: the receive pump
+starts no receive while the decoder stalls (the kernel socket buffer
+absorbs the window) — past a stall it has taken at most ``READAHEAD``
+slabs more than the inline pump would — and the send pump stops
+pulling while the transport blocks.  PERF.md "Wire pump" has the syscall cost
 model and the batch-size sweep.
 """
 
 from __future__ import annotations
 
+import errno
 import itertools
 import os
 import queue
+import socket
+import stat
 import threading
 from time import perf_counter as _perf, thread_time as _thread_time
 from typing import Callable, Optional
@@ -78,6 +84,14 @@ __all__ = [
 # smaller slices re-enter the interpreter per ~kernel-buffer-full.
 PUMP_BUF = 2 << 20
 PUMP_SLICE = 1 << 20
+# recv_pump's read-ahead: slabs its helper may have received that the
+# session thread has not fed yet.  Past a stalled decoder the socket
+# stops at most READAHEAD x PUMP_BUF later than an inline pump's would
+# (ROBUSTNESS.md).  2, not 1: with a second job queued the helper hands
+# slab n+1 back and starts n+2 in ONE hold of the interpreter lock; at
+# depth 1 its next receive waits for the session thread to let the
+# lock go (PERF.md §6, PR 39)
+READAHEAD = 2
 # send pull size: one encoder.read per native gather call
 PUMP_SEND_CHUNK = 1 << 20
 
@@ -105,6 +119,10 @@ _H_NATIVE = _histogram("transport.pump.native.seconds")
 _H_FETCH = _histogram("pump.fetch.seconds")
 _H_FETCH_CPU = _histogram("pump.fetch.cpu_seconds")
 _fetches = itertools.count()  # lit receives; next() is atomic
+# recv_pump's read-ahead, lit: slabs received on its helper, and of
+# them the ones already in when the session thread asked (the hit share)
+_M_RA_SLABS = _counter("pump.readahead.slabs")
+_M_RA_READY = _counter("pump.readahead.ready")
 
 
 def effective_pump_route() -> str:
@@ -205,11 +223,20 @@ def recv_pump(decoder: Decoder, fd: int,
     """Pump ``fd`` into ``decoder`` until EOF or destroy, batched.
 
     The native twin of :func:`.transport.recv_over` (same flow-control
-    contract: reading suspends while the decoder stalls, resuming on
+    contract: no receive starts while the decoder stalls, resuming on
     its drain watcher).  ``tap`` observes every received slab as the
     exact ``bytes`` object the decoder is fed — the fan-out source's
     publish hook, byte-identical to wrapping ``read_bytes``.  Falls
     back to the Python pump when the route (or the library) says so.
+
+    Each slab is :func:`recv_fetch` then :func:`recv_feed`, the edge
+    legs' two halves.  A connection starts with both on this thread,
+    in a row; once a receive comes back with a full ``PUMP_SLICE``
+    (``EdgePump.bulk``) the receives move to one helper thread that
+    runs up to ``READAHEAD`` slabs ahead of the feeds
+    (:class:`_ReadAhead`).  The feeds — EOF and errors, the tap, the
+    lit counters, ``decoder.write_indexed`` — stay here, in slab
+    order, and ``decoder.end()`` follows the last of them.
     """
     if effective_pump_route() != "native":
         if _OBS.on:
@@ -217,63 +244,164 @@ def recv_pump(decoder: Decoder, fd: int,
         read_bytes = _tapped_reader(fd, tap)
         recv_over(decoder, _metered_reader(decoder, read_bytes))
         return
-    st = _RecvState(cap)
     wake = threading.Event()
     decoder._add_drain_watcher(wake.set)
+    ahead = _ReadAhead(fd, cap, wake)
     try:
         while not decoder.destroyed:
-            buf = np.empty(st.cap, dtype=np.uint8)  # fresh: see _RecvState
-            t0 = _perf()
-            # a blocking read plus the frame scan: where the peer keeps
-            # the socket full it is the kernel's copy and the scan,
-            # where it does not it is the wait for the peer — the one
-            # wait a stage span may bracket (OBSERVABILITY.md)
-            with span("pump.recv"):
-                r = native.pump_recv_scan(fd, buf, PUMP_SLICE, st.starts,
-                                          st.lens, st.ids, st.stats)
+            pump, fetched = ahead.next(decoder)
+            if pump is None:  # destroyed while it waited for a receive
+                return
+            if isinstance(fetched, BaseException):
+                raise fetched  # the helper's, in slab order
+            r = fetched[1]
             if r is None:  # library vanished mid-session (tests reset)
+                # a receive still in flight finds no library either and
+                # returns without reading: the fd's bytes are all ahead
+                ahead.close(shut=False)
                 recv_over(decoder,
                           _metered_reader(decoder, _tapped_reader(fd, tap)))
                 return
-            nbytes, nframes, consumed, _err = r
-            if _OBS.on:
-                _H_NATIVE.observe(_perf() - t0)
-            if nbytes == 0:
+            wake.clear()
+            if r[0] > 0:
+                # frame callbacks, blob join, digest submits (a private
+                # pipeline's digest.* stages nest inside)
+                with span("decode.write", bytes=r[0], frames=r[1]):
+                    nbytes, eof = recv_feed(pump, decoder, fetched, tap)
+            else:  # EOF, or an errno recv_feed raises
+                nbytes, eof = recv_feed(pump, decoder, fetched, tap)
+            if eof:
                 if not decoder.destroyed and not decoder.finished:
                     decoder.end()
                 return
-            if nbytes < 0:
-                raise OSError(-nbytes, os.strerror(-nbytes))
-            if _OBS.on:
-                _note_batch(nbytes, st.stats)
-                _lit_rx(decoder, nbytes)
-            # zero-copy handoff: the decoder owns this slab's memory
-            # from here (its cursors may pin slices of it, a digesting
-            # decoder a blob's until the pack); the tap sees the same
-            # bytes as one read-only view.  The view is OF the received
-            # prefix, so its ``.obj`` says how much a slice of it pins
-            data = memoryview(buf[:nbytes])
-            if tap is not None:
-                # the broadcast tee (FanoutServer.publish): an append +
-                # O(1) mark under the server lock — never blocks
-                # datlint: allow-callback-escape
-                tap(data)
-            wake.clear()
-            try:
-                # frame callbacks, blob join, digest submits (a private
-                # pipeline's digest.* stages nest inside)
-                with span("decode.write", bytes=nbytes, frames=nframes):
-                    ok = decoder.write_indexed(data, st.starts, st.lens,
-                                               st.ids, nframes, consumed)
-            except DecoderDestroyedError:
-                return
-            if not ok:
-                while not (decoder.writable() or decoder.destroyed
-                           or decoder.finished):
-                    wake.wait(WAKE_FALLBACK)
-                    wake.clear()
+            if not nbytes:  # EAGAIN: a receive timeout on this blocking leg
+                raise OSError(errno.EAGAIN, os.strerror(errno.EAGAIN))
+            while not (decoder.writable() or decoder.destroyed
+                       or decoder.finished):
+                wake.wait(WAKE_FALLBACK)
+                wake.clear()
+            ahead.release(pump)
     finally:
+        ahead.close()
         decoder._remove_drain_watcher(wake.set)
+
+
+def _recv_spanned(pump: "EdgePump") -> tuple:
+    # recv_pump's receive, on whichever thread makes it: the stage
+    # pump_busy reads.  A blocking read plus the frame scan: where the
+    # peer keeps the socket full it is the kernel's copy and the scan,
+    # where it does not it is the wait for the peer — the one wait a
+    # stage span may bracket (OBSERVABILITY.md)
+    with span("pump.recv"):
+        return recv_fetch(pump)
+
+
+class _ReadAhead:
+    """:func:`recv_pump`'s receives.  Inline until the connection shows
+    bulk, then on one helper thread (a :class:`RecvFan` of one) that
+    keeps ``READAHEAD`` receives going while the session thread feeds.
+
+    * Depth: a receive starts only from :meth:`next`, which the session
+      thread calls once the decoder is writable — so a stalled decoder
+      has at most ``READAHEAD`` slabs received past it.
+    * One :class:`EdgePump` a slab in flight: its ``_RecvState`` holds
+      that slab's frame index, and ``Decoder._install_index`` keeps
+      views of it while the decoder is parked on the slab's bulk
+      cursor; :meth:`release` takes a pump back only once the decoder
+      has drained past its slab.
+    * Teardown: :meth:`close` wakes a helper blocked in a read with
+      ``shutdown(SHUT_RD)`` — never a close: the caller owns the fd,
+      and a number closed under a blocked reader can be handed to
+      another connection before the read returns — then joins it under
+      ``RecvFan.close``'s bound.  Only a socket engages: a pipe has no
+      read half to shut."""
+
+    __slots__ = ("fd", "cap", "wake", "free", "last", "fan", "inflight")
+
+    def __init__(self, fd: int, cap: int, wake: threading.Event):
+        self.fd = fd
+        self.cap = cap
+        self.wake = wake
+        self.free: list = []  # pumps whose slab the decoder is past
+        self.last: Optional[EdgePump] = None  # the pump fed last
+        self.fan: Optional[RecvFan] = None
+        self.inflight = 0
+
+    def _pump(self) -> EdgePump:
+        return self.free.pop() if self.free else EdgePump(self.fd, self.cap)
+
+    def _start(self) -> None:
+        pump = self._pump()
+        self.fan.start(pump, pump)
+        self.inflight += 1
+
+    def next(self, decoder: Decoder) -> tuple:
+        """``(pump, fetched)`` of the next slab in order —
+        :func:`recv_feed`'s arguments, or what the helper raised —
+        and ``(None, None)`` if the decoder is destroyed meanwhile."""
+        if self.fan is None:
+            last = self.last
+            if last is None or not last.bulk or not _is_socket(self.fd):
+                pump = self._pump()
+                return pump, _recv_spanned(pump)
+            self.fan = RecvFan(1, name="pump-rx", fetch=_recv_spanned,
+                               on_done=self.wake.set)
+        if not self.inflight:
+            self._start()
+        got = self.fan.poll()
+        lit = _OBS.on
+        if got is None:
+            # the session thread's wait for its helper's receive
+            with span("pump.wait"):
+                while got is None:
+                    if decoder.destroyed:
+                        return None, None
+                    self.wake.wait(WAKE_FALLBACK)
+                    self.wake.clear()
+                    got = self.fan.poll()
+        elif lit:
+            _M_RA_READY.inc()
+        if lit:
+            _M_RA_SLABS.inc()
+        self.inflight -= 1
+        pump, fetched = got
+        if (not isinstance(fetched, BaseException) and fetched[1] is not None
+                and fetched[1][0] > 0):
+            while self.inflight < READAHEAD:
+                self._start()
+        return pump, fetched
+
+    def release(self, pump: EdgePump) -> None:
+        """The decoder is writable again after ``pump``'s slab: nothing
+        it holds reads that slab's index any more."""
+        self.last = pump
+        self.free.append(pump)
+
+    def close(self, shut: bool = True) -> None:
+        fan, self.fan = self.fan, None
+        if fan is None:
+            return
+        if self.inflight and shut:
+            _shut_rd(self.fd)
+        fan.close()
+
+
+def _is_socket(fd: int) -> bool:
+    try:
+        return stat.S_ISSOCK(os.fstat(fd).st_mode)
+    except OSError:
+        return False
+
+
+def _shut_rd(fd: int) -> None:
+    """Shut ``fd``'s read half: a read blocked on it returns."""
+    sock = socket.socket(fileno=fd)
+    try:
+        sock.shutdown(socket.SHUT_RD)
+    except OSError:
+        pass  # the peer or the caller got there first
+    finally:
+        sock.detach()
 
 
 def _tapped_reader(fd: int, tap) -> Callable[[int], bytes]:
@@ -487,11 +615,13 @@ class EdgePump:
     the batched-syscall primitives of this module, re-cut as ONE
     bounded non-blocking turn per call instead of a thread-owned loop.
 
-    ``fd`` MUST be non-blocking — the edge loop sets ``O_NONBLOCK``
-    at admission and never clears it; every kernel call below is
-    bounded by that flag (would-block returns immediately), which is
-    what lets :meth:`EdgeLoop._dispatch_loop` inline these sites and
-    still certify ``bounded-blocking``.  The native route degrades
+    On the edge ``fd`` MUST be non-blocking — the edge loop sets
+    ``O_NONBLOCK`` at admission and never clears it; every kernel call
+    below is bounded by that flag (would-block returns immediately),
+    which is what lets :meth:`EdgeLoop._dispatch_loop` inline these
+    sites and still certify ``bounded-blocking``.  :func:`recv_pump`
+    holds one over its blocking fd for each slab in flight and uses
+    the two receive halves alone.  The native route degrades
     per-call to plain ``os.read``/``os.write`` exactly like the
     thread pumps (the route that runs is always a route that
     exists)."""
@@ -603,28 +733,38 @@ def recv_feed(pump: EdgePump, decoder: Decoder, fetched: tuple,
 
 
 # RecvFan.close's bound on each helper's exit: a helper is at most one
-# non-blocking receive away from its sentinel
+# non-blocking receive away from its sentinel (recv_pump's, one read on
+# a socket whose read half _ReadAhead.close has just shut)
 _FAN_JOIN_TIMEOUT = 5.0
 
 
 class RecvFan:
-    """A few helper threads that run :func:`recv_fetch` for the edge
-    loop, so that one turn's bulk sessions are received side by side:
-    the kernel's socket copies and the frame scan need no interpreter,
+    """A few helper threads that run :func:`recv_fetch` (or ``fetch``)
+    for the edge loop, so that one turn's bulk sessions are received
+    side by side, and one for :func:`recv_pump`'s read-ahead: the
+    kernel's socket copies and the frame scan need no interpreter,
     only a core each.  A helper touches what ``recv_fetch`` touches —
     a descriptor, a slab, the session's ``_RecvState`` — and hands the
     result back untouched; whatever it raised is handed back too and
-    raised by the caller.  Every descriptor is ``O_NONBLOCK`` (the
-    :class:`EdgePump` contract), so a started receive returns without
-    sleeping and :meth:`wait_one` is bounded by construction."""
+    raised by the caller.  On the edge every descriptor is
+    ``O_NONBLOCK`` (the :class:`EdgePump` contract), so a started
+    receive returns without sleeping and :meth:`wait_one` is bounded
+    by construction.  ``recv_pump``'s descriptor blocks: it waits
+    through :meth:`poll` and its own wake event, which ``on_done``
+    (run on the helper after each hand-back) and its decoder's destroy
+    both set."""
 
-    __slots__ = ("_jobs", "_done", "_threads")
+    __slots__ = ("_jobs", "_done", "_threads", "_fetch", "_on_done")
 
-    def __init__(self, helpers: int):
+    def __init__(self, helpers: int, name: str = "edge-rx",
+                 fetch: Optional[Callable] = None,
+                 on_done: Optional[Callable[[], None]] = None):
         self._jobs: queue.SimpleQueue = queue.SimpleQueue()
         self._done: queue.SimpleQueue = queue.SimpleQueue()
+        self._fetch = fetch
+        self._on_done = on_done
         self._threads = [
-            threading.Thread(target=self._run, name=f"edge-rx-{i}",
+            threading.Thread(target=self._run, name=f"{name}-{i}",
                              daemon=True) for i in range(helpers)]
         for t in self._threads:
             t.start()
@@ -636,10 +776,14 @@ class RecvFan:
                 return
             token, pump = job
             try:
-                fetched = recv_fetch(pump)
+                fetched = (self._fetch or recv_fetch)(pump)
             except BaseException as e:  # the caller's to raise
                 fetched = e
             self._done.put((token, fetched))
+            if self._on_done is not None:
+                # a wake hook: recv_pump's Event.set, never blocks
+                # datlint: allow-callback-escape
+                self._on_done()
 
     def start(self, token, pump: EdgePump) -> None:
         """Begin one receive on ``pump``; ``token`` comes back with it."""
@@ -650,6 +794,13 @@ class RecvFan:
         ``fetched`` is :func:`recv_feed`'s argument, or the exception
         the helper's call raised."""
         return self._done.get()
+
+    def poll(self) -> Optional[tuple]:
+        """:meth:`wait_one`'s result if one is in, else ``None``."""
+        try:
+            return self._done.get(False)
+        except queue.Empty:
+            return None
 
     def close(self) -> None:
         """End the helpers once their started receives are through."""

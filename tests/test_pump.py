@@ -612,3 +612,490 @@ def test_recv_fan_stress_keeps_every_stream_whole(monkeypatch):
         for a, b in pairs:
             a.close()
             b.close()
+
+
+# -- the thread-per-connection read-ahead (ISSUE 39) --------------------------
+#
+# Slices and slabs are shrunk so that a socketpair fills whole slices and
+# the helper engages within a few receives; the rules are the same at any
+# size.  RA_SLICE / RA_CAP: what a "full receive" is, and a slab.
+
+RA_SLICE = 16 << 10
+RA_CAP = 64 << 10
+
+
+def _h(data) -> bytes:
+    import hashlib
+
+    return hashlib.blake2b(bytes(data), digest_size=32).digest()
+
+
+def _big_blob(i: int) -> bytes:
+    return bytes((b + 37 * i) & 0xFF for b in range(256)) * 4096  # 1 MiB
+
+
+def _ra_wire(traffic: str) -> tuple:
+    """``(wire, expected deliveries)``: ``("c", key)`` a change,
+    ``("b", digest)`` a blob."""
+    if traffic == "blobs":
+        blobs = [_big_blob(i) for i in range(5)]
+        return (b"".join(frame(TYPE_BLOB, b) for b in blobs),
+                [("b", _h(b)) for b in blobs])
+    if traffic == "changes":
+        return (_changes_wire(0, 3000),
+                [("c", f"k{i}") for i in range(3000)])
+    wire, blobs, n = _mixed_wire()
+    big = _big_blob(9)
+    want = ([("c", f"k{i}") for i in range(40)]
+            + [("b", _h(b)) for b in blobs]
+            + [("c", f"k{i}") for i in range(40, n)] + [("b", _h(big))])
+    return wire + frame(TYPE_BLOB, big), want
+
+
+def _rows_wire(lo: int, hi: int) -> bytes:
+    """Change rows of one size (~100 bytes on the wire)."""
+    enc = protocol.encode()
+    for i in range(lo, hi):
+        enc.change({"key": f"k{i}", "change": i, "from": 0, "to": 1,
+                    "value": b"r" * 80})
+    return bytes(enc.read(1 << 30))
+
+
+def _ra_decoder(got: list) -> Decoder:
+    dec = Decoder()
+    dec.change(lambda ch, done: (got.append(("c", ch.key)), done()))
+    dec.blob(lambda blob, done: blob.collect(
+        lambda data: (got.append(("b", _h(data))), done())))
+    return dec
+
+
+def _ra_setup(monkeypatch, depth: int) -> list:
+    """Pin the native route, shrink the slice and set the depth (0: the
+    slices never fill, so the connection stays inline); returns the
+    list every helper start is recorded in."""
+    monkeypatch.setenv("DAT_PUMP", "native")
+    monkeypatch.setattr(pump, "PUMP_SLICE", RA_SLICE if depth else RA_CAP * 2)
+    monkeypatch.setattr(pump, "READAHEAD", max(depth, 1))
+    made = []
+    real = pump.RecvFan
+
+    def fan(*a, **k):
+        made.append(k.get("name"))
+        return real(*a, **k)
+
+    monkeypatch.setattr(pump, "RecvFan", fan)
+    return made
+
+
+def _send_quietly(sock: socket.socket, data: bytes) -> None:
+    try:
+        sock.sendall(data)
+    except OSError:
+        pass  # the test closed the pair under a blocked send
+
+
+def _helpers_alive() -> list:
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("pump-rx")]
+
+
+@pytest.mark.parametrize("depth", [0, 1, 2], ids=["inline", "d1", "d2"])
+@pytest.mark.parametrize("traffic", ["blobs", "changes", "mix"])
+def test_read_ahead_delivers_what_the_inline_pump_does(monkeypatch, traffic,
+                                                       depth):
+    """The same frames in the same order, the same digests and the
+    same tap bytes, whether the receives run in a row with the feeds or
+    on the helper up to ``depth`` slabs ahead of them — 1 MiB blobs
+    across many slabs, change rows, and the two mixed."""
+    made = _ra_setup(monkeypatch, depth)
+    wire, want = _ra_wire(traffic)
+    a, b = socket.socketpair()
+    tapped: list = []
+    got: list = []
+    try:
+        dec = _ra_decoder(got)
+        sender = threading.Thread(
+            target=lambda: (a.sendall(wire), a.shutdown(socket.SHUT_WR)),
+            daemon=True)
+        sender.start()
+        pump.recv_pump(dec, b.fileno(), tap=lambda v: tapped.append(bytes(v)),
+                       cap=RA_CAP)
+        sender.join(10)
+        assert dec.finished and dec.bytes == len(wire)
+    finally:
+        a.close()
+        b.close()
+    assert got == want
+    assert b"".join(tapped) == wire
+    assert made == (["pump-rx"] if depth else [])
+    assert not _helpers_alive()
+
+
+def test_read_ahead_counts_its_slabs_and_waits_lit(monkeypatch, obs_enabled):
+    """Lit: every slab taken from the helper is counted, the ones that
+    were in already as `ready`; the session thread's waits for it and
+    the helper's receives are stage spans of their own."""
+    _ra_setup(monkeypatch, 1)
+    wire, want = _ra_wire("blobs")
+    a, b = socket.socketpair()
+    got: list = []
+    try:
+        dec = _ra_decoder(got)
+        threading.Thread(target=lambda: (a.sendall(wire),
+                                         a.shutdown(socket.SHUT_WR)),
+                         daemon=True).start()
+        pump.recv_pump(dec, b.fileno(), cap=RA_CAP)
+    finally:
+        a.close()
+        b.close()
+    assert got == want
+    snap = obs_metrics.snapshot()
+    c, h = snap["counters"], snap["histograms"]
+    slabs = c["pump.readahead.slabs"]
+    assert slabs > 0 and 0 <= c["pump.readahead.ready"] <= slabs
+    # every slab was received under pump.recv, inline or on the helper
+    # (the EOF's receive too), and every one was fed but the EOF
+    assert h["span.pump.recv.seconds"]["count"] >= slabs + 1
+    assert h["span.decode.write.seconds"]["count"] == \
+        c["transport.pump.batches"]
+    assert h["span.pump.wait.seconds"]["count"] <= slabs
+
+
+def _counted_halves(monkeypatch) -> tuple:
+    """Record the pump of every receive that brought bytes, and of
+    every feed, in order."""
+    fetched, fed = [], []
+    real_fetch, real_feed = pump.recv_fetch, pump.recv_feed
+
+    def fetch(p):
+        out = real_fetch(p)
+        if out[1] is not None and out[1][0] > 0:
+            fetched.append(p)
+        return out
+
+    def feed(p, dec, got, tap=None):
+        fed.append(p)
+        return real_feed(p, dec, got, tap)
+
+    monkeypatch.setattr(pump, "recv_fetch", fetch)
+    monkeypatch.setattr(pump, "recv_feed", feed)
+    return fetched, fed
+
+
+def _holding_decoder(hold_key: str) -> tuple:
+    """A decoder whose change handler never acks ``hold_key`` until the
+    test calls the held ``done``."""
+    held, keys = [], []
+
+    def on_change(ch, done):
+        keys.append(ch.key)
+        if ch.key == hold_key and not held:
+            held.append(done)
+        else:
+            done()
+
+    dec = Decoder()
+    dec.change(on_change)
+    return dec, held, keys
+
+
+def _settled(read, quiet: float = 0.3, timeout: float = 10.0):
+    """``read()`` once it has returned the same value for ``quiet``
+    seconds (or at ``timeout``)."""
+    last, since = read(), time.monotonic()
+    deadline = since + timeout
+    while time.monotonic() < deadline:
+        time.sleep(0.02)
+        now = read()
+        if now != last:
+            last, since = now, time.monotonic()
+        elif time.monotonic() - since >= quiet:
+            break
+    return last
+
+
+def _wait_for(pred, timeout: float = 10.0) -> bool:
+    deadline = time.monotonic() + timeout
+    while not pred() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return pred()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_read_ahead_stops_at_its_depth_past_a_stalled_decoder(monkeypatch,
+                                                              depth):
+    """A handler that never acks stalls the decoder: the helper then
+    starts no receive, so at most ``depth`` slabs are received past
+    the one that stalled (``depth`` x the slab size of socket the
+    pump takes beyond an inline pump's).  On the ack the stream
+    resumes whole."""
+    _ra_setup(monkeypatch, depth)
+    fetched, fed = _counted_halves(monkeypatch)
+    wire = _rows_wire(0, 6000)
+    dec, held, keys = _holding_decoder("k4000")
+    a, b = socket.socketpair()
+    t = threading.Thread(target=pump.recv_pump,
+                         args=(dec, b.fileno()), kwargs={"cap": RA_CAP},
+                         daemon=True)
+    threading.Thread(
+        target=lambda: (a.sendall(wire), a.shutdown(socket.SHUT_WR)),
+        daemon=True).start()
+    t.start()
+    try:
+        assert _wait_for(lambda: held), "the stall never came"
+        # what the helper may still receive, it has once the two
+        # counts hold still
+        stalled_at, got = _settled(lambda: (len(fed), len(fetched)))
+        assert got == stalled_at + depth
+    finally:
+        if held:
+            held[0]()
+        t.join(10)
+        a.close()
+        b.close()
+    assert not t.is_alive()
+    assert dec.finished and keys == [f"k{i}" for i in range(6000)]
+    assert not _helpers_alive()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_slab_read_ahead_leaves_the_parked_index_alone(monkeypatch, depth):
+    """Rule 3: the decoder parks on a slab's bulk cursor, whose index
+    arrays are views of that slab's ``_RecvState``; the slabs the helper
+    receives meanwhile land in index arrays of their own, so the parked
+    index reads the same before and after, and shares no memory with
+    them.  The peer sends whole frames a slab at a time, so that every
+    slab installs its own index (``Decoder.write_indexed``)."""
+    _ra_setup(monkeypatch, depth)
+    fetched, fed = _counted_halves(monkeypatch)
+    chunks = [_rows_wire(400 * k, 400 * k + 400) for k in range(8)]
+    assert all(RA_SLICE <= len(c) <= RA_CAP for c in chunks)
+    dec, held, keys = _holding_decoder("k1400")  # mid-chunk 3
+    a, b = socket.socketpair()
+
+    def paced():
+        for k, c in enumerate(chunks):
+            a.sendall(c)
+            if not _wait_for(lambda: len(fetched) > k, 5.0):
+                _wait_for(lambda: not held or len(fetched) > k, 20.0)
+        a.shutdown(socket.SHUT_WR)
+
+    t = threading.Thread(target=pump.recv_pump,
+                         args=(dec, b.fileno()), kwargs={"cap": RA_CAP},
+                         daemon=True)
+    threading.Thread(target=paced, daemon=True).start()
+    t.start()
+    try:
+        assert _wait_for(lambda: held), "the stall never came"
+        bulk = dec._bulk
+        assert bulk is not None and fed[-1] is fetched[3]
+        # the hazard is real: the parked index IS the slab's state
+        assert np.shares_memory(bulk["starts_np"], fed[-1].recv_st.starts)
+        index = {k: bulk[k].copy() for k in ("starts_np", "lens_np",
+                                              "ids_np")}
+        assert _wait_for(lambda: len(fetched) == 4 + depth)
+        ahead = fetched[4:]
+        time.sleep(0.2)
+        assert dec._bulk is bulk and len(fed) == 4
+        for k, was in index.items():
+            assert np.array_equal(bulk[k], was)
+        for p in ahead:
+            assert not np.shares_memory(p.recv_st.starts, bulk["starts_np"])
+    finally:
+        if held:
+            held[0]()
+        t.join(10)
+        a.close()
+        b.close()
+    assert not t.is_alive()
+    assert dec.finished and keys == [f"k{i}" for i in range(3200)]
+
+
+@pytest.mark.parametrize("fault", ["destroy", "shed", "transport-error"])
+def test_read_ahead_teardown_joins_the_helper_and_keeps_the_fd(monkeypatch,
+                                                               fault):
+    """The three ways out of a connection that is not its EOF: the
+    decoder destroyed from another thread while the helper sits in a
+    receive on a silent peer, a SessionShed raised out of a feed, an
+    errno from the helper's receive.  recv_pump returns (or raises the
+    error, in slab order) within a bound, with no helper left behind
+    and the descriptor still open: the close is the caller's."""
+    from dat_replication_protocol_tpu.hub import SessionShed
+
+    _ra_setup(monkeypatch, 1)
+    blobs = [bytes([i]) * 100_000 for i in range(12)]
+    wire = b"".join(frame(TYPE_BLOB, x) for x in blobs)
+    fetched = []
+    real_fetch = pump.recv_fetch
+
+    def fetch(p):
+        out = real_fetch(p)
+        if (fault == "transport-error" and len(fetched) == 6
+                and threading.current_thread().name.startswith("pump-rx")):
+            out = (out[0], (-104, 0, 0, 0), out[2])  # ECONNRESET
+        fetched.append(out[1][0])
+        return out
+
+    monkeypatch.setattr(pump, "recv_fetch", fetch)
+    got = []
+
+    def on_blob(blob, done):
+        if fault == "shed" and len(got) == 5:
+            raise SessionShed("s", "parked-budget", 1)
+        blob.collect(lambda data: (got.append(len(data)), done()))
+
+    a, b = socket.socketpair()
+    dec = Decoder()
+    dec.blob(on_blob)
+    out = {}
+
+    def run():
+        try:
+            pump.recv_pump(dec, b.fileno(), cap=RA_CAP)
+        except BaseException as e:
+            out["err"] = e
+        out["t"] = time.monotonic()
+
+    t = threading.Thread(target=run, daemon=True)
+    try:
+        t.start()
+        # the peer sends half and then stays open and silent
+        threading.Thread(target=_send_quietly, args=(a, wire[:len(wire) // 2]),
+                         daemon=True).start()
+        if fault == "destroy":
+            deadline = time.monotonic() + 10
+            while dec.bytes < len(wire) // 2 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            time.sleep(0.2)  # the helper's next receive is blocked
+            assert _helpers_alive()
+            t_destroy = time.monotonic()
+            dec.destroy()
+        else:
+            t_destroy = time.monotonic()
+        t.join(5)
+        assert not t.is_alive(), "recv_pump did not return"
+        assert out["t"] - t_destroy < 3.0
+        assert not _helpers_alive()
+        os.fstat(b.fileno())  # still ours to close
+        b.getsockname()
+        if fault == "destroy":
+            assert "err" not in out and dec.destroyed
+        elif fault == "shed":
+            assert isinstance(out["err"], SessionShed) and len(got) == 5
+        else:
+            assert isinstance(out["err"], OSError)
+            assert out["err"].errno == 104
+            # every slab received before the faulty one was fed first
+            assert dec.bytes == sum(fetched[:6])
+    finally:
+        a.close()
+        b.close()
+
+
+def test_read_ahead_ends_the_decoder_after_its_last_slab(monkeypatch):
+    """EOF: ``decoder.end()`` (and the finalize hook behind it) runs
+    once the last received slab has been fed, never before — every
+    byte of the wire counted and every frame delivered when it fires."""
+    made = _ra_setup(monkeypatch, 2)
+    wire, want = _ra_wire("mix")
+    got: list = []
+    seen = {}
+
+    class Dec(Decoder):
+        def end(self, on_finished=None):
+            seen["bytes"] = self.bytes
+            return super().end(on_finished)
+
+    dec = Dec()
+    dec.change(lambda ch, done: (got.append(("c", ch.key)), done()))
+    dec.blob(lambda blob, done: blob.collect(
+        lambda data: (got.append(("b", _h(data))), done())))
+    dec.finalize(lambda done: (seen.__setitem__("delivered", len(got)),
+                               done()))
+    a, b = socket.socketpair()
+    try:
+        threading.Thread(target=lambda: (a.sendall(wire),
+                                         a.shutdown(socket.SHUT_WR)),
+                         daemon=True).start()
+        pump.recv_pump(dec, b.fileno(), cap=RA_CAP)
+    finally:
+        a.close()
+        b.close()
+    assert made == ["pump-rx"]
+    assert dec.finished and seen == {"bytes": len(wire),
+                                     "delivered": len(want)}
+    assert got == want
+
+
+def test_a_connection_of_short_reads_never_starts_a_helper(monkeypatch):
+    """Adapting, not a knob: a peer that sends a little at a time never
+    fills a receive slice, so its connection stays inline — no helper
+    thread is ever started for it."""
+    monkeypatch.setenv("DAT_PUMP", "native")
+    made = []
+    real = pump.RecvFan
+    monkeypatch.setattr(pump, "RecvFan",
+                        lambda *a, **k: (made.append(1), real(*a, **k))[1])
+    wire = _changes_wire(0, 400)
+    a, b = socket.socketpair()
+    got: list = []
+    try:
+        dec = _ra_decoder(got)
+
+        def trickle():
+            for i in range(0, len(wire), 4096):
+                a.sendall(wire[i:i + 4096])
+                time.sleep(0.002)
+            a.shutdown(socket.SHUT_WR)
+
+        threading.Thread(target=trickle, daemon=True).start()
+        pump.recv_pump(dec, b.fileno())
+    finally:
+        a.close()
+        b.close()
+    assert got == [("c", f"k{i}") for i in range(400)]
+    assert made == []
+
+
+def test_read_ahead_stress_keeps_every_connection_whole(monkeypatch):
+    """Twelve connections at once, each on its own session thread with
+    its own helper (more threads than cores), under a switch interval
+    short enough to interleave every hand-off: each stream's blobs come
+    through whole and in order, and no helper outlives its pump."""
+    import sys
+
+    made = _ra_setup(monkeypatch, 2)
+    n = 12
+    wires, wants = [], []
+    for i in range(n):
+        blobs = [bytes([i, j]) * (9_000 + 997 * ((i + j) % 7))
+                 for j in range(30)]
+        wants.append([("b", _h(b)) for b in blobs])
+        wires.append(b"".join(frame(TYPE_BLOB, b) for b in blobs))
+    pairs = [socket.socketpair() for _ in range(n)]
+    gots = [[] for _ in range(n)]
+    decs = [_ra_decoder(g) for g in gots]
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(
+            target=pump.recv_pump, args=(dec, b.fileno()),
+            kwargs={"cap": RA_CAP}, daemon=True)
+            for dec, (_a, b) in zip(decs, pairs)]
+        threads += [threading.Thread(
+            target=lambda a=a, w=w: (a.sendall(w), a.shutdown(socket.SHUT_WR)),
+            daemon=True) for (a, _b), w in zip(pairs, wires)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(was)
+        for a, b in pairs:
+            a.close()
+            b.close()
+    for i in range(n):
+        assert decs[i].finished and gots[i] == wants[i], f"stream {i}"
+    assert made == ["pump-rx"] * n
+    assert not _helpers_alive()

@@ -15,6 +15,8 @@ import sys
 import threading
 import time
 
+import pytest
+
 import dat_replication_protocol_tpu as protocol
 from dat_replication_protocol_tpu import sidecar
 
@@ -1337,3 +1339,61 @@ def test_snapshot_stats_propagation_section_is_presence_gated():
     finally:
         sidecar.set_active_gossip(None)
         PROPAGATION.reset_for_tests()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_tcp_sidecar_reads_a_bulk_session_ahead(monkeypatch, depth):
+    """ISSUE 39: a `--tcp` connection streaming 1 MiB blobs fills its
+    receives, so its session thread feeds while a helper receives up to
+    `depth` slabs ahead — and the reply is the same: every blob's digest
+    equal to hashlib's, in order, and (the fan-out tap, fed on the
+    session thread in slab order) a subscriber's copy of the wire equal
+    to the wire, byte for byte."""
+    from dat_replication_protocol_tpu.fanout import FanoutServer
+    from dat_replication_protocol_tpu.session import pump
+    from dat_replication_protocol_tpu.wire.framing import TYPE_BLOB, frame
+
+    monkeypatch.setenv("DAT_PUMP", "native")
+    if pump.effective_pump_route() != "native":
+        pytest.skip("no native pump library on this machine")
+    monkeypatch.setattr(pump, "PUMP_SLICE", 64 << 10)
+    monkeypatch.setattr(pump, "READAHEAD", depth)
+    made = []
+    real_fan = pump.RecvFan
+    monkeypatch.setattr(pump, "RecvFan", lambda *a, **k: (
+        made.append(k.get("name")), real_fan(*a, **k))[1])
+    blobs = [bytes((b + 29 * i) & 0xFF for b in range(256)) * 4096
+             for i in range(12)]
+    wire = b"".join(frame(TYPE_BLOB, b) for b in blobs)
+    fanout = FanoutServer(stall_timeout=20.0)
+    ready = threading.Event()
+    port_box = {}
+    t = threading.Thread(
+        target=sidecar.serve_tcp, args=("127.0.0.1", 0),
+        kwargs=dict(max_sessions=2, fanout=fanout,
+                    ready_cb=lambda p: (port_box.__setitem__("p", p),
+                                        ready.set())),
+        daemon=True)
+    t.start()
+    try:
+        assert ready.wait(10)
+        addr = ("127.0.0.1", port_box["p"])
+        src = socket.create_connection(addr, timeout=30)
+        sub = socket.create_connection(addr, timeout=30)
+        sender = threading.Thread(
+            target=lambda: (src.sendall(wire), src.shutdown(socket.SHUT_WR)),
+            daemon=True)
+        sender.start()
+        reply = _decode_reply(_recv_all(src))
+        sender.join(10)
+        src.close()
+        got = _recv_all(sub)
+        sub.close()
+        t.join(timeout=10)
+    finally:
+        fanout.close()
+    assert [ch.key for ch in reply] == [f"blob-{i}" for i in range(12)]
+    assert [ch.value for ch in reply] == [
+        hashlib.blake2b(b, digest_size=32).digest() for b in blobs]
+    assert got == wire
+    assert made == ["pump-rx"]

@@ -387,6 +387,8 @@ SITES = {
     DigestPipeline.dispatch: set(),
     DigestPipeline._deliver_oldest: {"_monotonic", "_H_RESIDENCE"},
     pump_mod.recv_pump: {"_perf", "_H_NATIVE"},
+    pump_mod._recv_spanned: set(),
+    pump_mod._ReadAhead.next: set(),
 }
 CLOCKS = {"monotonic", "_monotonic", "perf_counter", "_perf", "time",
           "_histogram"}
@@ -410,6 +412,11 @@ def _gated_on_obs(fn, target: str) -> bool:
         if ins.argval == target and not seen_gate:
             return False
     return True
+
+
+def test_read_ahead_counters_sit_behind_the_gate():
+    for lit_only in ("_M_RA_READY", "_M_RA_SLABS"):
+        assert _gated_on_obs(pump_mod._ReadAhead.next, lit_only)
 
 
 def test_pipeline_clock_reads_sit_behind_the_gate():
