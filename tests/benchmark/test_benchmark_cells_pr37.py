@@ -9,16 +9,10 @@ clock on one visit in a few, so a share compares the two MEANS); nothing
 absent, as in the PR's parent; nothing where the wall sum did not move
 or the CPU clock has too few observations; nothing on a dark run.
 
-The seven entries are NOT in `BENCHMARK.json`.  A PR that changes the
-program may only put entries at the END of a list (the driver read the
-seven, inserted in front of the last entry, as a change to
-`rx_fanned_share` and refused them), and `test_benchmark_cells_pr36.py::
-test_what_this_pr_added_to_the_manifest` holds `m["per_layer"][-1]` to
-be `rx_fanned_share`, in an accepted file that may not be edited: no
-place is left.  The readers are here, tested, for the `benchmark` PR
-that rewrites that pin (PERF.md section 7); the manifest case below
-rehearses its entries in a copy, finds them BY NAME and pins no
-position."""
+The seven entries are in `BENCHMARK.json`, appended to `per_layer`.  The
+manifest case below finds them BY NAME, holds each one's list of cells
+as a prefix and pins no position, so that it holds on a manifest grown
+as a later PR grows it (`test_benchmark_cells_pr40.py`)."""
 
 import json
 import os
@@ -39,24 +33,22 @@ CELLS = ["plain.publish", "edgehub.feed", "edgehub.publish",
 
 
 ONE_CHIP = ["plain.publish", "edgehub.feed", "edgehub.publish"]
-HUB = ["edgehub.feed", "edgehub.publish"]
+EDGE = ["edgehub.feed", "edgehub.publish", "meshhub.publish"]
 # name -> (unit, layer, the cells it lists; None: no list, every cell).
 # A list where a reader finds nothing in some cell by design: the mesh
 # arm has no slice and no lengths transfer, and `plain.publish` has no
-# edge loop, no `recv_fetch`, and 4-5 clocked batches a window (under
-# `_shares.MIN_CLOCKED`).  The four shares DO read on `meshhub.publish`,
-# and cannot list it: `test_benchmark_cells_pr33.py` holds
-# `edgehub.publish` last in every list it is in and
-# `test_benchmark_cells_pr35.py` holds the accepted cells first, so no
-# list can hold both names (PERF.md section 7)
+# edge loop and 4-5 clocked batches a window (under
+# `_shares.MIN_CLOCKED`).  The shares read on every edge cell, the
+# four-chip one among them; the receive's also on `plain.publish`, whose
+# read-ahead helper receives through the same `recv_fetch`
 WANT = {
     "launch_program_ms": ("ms", "kernels", None),
     "launch_slice_ms": ("ms", "kernels", ONE_CHIP),
     "launch_lengths_ms": ("ms", "kernels", ONE_CHIP),
-    "launch_offcpu_share": ("%", "kernels", HUB),
-    "pack_offcpu_share": ("%", "staging", HUB),
-    "edge_read_offcpu_share": ("%", "host path", HUB),
-    "rx_offcpu_share": ("%", "host path", HUB),
+    "launch_offcpu_share": ("%", "kernels", EDGE),
+    "pack_offcpu_share": ("%", "staging", EDGE),
+    "edge_read_offcpu_share": ("%", "host path", EDGE),
+    "rx_offcpu_share": ("%", "host path", CELLS),
 }
 NAMES = list(WANT)
 
@@ -158,8 +150,10 @@ DARK = {"snaps": None}
     # a thread that never left its CPU reads 0, and that IS a reading
     ("pack_offcpu_share",
      _pair(*PACK, (0.5, 0.5, 100), (10.5, 10.5, 2400)), 0.0),
-    # the wall sum did not move (plain.publish: the receives' pair is
-    # registered at import and never fed): nothing, not 0
+    # the wall sum did not move (a path that makes no native receive
+    # through `recv_fetch`, as `plain.publish` before its read-ahead
+    # helper: the pair is registered at import and never fed): nothing,
+    # not 0
     ("rx_offcpu_share",
      _pair(*FETCH, (0.0, 0.0, 0), (0.0, 0.0, 0)), None),
     ("edge_read_offcpu_share",
@@ -201,26 +195,29 @@ def entry(name: str) -> dict:
     return e
 
 
-def test_the_seven_entries_by_name_in_a_copy_of_the_manifest():
-    """The entries a `benchmark` PR appends once PR 36's `[-1]` case is
-    rewritten, rehearsed in a copy as `test_benchmark_cells_pr33.py`
-    rehearses `plain.feed`: whichever of the seven the manifest lacks is
-    appended to the copy.  Found BY NAME, never by position: a second
-    pin on the list's order would bar the next PR as `[-1]` barred this
-    one.  Each entry has the contract's form, names a layer and an
-    end-to-end metric the manifest has, and every cell resolves exactly
-    the readers that list it."""
-    m = manifest()
-    have = {p["name"] for p in m["per_layer"]}
-    layers = {p["layer"] for p in m["per_layer"] if p["name"] not in WANT}
-    m["per_layer"] = m["per_layer"] + [entry(n) for n in NAMES
-                                       if n not in have]
+def test_the_seven_entries_by_name_in_the_manifest():
+    check_the_seven_entries(manifest())
+
+
+def check_the_seven_entries(m: dict) -> None:
+    """Each of the seven once, found BY NAME, never by position: a pin
+    on the list's order would bar the next PR, as a pin on its last
+    entry once barred these.  Each has the contract's form, its list of
+    cells begins with the cells it was entered with, it names a layer
+    and an end-to-end metric the manifest has, and every accepted cell
+    resolves exactly the readers that list it."""
     names = [p["name"] for p in m["per_layer"]]
     assert len(set(names)) == len(names)
+    assert set(NAMES) <= set(names)
     by_name = {p["name"]: p for p in m["per_layer"]}
+    layers = {p["layer"] for p in m["per_layer"] if p["name"] not in WANT}
     end_to_end = {e["name"] for e in m["end_to_end"]}
-    for name, (_, layer, _) in WANT.items():
-        assert by_name[name] == entry(name)
+    for name, (_, layer, cells) in WANT.items():
+        got = dict(by_name[name])
+        want = entry(name)
+        if cells is not None:
+            assert got.pop("workloads")[:len(cells)] == want.pop("workloads")
+        assert got == want
         assert layer in layers and "payload_rate" in end_to_end
     for cell in CELLS:
         plan = run.resolve(m, cell, dry=False)
@@ -229,4 +226,3 @@ def test_the_seven_entries_by_name_in_a_copy_of_the_manifest():
                   if cells is None or cell in cells]
         assert [n for n in got if n in WANT] == listed, cell
         assert all(callable(r) for _, r in plan["per_layer"])
-    assert len(json.dumps(m, indent=1)) < 64 << 10
