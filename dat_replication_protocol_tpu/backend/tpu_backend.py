@@ -676,7 +676,10 @@ class TpuDecoder(Decoder):
         # of the blob (round-2 verdict weak #5)
         seq = self._blob_seq - 1
         if seq in self._blob_streams:
-            self._blob_streams[seq].update(data)
+            # an over-threshold blob is hashed here, on the decoding
+            # thread, one piece a slab
+            with span("digest.stream"):
+                self._blob_streams[seq].update(data)
         elif seq in self._blob_parts:
             self._blob_parts[seq].append(data)
 

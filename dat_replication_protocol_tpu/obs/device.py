@@ -372,10 +372,11 @@ def reset_engine_notes() -> None:
 
 class BucketTable:
     """Process-global traffic of the blake2b batch edge per (engine,
-    block-count bucket): dispatches, real items, and ``padded_items`` —
+    block-count bucket): dispatches, real items, ``padded_items`` —
     the DECLARED row count each dispatch was staged at
     (``ops.blake2b.batch_rows``: one per slot width up to 4 KiB, a few
-    doubling ones for wider slots).  The engine is the backend's
+    doubling ones for wider slots) — and ``payload_bytes``, the bytes
+    of the items the kernel was handed.  The engine is the backend's
     (``pallas`` on a TPU, ``xla-scan`` elsewhere), so this table says
     which kernel a traffic mix reached and what its padding cost —
     bounded cardinality (two engines x power-of-two block counts).
@@ -386,21 +387,22 @@ class BucketTable:
         self._rows: dict[tuple[str, int], list[int]] = {}
 
     def note(self, engine: str, nblocks: int, items: int,
-             padded_items: int) -> None:
+             padded_items: int, payload_bytes: int) -> None:
         with self._lock:
-            row = self._rows.setdefault((engine, nblocks), [0, 0, 0])
+            row = self._rows.setdefault((engine, nblocks), [0, 0, 0, 0])
             row[0] += 1
             row[1] += items
             row[2] += padded_items
+            row[3] += payload_bytes
 
     def snapshot(self) -> dict:
         """``{"<engine>:<nblocks>": {"dispatches", "items",
-        "padded_items"}}`` (JSON-able)."""
+        "padded_items", "payload_bytes"}}`` (JSON-able)."""
         with self._lock:
             rows = sorted(self._rows.items())
         return {f"{engine}:{nb}": {"dispatches": d, "items": i,
-                                   "padded_items": p}
-                for (engine, nb), (d, i, p) in rows}
+                                   "padded_items": p, "payload_bytes": b}
+                for (engine, nb), (d, i, p, b) in rows}
 
     def reset_for_tests(self) -> None:
         with self._lock:

@@ -786,10 +786,13 @@ def blake2b_batch_begin(
 
     A bucket is staged as ONE array of raw little-endian u32 message
     words (:func:`stage_payloads`: one copy per item, no host-side
-    de-interleave) and one ``device_put``; the hi/lo word split happens
-    on the device, inside the bucket's one program
-    (:func:`split_words`).  Staging buffers come from a small
-    process-wide pool (:class:`_StagePool`).
+    de-interleave) and ONE ``device_put`` of the words and their
+    lengths; the hi/lo word split happens on the device, inside the
+    bucket's one program (:func:`split_words`), whose jit call is the
+    launch's only jax call.  Staging buffers come from a small
+    process-wide pool (:class:`_StagePool`).  A bucket's digests come
+    back whole and the items' rows are picked on the host, so no
+    program is built per item count.
 
     ``mesh`` (a 1-D :class:`jax.sharding.Mesh`; None: one device) lays
     every bucket over the mesh's chips — the same staging, the same
@@ -798,18 +801,22 @@ def blake2b_batch_begin(
     are dealt round the chips (:func:`dealt_rows`: every chip hashes
     payload, whatever the batch), the one ``device_put`` carries a
     batch-dim ``NamedSharding``, and each chip runs the words program on
-    its shard with no exchange (:func:`_sharded_words_program`).  The
-    digests come back whole and the items' rows are picked on the host.
+    its shard with no exchange (:func:`_sharded_words_program`).
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     donate = donation_supported()
-    # the two layouts differ in four places, gathered here: the rows a
-    # bucket is staged at, how the staging is shipped, how the program
-    # is called, and which of its rows are the items' (``at``)
+    # the two layouts differ in three places, gathered here: the rows a
+    # bucket is staged at (``n_dev``), where the one ``device_put``
+    # sends the rows and their lengths (``by_rows``), and which program
+    # it runs; which rows are the items' (``dealt_rows``) follows from
+    # the first.  Both fetch a bucket's digests whole and pick the
+    # items' rows in collect(): a slice on the device is a program per
+    # item count
     if mesh is None:
         n_dev = 1
         over: dict = {}  # what the spans say beside items and nblocks
+        by_rows = None   # the default device
         if use_pallas:
             if donate:
                 from .blake2b_pallas import (
@@ -818,42 +825,15 @@ def blake2b_batch_begin(
                 from .blake2b_pallas import blake2b_words_pallas as words_fn
         else:
             words_fn = blake2b_words_donated if donate else blake2b_words
-
-        def put(words, lengths):
-            return jax.device_put(words), lengths
-
-        def run(words_d, lengths, n):
-            # the launch's three parts, each a child span: a second
-            # host-to-device transfer, the jit call, and two slice
-            # programs (a jax op each, a program per item count)
-            with span("digest.launch.lengths"):
-                lengths_d = jnp.asarray(lengths)
-            with span("digest.launch.program"):
-                hh, hl = words_fn(words_d, lengths_d, digest_size)
-            with span("digest.launch.slice"):
-                return hh, hh[:n], hl[:n]
+        program = functools.partial(words_fn, digest_size=digest_size)
     else:
         from jax.sharding import NamedSharding, PartitionSpec
 
         n_dev = mesh.devices.size
         over = {"devices": n_dev}
         by_rows = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
-        words_fn = _sharded_words_program(mesh, use_pallas, donate,
-                                          digest_size)
-
-        def put(words, lengths):
-            # ONE call ships every chip its shard of the rows and of
-            # the lengths
-            return jax.device_put((words, lengths), by_rows)
-
-        def run(words_d, lengths, n):
-            # fetched whole, the items' rows picked in collect(): a
-            # slice of a sharded array is a program per item count;
-            # the lengths went up with the words: the jit call is the
-            # launch's one child here
-            with span("digest.launch.program"):
-                hh, hl = words_fn(words_d, lengths)
-            return hh, hh, hl
+        program = _sharded_words_program(mesh, use_pallas, donate,
+                                         digest_size)
     engine = "pallas" if use_pallas else "xla-scan"
     if _OBS.on:
         _note_engine("blake2b.batch", engine, items=len(payloads))
@@ -867,9 +847,7 @@ def blake2b_batch_begin(
         # digests are dropped in collect()
         per = shard_rows(len(idxs), nb, n_dev)
         Bp = n_dev * per
-        at = None if mesh is None else dealt_rows(len(idxs), per, n_dev)
-        if _OBS.on:
-            _BUCKETS.note(engine, nb, len(idxs), Bp)
+        at = dealt_rows(len(idxs), per, n_dev)  # one chip: the first rows
         # one copy per item into a (Bp, nb*128) byte buffer, shipped as
         # raw <u4 words: the hi/lo split is the program's first step
         with span("digest.pack", items=len(idxs), nblocks=nb, **over):
@@ -877,20 +855,27 @@ def blake2b_batch_begin(
             lengths = stage_payloads([payloads[i] for i in idxs], buf, n_dev)
             words = buf.view("<u4")
         if _OBS.on:
+            # with the payload bytes the kernel is handed (a blob hashed
+            # on the host stream is not among them)
+            _BUCKETS.note(engine, nb, len(idxs), Bp,
+                          int(lengths.sum(dtype=np.int64)))
             _M_H2D.inc(words.nbytes + lengths.nbytes)
         # stage explicitly (device_put returns immediately): the upload
         # streams while earlier batches compress, and — when donation is
         # supported — the staged words are DONATED to the dispatch, so
         # successive batches recycle staging HBM instead of growing the
-        # live set.  The span is the HOST's time inside the call, not
-        # the link's.
+        # live set.  ONE call ships the rows and their lengths (over a
+        # mesh, every chip its shard of both).  The span is the HOST's
+        # time inside the call, not the link's.
         with span("digest.h2d", items=len(idxs), nblocks=nb, **over):
-            words_d, lengths = put(words, lengths)
+            words_d, lengths_d = jax.device_put((words, lengths), by_rows)
         with span("digest.launch", items=len(idxs), nblocks=nb, **over):
-            fence, hh, hl = run(words_d, lengths, len(idxs))
-            # fence ready => the program ran => the transfer out of buf
-            # is over
-            _STAGE_POOL.give(buf, fence)
+            # the jit call is the launch's one child
+            with span("digest.launch.program"):
+                hh, hl = program(words_d, lengths_d)
+            # hh ready => the program ran => the transfer out of buf is
+            # over
+            _STAGE_POOL.give(buf, hh)
             handles.append((idxs, hh, hl, at))
 
     def start_d2h() -> None:
@@ -915,15 +900,15 @@ def blake2b_batch_begin(
         out: list[bytes | None] = [None] * n_items
         for idxs, hh, hl, at in handles:
             if _OBS.on:
-                # two (B, 8) u32 halves fetched per bucket = 64 B/item
+                # the items' two 8 x u32 digest halves = 64 B/item (the
+                # padding rows' come with them, uncounted)
                 _M_D2H.inc(64 * len(idxs))
             # the one place the host legitimately waits for the device
             with span("digest.d2h_wait", items=len(idxs), **over):
                 hh = np.asarray(hh)
                 hl = np.asarray(hl)
             with span("digest.unpack", items=len(idxs), **over):
-                if at is not None:
-                    hh, hl = hh[at], hl[at]
+                hh, hl = hh[at], hl[at]
                 for i, d in zip(idxs, digests_to_bytes(hh, hl, digest_size)):
                     out[i] = d
         return out  # type: ignore[return-value]

@@ -186,7 +186,8 @@ def test_each_declared_row_count_matches_hashlib_and_the_scan(
     assert b2.blake2b_batch(payloads, use_pallas=False) == want
     assert b2._BUCKETS.snapshot() == {
         f"{e}:{nblocks}": {"dispatches": 1, "items": n_items,
-                           "padded_items": rows}
+                           "padded_items": rows,
+                           "payload_bytes": sum(map(len, payloads))}
         for e in ("pallas", "xla-scan")}
 
 
@@ -200,14 +201,17 @@ def test_every_item_count_of_a_narrow_slot_is_one_program(engine, obs_enabled,
     # a digest size no other test asks for: the jit cache is the
     # process's, and a program an earlier test built would count nothing
     size = 28 if engine == "pallas" else 24
+    sent = 0
     for n in (1, 2, 3, 31, 32, 33, 511, 512, 513, 1024):
         payloads = [rng.randbytes(rng.randrange(1025, 2049))
                     for _ in range(n)]
+        sent += sum(map(len, payloads))
         assert b2.blake2b_batch(payloads, size, engine == "pallas") \
             == [host(p, size) for p in payloads]
     assert traces.value == 1
     assert b2._BUCKETS.snapshot() == {f"{engine}:16": {
-        "dispatches": 10, "items": 2662, "padded_items": 10 * 1024}}
+        "dispatches": 10, "items": 2662, "padded_items": 10 * 1024,
+        "payload_bytes": sent}}
 
 
 @pytest.mark.parametrize("nblocks", [1 << k for k in range(17)])

@@ -243,7 +243,8 @@ def test_bucket_table_says_which_kernel_a_bucket_reached(obs_enabled):
     assert blake2b_batch(payloads) == [
         hashlib.blake2b(p, digest_size=32).digest() for p in payloads]
     assert obs_device.BUCKETS.snapshot() == {
-        "xla-scan:1": {"dispatches": 1, "items": 3, "padded_items": 1024}}
+        "xla-scan:1": {"dispatches": 1, "items": 3, "padded_items": 1024,
+                       "payload_bytes": 100 + 90 + 3}}
 
 
 def test_compile_events_reach_the_registry(obs_enabled):

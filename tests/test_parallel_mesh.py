@@ -372,7 +372,8 @@ def test_sharded_engine_keeps_the_spans_and_the_bucket_table(obs_enabled):
     rows = device.BUCKETS.snapshot()
     assert rows == {"xla-scan:2": {
         "dispatches": 1, "items": 5,
-        "padded_items": 4 * blake2b.declared_rows(2)[0]}}
+        "padded_items": 4 * blake2b.declared_rows(2)[0],
+        "payload_bytes": 5 * 200}}
     for name in ("digest.pack", "digest.h2d", "digest.launch",
                  "digest.d2h_wait", "digest.unpack"):
         (rec,) = tracing.SPANS.spans(name)

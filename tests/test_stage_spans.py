@@ -385,6 +385,7 @@ def _all_names(fn) -> set:
 SITES = {
     blake2b_mod.blake2b_batch_begin: set(),
     DigestPipeline.dispatch: set(),
+    tpu_backend.TpuDecoder._note_blob_bytes: set(),
     DigestPipeline._deliver_oldest: {"_monotonic", "_H_RESIDENCE"},
     pump_mod.recv_pump: {"_perf", "_H_NATIVE"},
     pump_mod._recv_spanned: set(),
@@ -417,6 +418,11 @@ def _gated_on_obs(fn, target: str) -> bool:
 def test_read_ahead_counters_sit_behind_the_gate():
     for lit_only in ("_M_RA_READY", "_M_RA_SLABS"):
         assert _gated_on_obs(pump_mod._ReadAhead.next, lit_only)
+
+
+def test_bucket_note_sits_behind_the_gate():
+    # the payload bytes a bucket hands the kernel are summed lit only
+    assert _gated_on_obs(blake2b_mod.blake2b_batch_begin, "_BUCKETS")
 
 
 def test_pipeline_clock_reads_sit_behind_the_gate():
@@ -487,15 +493,14 @@ def test_edge_dark_twin_has_no_stage_site():
 
 # -- (f) ISSUE 37: digest.launch's children and the second clock --------------
 
-LAUNCH_CHILDREN = ("digest.launch.lengths", "digest.launch.program",
-                   "digest.launch.slice")
+LAUNCH_CHILDREN = ("digest.launch.program",)
 
 
 def test_launch_children_nest_under_digest_launch(obs_enabled, monkeypatch):
-    """One chip: the second transfer, the jit call and the two slices
-    are each a child of ``digest.launch``, carry the batch's ordinal,
-    and their wall seconds fit inside the parent's (what is left is the
-    parent's self time: the pool's ``give`` and the bookkeeping)."""
+    """One chip: the jit call is a child of ``digest.launch``, carries
+    the batch's ordinal, and its wall seconds fit inside the parent's
+    (what is left is the parent's self time: the pool's ``give`` and
+    the bookkeeping)."""
     monkeypatch.setenv("DAT_DEVICE_HASH", "1")
     monkeypatch.setattr(trace_mod, "_span_hists", {})  # first visits
     pipe = DigestPipeline(max_batch=4)
@@ -516,21 +521,22 @@ def test_launch_children_nest_under_digest_launch(obs_enabled, monkeypatch):
         assert _hist(f"span.{n}.seconds")["count"] == 1
         assert _hist(f"span.{n}.cpu_seconds")["count"] == 1
     assert sum(r[0]["dur"] for r in kids.values()) <= launch["dur"]
-    order = [kids[n][0]["ts"] for n in LAUNCH_CHILDREN]
-    assert order == sorted(order)
 
 
-def test_the_mesh_arm_launches_with_the_program_alone(obs_enabled,
-                                                      monkeypatch):
-    """Over a mesh the lengths ride the one ``device_put`` and the
-    digests are cut on the host: ``.program`` is the launch's one
-    child, and ``.lengths`` / ``.slice`` observe nothing."""
+@pytest.mark.parametrize("devices", [None, 4])
+def test_each_arm_launches_with_the_program_alone(devices, obs_enabled,
+                                                  monkeypatch):
+    """On one chip as over a mesh the lengths ride the one
+    ``device_put`` and the digests are cut on the host: ``.program`` is
+    the launch's one child, and the old ``.lengths`` / ``.slice``
+    children observe nothing."""
     from dat_replication_protocol_tpu.parallel import mesh as pmesh
 
     monkeypatch.setattr(trace_mod, "_span_hists", {})  # first visits
     payloads = [b"mesh-%d" % i for i in range(6)]
+    mesh = None if devices is None else pmesh.make_mesh(devices)
     assert blake2b_mod.blake2b_batch_begin(
-        payloads, mesh=pmesh.make_mesh(4))() == _host_batch(payloads)
+        payloads, use_pallas=False, mesh=mesh)() == _host_batch(payloads)
     launch, = SPANS.spans("digest.launch")
     program, = SPANS.spans("digest.launch.program")
     assert program["parent"] == launch["id"]
