@@ -102,7 +102,7 @@ BATCH_ITEMS = 1024
 HUB_PARKED_BUDGET = 1 << 30
 
 PALLAS_KERNELS = (
-    "blake2b_native", "merkle_level_native", "gear_candidates_native",
+    "blake2b_compress", "merkle_level_native", "gear_candidates_native",
     "gear_first_native", "gear_window_first_native", "fused_cdc_hash",
 )
 
@@ -724,9 +724,8 @@ def _mosaic_kernels() -> dict:
     u32 = jnp.uint32
     words = jnp.zeros((2, rabin.GROUP // 4, 8, 1024), u32)
     lowered = {
-        "blake2b_native": blake2b_pallas.blake2b_native.lower(
-            jnp.zeros((1, 16, 8, 128), u32), jnp.zeros((1, 16, 8, 128), u32),
-            jnp.zeros((8, 128), u32)),
+        "blake2b_compress": blake2b_pallas.blake2b_words_pallas.lower(
+            jnp.zeros((32, 32), u32), jnp.zeros((32,), u32)),
         "merkle_level_native": merkle_pallas.merkle_level_native.lower(
             jnp.zeros((8, 8, 128), u32), jnp.zeros((8, 8, 128), u32)),
         "gear_candidates_native": rabin_pallas.gear_candidates_native.lower(

@@ -303,10 +303,11 @@ def split_words(words):
     the item's zero-padded bytes viewed ``<u4``) -> ``(mh, ml)``, each
     ``(B, nblocks, 16)``: u32 index 2k is 64-bit word k's low half,
     2k+1 its high half.  Traced inside the one program per bucket, so
-    the de-interleave is device time, not a host pass.  Two stride-2
+    the de-interleave is device time, not a host pass — the scan's
+    first step (the Pallas kernel reads the rows unsplit).  Two stride-2
     slices of the 2-D rows, not ``reshape(B, nb, 16, 2)[..., h]``: by
     the v5e compiler's own cycle estimates the minor-dim-of-2 form costs
-    three times the copy work ahead of the kernel (PERF.md §6, PR 27)."""
+    three times the copy work (PERF.md §6)."""
     B = words.shape[0]
     return (words[:, 1::2].reshape(B, -1, 16),
             words[:, 0::2].reshape(B, -1, 16))
@@ -578,8 +579,9 @@ def stage_payloads(payloads, buf: np.ndarray, shards: int = 1) -> np.ndarray:
 
     THE routine that puts payload bytes into staging rows:
     :func:`blake2b_batch_begin` ships ``buf`` viewed ``<u4`` as it is
-    and the device splits the words (:func:`split_words`);
-    :func:`pack_payloads` splits the same rows in numpy.
+    and the kernel reads the rows where they lie (the scan splits them
+    first, :func:`split_words`); :func:`pack_payloads` splits the same
+    rows in numpy.
     """
     rows, width = buf.shape
     n_items = len(payloads)
@@ -787,8 +789,9 @@ def blake2b_batch_begin(
     A bucket is staged as ONE array of raw little-endian u32 message
     words (:func:`stage_payloads`: one copy per item, no host-side
     de-interleave) and ONE ``device_put`` of the words and their
-    lengths; the hi/lo word split happens on the device, inside the
-    bucket's one program (:func:`split_words`), whose jit call is the
+    lengths; the bucket's one program reads the rows as they were
+    shipped — the Pallas kernel turns them onto lanes in VMEM, the scan
+    splits them first (:func:`split_words`) — and its jit call is the
     launch's only jax call.  Staging buffers come from a small
     process-wide pool (:class:`_StagePool`).  A bucket's digests come
     back whole and the items' rows are picked on the host, so no
@@ -849,7 +852,7 @@ def blake2b_batch_begin(
         Bp = n_dev * per
         at = dealt_rows(len(idxs), per, n_dev)  # one chip: the first rows
         # one copy per item into a (Bp, nb*128) byte buffer, shipped as
-        # raw <u4 words: the hi/lo split is the program's first step
+        # raw <u4 words, which the program reads as they lie
         with span("digest.pack", items=len(idxs), nblocks=nb, **over):
             buf = _STAGE_POOL.take((Bp, nb * BLOCK_BYTES))
             lengths = stage_payloads([payloads[i] for i in idxs], buf, n_dev)

@@ -10,19 +10,29 @@ message bytes themselves.
 
 Layout (TPU-first):
 
-* Mosaic tiles are (8, 128) for uint32, so the batch axis is reshaped to
-  ``(8, B/8)`` — every 64-bit lane-pair op covers full vector registers.
-* Messages arrive pre-packed as ``(nblocks, 16, 8, B/8)`` hi/lo uint32
-  (word-major), so each of the 16 message words is one contiguous
-  ``(8, BTL)`` tile slice: zero strided reads in the hot loop.
-* Grid = (batch_tiles, nblocks): batch tiles are embarrassingly parallel;
-  the block axis is sequential ("arbitrary") with the chaining state in
-  VMEM scratch, initialized at block 0 and emitted at the last block.
-* A batch under one 1,024-item tile is ONE tile in a packed layout,
-  ``(nblocks, 16*S, L)`` with ``S x L`` items (``L`` = 128 lanes, or the
-  whole batch under 128): the word axis shares the sublane axis, so HBM
-  holds no padded sublanes and a few wide rows stay a few wide rows
-  (:func:`tile_items`, :func:`to_native`).
+* The kernel reads the rows as :func:`.blake2b.stage_payloads` laid them
+  and ``device_put`` shipped them: ``(rows, nblocks*32)`` little-endian
+  u32, a row one item's zero-padded bytes, u32 ``2k`` the low half of
+  message word ``k`` and ``2k+1`` its high half.  No copy of the staged
+  words is made in HBM.
+* A tile is 1,024 rows once the batch fills one, else the batch itself
+  (a power of two, at least ``MIN_TILE_ITEMS``), so a few wide rows stay
+  a few wide rows.  Its items sit on ``S`` sublanes x ``L`` lanes
+  (``L`` = 128, or the whole tile under 128): every 64-bit lane-pair op
+  of the rounds covers the tile in ``S``-sublane vector registers
+  (:func:`tile_shape`).
+* Grid = (tiles, steps): tiles are embarrassingly parallel; each step's
+  block fetch is a ``(tile, 32*blocks)`` window of raw rows — the whole
+  row where a slot is four blocks or narrower, else four or more blocks
+  side by side on the lanes — and the step axis is sequential
+  ("arbitrary"), the chaining state in VMEM scratch, initialized at the
+  first step and emitted at the last.
+* In VMEM, each ``L``-row chunk of the window is transposed (XLU) and
+  stored with a sublane stride of ``S``, so u32 column ``c`` lies in rows
+  ``c*S .. c*S+S`` of a scratch: one message word's half is then one
+  ``(S, L)`` item tile at a row the word's index gives — the hi/lo
+  de-interleave is a choice of row, not a pass over the bytes.  The
+  step then compresses its blocks in order.
 * Per-item variable lengths use the same active/final masks as the scan
   version (:func:`.blake2b.blake2b_packed`) — no dynamic shapes.
 
@@ -35,6 +45,7 @@ this kernel serves BASELINE.json's ">= 50 GiB/s batched BLAKE2b" target.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -45,34 +56,50 @@ from jax.experimental.pallas import tpu as pltpu
 from .blake2b import (
     _IV_HI,
     _IV_LO,
+    _ROUND_SIGMA,
     DIGEST_SIZE,
     MIN_TILE_ITEMS,
     compress_soa,
-    split_words,
 )
 from ..obs.device import jit_site as _jit_site
 from ..utils.num import next_pow2
 from .u64 import U32
 
-# batch items per kernel tile: 8 sublanes x BTL lanes
 _LANE = 128
-_SUBLANE = 8
+_TILE_ROWS = 1024  # a full tile: 8 sublanes x 128 lanes of items
+_WORDS = 32  # u32 words a 128-byte block
+# the raw words one grid step fetches, at most: a wide slot's window grows
+# past four blocks while it stays under this, so a tile of a few rows
+# walks a wide slot in fewer, larger steps
+_WINDOW_BYTES = 512 << 10
 
 
-def tile_items(B: int, block_items: int = 1024) -> int:
-    """Rows one kernel tile holds for a batch of ``B``: ``block_items``
-    once the batch fills a tile, else the batch itself (a power of two,
-    at least ``MIN_TILE_ITEMS``) as ONE packed tile — what keeps a few
-    wide rows from being padded to 1,024 of them."""
-    if B >= block_items:
-        return block_items
-    return max(MIN_TILE_ITEMS, next_pow2(B))
+class Tile(NamedTuple):
+    """How the kernel walks a bucket: ``rows`` items a tile on
+    ``sublanes`` x ``lanes``, ``blocks`` message blocks a grid step."""
+    rows: int
+    sublanes: int
+    lanes: int
+    blocks: int
 
 
-def _packed_shape(items: int) -> tuple[int, int]:
-    """(sublanes S, lanes L) of a packed single tile of ``items``."""
-    lanes = min(_LANE, items)
-    return items // lanes, lanes
+def tile_shape(rows: int, nblocks: int) -> Tile:
+    """The tile and step of a ``(rows, nblocks*32)`` batch: a full tile
+    once the batch fills one, else the batch itself as ONE tile (a power
+    of two, at least ``MIN_TILE_ITEMS``); a step the whole row where a
+    slot is at most four blocks wide, else four blocks doubled while they
+    divide the slot and the window stays within ``_WINDOW_BYTES``."""
+    t = _TILE_ROWS if rows >= _TILE_ROWS else max(MIN_TILE_ITEMS,
+                                                  next_pow2(rows))
+    lanes = min(_LANE, t)
+    if nblocks <= 4:
+        blocks = nblocks
+    else:
+        blocks = 4
+        while (nblocks % (2 * blocks) == 0
+               and t * 2 * blocks * _WORDS * 4 <= _WINDOW_BYTES):
+            blocks *= 2
+    return Tile(t, t // lanes, lanes, blocks)
 
 
 class _RefWords:
@@ -86,40 +113,44 @@ class _RefWords:
     loads where the schedule consumes them leaves liveness decisions to
     Mosaic, which can rematerialize a cheap VMEM load instead of
     spilling a hot value.
+
+    Word ``w`` of the step's block ``b`` is u32 columns ``32b + 2w``
+    (low half) and ``32b + 2w + 1`` (high half): ``S`` rows each of the
+    transposed scratch.  A column's first row is traced once a block, not
+    once a use: 32 row numbers, where the rounds load 768 times.
     """
 
-    def __init__(self, mh_ref, ml_ref, packed: int):
-        self._mh = mh_ref
-        self._ml = ml_ref
-        self._packed = packed
+    def __init__(self, msg_ref, b, sublanes: int):
+        self._msg = msg_ref
+        self._s = sublanes
+        self._base = jax.lax.mul(b, np.int32(_WORDS * sublanes))
+        self._rows: dict = {}
 
     def __getitem__(self, w):
-        return _word(self._mh, self._ml, int(w), self._packed)
+        return self._half(2 * int(w) + 1), self._half(2 * int(w))
+
+    def _half(self, c: int):
+        row = self._rows.get(c)
+        if row is None:
+            row = self._rows[c] = jax.lax.add(self._base,
+                                              np.int32(c * self._s))
+        return self._msg[pl.ds(row, self._s), :]
 
 
-def _word(mh_ref, ml_ref, w: int, packed: int):
-    """Message word ``w`` of the step's block as a (hi, lo) pair of item
-    tiles: ``ref[0, w]`` of a ``(1, 16, 8, BTL)`` block, or — in a
-    packed single tile of ``packed`` sublanes — rows ``w*S .. w*S+S`` of
-    a ``(1, 16*S, L)`` block."""
-    if not packed:
-        return mh_ref[0, w], ml_ref[0, w]
-    rows = pl.ds(w * packed, packed)
-    return mh_ref[0, rows, :], ml_ref[0, rows, :]
-
-
-def _kernel(*refs, digest_size: int, unroll: bool = True, packed: int = 0):
+def _kernel(*refs, digest_size: int, unroll: bool, sublanes: int,
+            blocks: int):
     if unroll:
-        len_ref, mh_ref, ml_ref, outh_ref, outl_ref, sth_ref, stl_ref = refs
+        len_ref, x_ref, outh_ref, outl_ref, sth_ref, stl_ref, msg_ref = refs
         sigma = None
     else:
         # scanned-rounds variant (interpreter): the schedule table rides in
         # as an input ref — pallas kernels may not capture array constants
-        (len_ref, mh_ref, ml_ref, sig_ref,
-         outh_ref, outl_ref, sth_ref, stl_ref) = refs
+        (len_ref, x_ref, sig_ref,
+         outh_ref, outl_ref, sth_ref, stl_ref, msg_ref) = refs
         sigma = sig_ref[:]
     j = pl.program_id(1)
-    nb = pl.num_programs(1)
+    lanes = len_ref.shape[1]
+    width = _WORDS * blocks
 
     @pl.when(j == 0)
     def _init():
@@ -130,102 +161,85 @@ def _kernel(*refs, digest_size: int, unroll: bool = True, packed: int = 0):
             lo = _IV_LO[w] ^ param_lo if w == 0 else _IV_LO[w]
             stl_ref[w] = jnp.full(shape, lo, U32)
 
+    # the window's items onto lanes: chunk a (rows a*L .. a*L+L) becomes
+    # sublane a of every column's (S, L) tile
+    for a in range(sublanes):
+        cols = x_ref[pl.ds(a * lanes, lanes), :].T
+        if sublanes == 1:
+            msg_ref[...] = cols
+        else:
+            msg_ref[pl.ds(a, width, stride=sublanes), :] = cols
+
     lengths = len_ref[:]
     # where-based max/min: Mosaic has no arith.maxui/minui legalization
     nb_ceil = (lengths + U32(127)) >> U32(7)
     item_blocks = jnp.where(nb_ceil == U32(0), U32(1), nb_ceil)
 
-    ju = j.astype(U32)
-    active = ju < item_blocks
-    final = ju == item_blocks - U32(1)
-    cap = (ju + U32(1)) << U32(7)
-    t_lo = jnp.where(cap < lengths, cap, lengths)
+    def compress_block(b, carry):
+        k = (j * blocks + b).astype(U32)
+        active = k < item_blocks
+        final = k == item_blocks - U32(1)
+        cap = (k + U32(1)) << U32(7)
+        t_lo = jnp.where(cap < lengths, cap, lengths)
+        m = _RefWords(msg_ref, b, sublanes)
+        if not unroll:
+            m = [m[w] for w in range(16)]
+        h = [(sth_ref[w], stl_ref[w]) for w in range(8)]
+        nh = compress_soa(h, m, t_lo, final, unroll=unroll, sigma=sigma)
+        for w in range(8):
+            sth_ref[w] = jnp.where(active, nh[w][0], h[w][0])
+            stl_ref[w] = jnp.where(active, nh[w][1], h[w][1])
+        return carry
 
-    if unroll:
-        m = _RefWords(mh_ref, ml_ref, packed)
-    else:
-        m = [_word(mh_ref, ml_ref, w, packed) for w in range(16)]
-    h = [(sth_ref[w], stl_ref[w]) for w in range(8)]
-    nh = compress_soa(h, m, t_lo, final, unroll=unroll, sigma=sigma)
-    for w in range(8):
-        sth_ref[w] = jnp.where(active, nh[w][0], h[w][0])
-        stl_ref[w] = jnp.where(active, nh[w][1], h[w][1])
+    jax.lax.fori_loop(0, blocks, compress_block, 0)
 
-    @pl.when(j == nb - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
         for w in range(8):
             outh_ref[w] = sth_ref[w]
             outl_ref[w] = stl_ref[w]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("digest_size", "block_items", "interpret"),
-)
-def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
-                   block_items: int = 1024, interpret: bool = False):
-    """Hash in the kernel-native layout.
-
-    ``mh``/``ml``: (nblocks, 16, 8, B/8) uint32 message word halves;
-    ``lengths``: (8, B/8) uint32.  ``B`` must be a multiple of
-    ``block_items`` (and ``block_items`` of 8*128).  Returns digest words
-    as ``(hh, hl)``, each (8, 8, B/8): word-major, batch split like the
-    input.
-
-    A batch under one tile arrives packed (:func:`to_native`):
-    ``mh``/``ml`` (nblocks, 16*S, L), ``lengths`` (S, L), and the digest
-    words come back (8, S, L); ``block_items`` is not consulted.
-    """
-    packed = lengths.shape[0] if mh.ndim == 3 else 0
-    if packed:
-        # ONE tile: (S, L) items, the word axis folded into the sublanes
-        nb = mh.shape[0]
-        tile = lengths.shape
-        if mh.shape != (nb, 16 * tile[0], tile[1]):
-            raise ValueError(
-                f"packed words {mh.shape} do not hold lengths {tile}")
-        n_tiles = 1
-    else:
-        nb, _, s, bl = mh.shape
-        if s != _SUBLANE:
-            raise ValueError(
-                f"batch must be split (8, B/8); got sublane {s}")
-        if block_items % (_SUBLANE * _LANE):
-            raise ValueError(
-                f"block_items must be a multiple of {_SUBLANE * _LANE}")
-        btl = block_items // _SUBLANE
-        if bl % btl:
-            raise ValueError(f"B/8={bl} not a multiple of tile width {btl}")
-        tile = (_SUBLANE, btl)
-        n_tiles = bl // btl
-    grid = (n_tiles, nb)
+def _compress_rows(words, lengths, digest_size: int, interpret: bool):
+    """The kernel over ``words`` ``(rows, nblocks*32)`` u32, ``rows`` a
+    whole number of :func:`tile_shape` tiles and a slot four blocks wide
+    or less or a multiple of four; ``lengths`` ``(rows,)``.  Returns the
+    digest words ``(hh, hl)``, each ``(rows, 8)``."""
+    rows, width = words.shape
+    nb = width // _WORDS
+    tile = tile_shape(rows, nb)
+    if rows % tile.rows or (nb > 4 and nb % 4):
+        raise ValueError(f"words {words.shape} are not whole tiles {tile}")
+    if lengths.shape != (rows,):
+        raise ValueError(
+            f"words {words.shape} do not hold lengths {lengths.shape}")
+    n_tiles = rows // tile.rows
+    item_tile = (tile.sublanes, tile.lanes)
+    step = _WORDS * tile.blocks
     # Mosaic gets the straight-line unrolled rounds; the interpreter (CPU
     # tests) gets the scanned rounds, whose 12x-smaller graph sidesteps
     # the CPU backend's pathological compile of the unrolled chain
     unroll = not interpret
     kernel = functools.partial(
-        _kernel, digest_size=digest_size, unroll=unroll, packed=packed,
+        _kernel, digest_size=digest_size, unroll=unroll,
+        sublanes=tile.sublanes, blocks=tile.blocks,
     )
-    if packed:
-        msg_spec = pl.BlockSpec((1,) + mh.shape[1:], lambda i, j: (j, 0, 0))
-    else:
-        msg_spec = pl.BlockSpec((1, 16) + tile, lambda i, j: (j, 0, 0, i))
-    in_specs = [pl.BlockSpec(tile, lambda i, j: (0, i)), msg_spec, msg_spec]
-    inputs = [lengths, mh, ml]
+    in_specs = [pl.BlockSpec(item_tile, lambda i, j: (i, 0)),
+                pl.BlockSpec((tile.rows, step), lambda i, j: (i, j))]
+    inputs = [lengths.reshape(n_tiles * tile.sublanes, tile.lanes), words]
     if not unroll:
-        from .blake2b import _ROUND_SIGMA
-
         in_specs.append(pl.BlockSpec((12, 16), lambda i, j: (0, 0)))
         inputs.append(jnp.asarray(np.stack(_ROUND_SIGMA)))
-    out_shape = jax.ShapeDtypeStruct((8, tile[0], tile[1] * n_tiles),
-                                     jnp.uint32)
+    out_shape = jax.ShapeDtypeStruct(
+        (8, n_tiles * tile.sublanes, tile.lanes), jnp.uint32)
     outh, outl = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_tiles, nb // tile.blocks),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((8,) + tile, lambda i, j: (0, 0, i))] * 2,
+        out_specs=[pl.BlockSpec((8,) + item_tile, lambda i, j: (0, i, 0))] * 2,
         out_shape=[out_shape, out_shape],
-        scratch_shapes=[pltpu.VMEM((8,) + tile, jnp.uint32)] * 2,
+        scratch_shapes=[pltpu.VMEM((8,) + item_tile, jnp.uint32)] * 2
+        + [pltpu.VMEM((step * tile.sublanes, tile.lanes), jnp.uint32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -233,104 +247,69 @@ def blake2b_native(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
         # the name the device trace shows this kernel under
         name="blake2b_compress",
     )(*inputs)
-    return outh, outl
+    # item r of tile i sits at (i*S + r // L, r % L): row-major, as staged
+    return outh.reshape(8, rows).T, outl.reshape(8, rows).T
 
 
-# recompile sentinel: the kernel specializes per (nblocks, B) tile shape
-blake2b_native = _jit_site("ops.blake2b_pallas.native", blake2b_native)
-
-
-def to_native(mh, ml, lengths, block_items: int = 1024):
-    """(B, nblocks, 16) padded-batch layout -> kernel-native layout.
-
-    Pads the batch up to whole tiles of :func:`tile_items` rows (zero
-    payloads are valid BLAKE2b inputs; the wrapper drops their digests).
-    Returns (mh_n, ml_n, lengths_n, B): (nblocks, 16, 8, Bp/8) words and
-    (8, Bp/8) lengths for whole 1,024-item tiles, the packed
-    (nblocks, 16*S, L) and (S, L) for a batch under one.
-
-    ``mh``/``ml`` arrive already split: by the host
-    (:func:`.blake2b.pack_payloads`) for :func:`blake2b_packed_pallas`'s
-    callers, or by :func:`.blake2b.split_words` earlier in the same
-    program for :func:`blake2b_words_pallas`, where the split and these
-    transposes are the program's layout copies ahead of the kernel.
-    """
-    B, nb, _ = mh.shape
-    tile = tile_items(B, block_items)
-    Bp = -(-B // tile) * tile
-    if Bp != B:
-        mh = jnp.pad(mh, ((0, Bp - B), (0, 0), (0, 0)))
-        ml = jnp.pad(ml, ((0, Bp - B), (0, 0), (0, 0)))
-        lengths = jnp.pad(lengths, (0, Bp - B))
-    if tile < block_items:
-        # ONE packed tile: item i sits at (i // L, i % L), and a word's S
-        # sublanes follow the word before it
-        s, lanes = _packed_shape(tile)
-        shape, len_shape = (nb, 16 * s, lanes), (s, lanes)
-    else:
-        shape = (nb, 16, _SUBLANE, Bp // _SUBLANE)
-        len_shape = shape[2:]
-    mh_n = jnp.transpose(mh, (1, 2, 0)).reshape(shape)
-    ml_n = jnp.transpose(ml, (1, 2, 0)).reshape(shape)
-    return mh_n, ml_n, lengths.reshape(len_shape), B
-
-
-def from_native(outh, outl, B: int):
-    """Kernel-native digest words -> (B, 8) hi/lo (the scan-path layout)."""
-    Bp = outh.shape[1] * outh.shape[2]
-    hh = outh.reshape(8, Bp).T[:B]
-    hl = outl.reshape(8, Bp).T[:B]
-    return hh, hl
-
-
-def blake2b_packed_pallas(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
-                          block_items: int = 1024, interpret: bool = False):
-    """Drop-in for :func:`.blake2b.blake2b_packed`, Pallas-accelerated.
-
-    Same (B, nblocks, 16) interface and (B, 8) hi/lo digest outputs.
-    """
-    mh_n, ml_n, len_n, B = to_native(mh, ml, lengths, block_items)
-    outh, outl = blake2b_native(
-        mh_n, ml_n, len_n, digest_size, block_items, interpret
-    )
-    return from_native(outh, outl, B)
-
-
-# donated twin (see blake2b.blake2b_packed_donated): one jit over the
-# whole layout-transpose + kernel chain so the staged (B, nblocks, 16)
-# message buffers are donated into the program and their HBM recycles
-# into the next batch's staging — the double-buffered upload discipline
-blake2b_packed_pallas_donated = functools.partial(
-    jax.jit,
-    static_argnames=("digest_size", "block_items", "interpret"),
-    donate_argnums=(0, 1),
-)(blake2b_packed_pallas)
-blake2b_packed_pallas_donated = _jit_site(
-    "ops.blake2b_pallas.packed_donated", blake2b_packed_pallas_donated
-)
+def _hash_rows(words, lengths, digest_size: int, interpret: bool):
+    B, width = words.shape
+    nb = width // _WORDS
+    per = tile_shape(B, nb).rows
+    rows = -(-B // per) * per
+    cols = width if nb <= 4 or nb % 4 == 0 else -(-nb // 4) * 4 * _WORDS
+    if (rows, cols) != (B, width):
+        # zero rows are empty payloads, zero blocks past a length inactive
+        words = jnp.pad(words, ((0, rows - B), (0, cols - width)))
+        lengths = jnp.pad(lengths, (0, rows - B))
+    hh, hl = _compress_rows(words, lengths.astype(U32), digest_size,
+                            interpret)
+    return hh[:B], hl[:B]
 
 
 def blake2b_words_pallas(words, lengths, digest_size: int = DIGEST_SIZE,
-                         block_items: int = 1024, interpret: bool = False):
-    """:func:`blake2b_packed_pallas` over raw staged words: ``words`` is
-    ``(B, nblocks*32)`` uint32 as :func:`.blake2b.stage_payloads` lays
-    them, split hi/lo here — split, transposes and kernel are one
-    ``jit_blake2b_words_pallas`` program per ``(B, nblocks)`` bucket."""
-    mh, ml = split_words(words)
-    return blake2b_packed_pallas(mh, ml, lengths, digest_size, block_items,
-                                 interpret)
+                         interpret: bool = False):
+    """BLAKE2b of raw staged rows: ``words`` is ``(B, nblocks*32)``
+    uint32 as :func:`.blake2b.stage_payloads` lays them, ``lengths``
+    ``(B,)``; returns ``(hh, hl)``, each ``(B, 8)``.  The kernel reads
+    the rows where they lie — one ``jit_blake2b_words_pallas`` program a
+    ``(B, nblocks)`` bucket, with no layout copy ahead of the kernel.
+    Only a batch that is not whole tiles (no declared row count), or a
+    slot wider than four blocks and not a multiple of four, is padded
+    first."""
+    return _hash_rows(words, lengths, digest_size, interpret)
 
 
-_WORDS_STATIC = ("digest_size", "block_items", "interpret")
+def blake2b_packed_pallas(mh, ml, lengths, digest_size: int = DIGEST_SIZE,
+                          interpret: bool = False):
+    """Drop-in for :func:`.blake2b.blake2b_packed`, Pallas-accelerated.
+
+    Same (B, nblocks, 16) hi/lo interface and (B, 8) hi/lo digest
+    outputs: the halves are interleaved back into raw rows on the device,
+    then hashed by the one kernel as :func:`blake2b_words_pallas` does.
+    """
+    words = jnp.stack([ml, mh], axis=-1).reshape(mh.shape[0], -1)
+    return _hash_rows(words, lengths, digest_size, interpret)
+
+
+_STATIC = ("digest_size", "interpret")
+# donated twin (see blake2b.blake2b_packed_donated): one jit over the
+# interleave and the kernel so that the staged (B, nblocks, 16) message
+# buffers are donated into the program and their HBM recycles into the
+# next batch's staging — the double-buffered upload discipline
+blake2b_packed_pallas_donated = _jit_site(
+    "ops.blake2b_pallas.packed_donated",
+    functools.partial(jax.jit, static_argnames=_STATIC,
+                      donate_argnums=(0, 1))(blake2b_packed_pallas),
+)
 # the donated twin first: it wraps the function, and the name is about
 # to be rebound to the plain twin's jit site
 blake2b_words_pallas_donated = _jit_site(
     "ops.blake2b_pallas.words_donated",
-    functools.partial(jax.jit, static_argnames=_WORDS_STATIC,
+    functools.partial(jax.jit, static_argnames=_STATIC,
                       donate_argnums=(0,))(blake2b_words_pallas),
 )
 blake2b_words_pallas = _jit_site(
     "ops.blake2b_pallas.words",
-    functools.partial(jax.jit, static_argnames=_WORDS_STATIC)(
+    functools.partial(jax.jit, static_argnames=_STATIC)(
         blake2b_words_pallas),
 )

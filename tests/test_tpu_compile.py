@@ -75,9 +75,9 @@ def test_each_declared_shape_compiles_for_the_v5e(one_chip, nblocks, rows):
     mem = compiled.memory_analysis()
     staged = rows * nblocks * 128
     assert mem.argument_size_in_bytes < staged + MIB
-    # the split halves and their transposes, and HBM's 128-lane padding
-    # of a tile under 128 rows: never the 32x of an (8, rows/8) tile
-    assert mem.temp_size_in_bytes <= 6 * staged + 4 * MIB
+    # the kernel reads the staged rows where they lie: no copy of them
+    # (no split halves, no transposes) among the program's temporaries
+    assert mem.temp_size_in_bytes <= staged // 8 + 4 * MIB
 
 
 @pytest.mark.parametrize("nblocks, per_chip", [(8192, 32), (8192, 64),
@@ -114,4 +114,4 @@ def test_the_sharded_program_compiles_for_a_four_chip_host(four_chips,
     mem = compiled.memory_analysis()      # bytes on each device
     staged = per_chip * nblocks * 128
     assert mem.argument_size_in_bytes < staged + MIB
-    assert mem.temp_size_in_bytes <= 6 * staged + 4 * MIB
+    assert mem.temp_size_in_bytes <= staged // 8 + 4 * MIB
