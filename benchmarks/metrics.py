@@ -103,3 +103,31 @@ def end_to_end(timings: dict, t0: float, t1: float) -> tuple[dict, dict]:
             if sess.size else None) if w],
     }
     return metrics, notes
+
+
+FANOUT_NOTES = ("subscriber_mib_per_s", "subscriber_delay_p50_ms",
+                "subscriber_delay_p95_ms", "subscriber_delay_samples")
+
+
+def fanout(timings: dict, t0: float, t1: float) -> dict | None:
+    """The fan-out subscribers' figures (printed, no metric yet): their
+    bytes a second, all subscribers together, edge to edge as the rates
+    above; and the delay from the writer's send of a frame's last byte
+    to a subscriber's receipt of it, p50 and p95 over the receipts
+    inside the window.  The writer's sends are its items' (`item_t` less
+    `item_lag`, in item order): one writer, one session, so item k is
+    frame k.  None where no subscriber wrote timings."""
+    if "sub_recv_t" not in timings:
+        return None
+    recv_t = timings["sub_recv_t"]
+    rates = edge_to_edge(recv_t, np.ones(recv_t.size, np.int64),
+                         timings["sub_recv_bytes"], t0, t1)
+    sent = timings["item_t"] - timings["item_lag"]
+    k, got = timings["sub_frame_k"], timings["sub_frame_t"]
+    known = k < sent.size
+    delay = in_window(got[known], got[known] - sent[k[known]], t0, t1) * 1e3
+    return {"subscriber_mib_per_s": None if rates["bytes_per_s"] is None
+            else rates["bytes_per_s"] / (1 << 20),
+            "subscriber_delay_p50_ms": percentile(delay, 50),
+            "subscriber_delay_p95_ms": percentile(delay),
+            "subscriber_delay_samples": int(delay.size)}

@@ -20,8 +20,18 @@ window, and the metrics are the cell's per-layer metrics.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name BENCHMARK.json gives:
 `configs/<name>.json` (the manifest's `file`), `traffic/<name>.json`,
-`generators/<generator>.py`, `layer_metrics/<name>.py`.  There is no
-`if workload == …` here.
+`generators/<generator>.py`, `sessions/<kind>.py` (the traffic file's
+`"session"`, `digest` where it names none), `layer_metrics/<name>.py`.
+There is no `if workload == …` here.
+
+A traffic file may hold `"groups"`, a list in connect order: each group
+gives its `name`, `session` kind, `generator`, `clients`, `processes` and
+its generator's parameters (a group that `"reads"` another takes that
+group's generator and parameters: its reference is that group's wire).
+The groups with a warm-up run it; then each group's clients connect, one
+group after the other's connections are up, and every group gets the
+same t0.  A file without groups is one group whose clients connect at
+t0.  Every group's counts enter `correct`.
 
 `--dry-run` rehearses the control flow on the CPU at the traffic file's
 `dry_run` sizes; its last line says `"dry_run": true` and carries no
@@ -63,6 +73,10 @@ TRACE_STOP_LIMIT_S = 200.0   # printed before the wait; PR 22 died on a silent o
 REDUCE_LIMIT_S = 200.0
 DRAIN_LIMIT_S = 170.0        # the clients give up at 150 s themselves
 SLICE_DELAY_S = 1.0          # into the window, so the slice is steady state
+CONNECT_LIMIT_S = 60.0
+# between one group's connections and the next group's: the sidecar
+# accepts a connection and gives it its role on a thread of its own
+CONNECT_SETTLE_S = 0.25
 
 
 def say(*a) -> None:
@@ -80,14 +94,61 @@ def load_json(path: str, what: str) -> dict:
         return json.load(f)
 
 
+def load_module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str):
     path = need_file(os.path.join(HERE, "layer_metrics", f"{name}.py"),
                      f"reader for the per-layer metric {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, "layer_metric_" + name).read
+
+
+def load_session(kind: str):
+    path = need_file(os.path.join(HERE, "sessions", f"{kind}.py"),
+                     f"session kind {kind!r}")
+    return load_module(path, "session_" + kind)
+
+
+def groups_of(traffic: dict, dry: bool) -> list[dict]:
+    """The traffic file's client groups, in connect order, each as
+    {name, session, generator, params (what its generator reads),
+    clients, processes, warmup, reads, connect_first}."""
+    if "groups" not in traffic:
+        return [{"name": "clients", "session": traffic.get("session",
+                                                           "digest"),
+                 "generator": traffic["generator"], "params": traffic,
+                 "clients": int(traffic["clients"]),
+                 "processes": int(traffic["processes"]),
+                 "warmup": traffic.get("warmup", {}), "reads": None,
+                 "connect_first": False}]
+    groups: list[dict] = []
+    for own in traffic["groups"]:
+        if dry:
+            own = {**own, **own.get("dry_run", {})}
+        reads = own.get("reads")
+        src = next((g for g in groups if g["name"] == reads), None)
+        if reads is not None and src is None:
+            raise BenchFailure(f"group {own['name']!r} reads {reads!r}, "
+                               f"which is no group before it")
+        if src is not None and src["clients"] != 1:
+            raise BenchFailure(
+                f"group {own['name']!r} reads {reads!r}, a group of "
+                f"{src['clients']} clients: the sidecar serves one "
+                f"broadcast, from one client")
+        groups.append({
+            "name": own["name"], "session": own.get("session", "digest"),
+            "generator": src["generator"] if src else own["generator"],
+            "params": src["params"] if src else own,
+            "clients": int(own["clients"]),
+            "processes": int(own["processes"]),
+            "warmup": own.get("warmup", {}), "reads": reads,
+            "connect_first": True})
+    return groups
 
 
 def for_cell(metrics: list, cell: str) -> list:
@@ -116,12 +177,15 @@ def resolve(manifest: dict, cell_name: str, dry: bool) -> dict:
         f"file of traffic mix {cell['traffic']!r}")
     if dry:
         traffic = {**traffic, **traffic.get("dry_run", {})}
-    need_file(os.path.join(HERE, "generators",
-                           f"{traffic['generator']}.py"),
-              f"generator {traffic['generator']!r}")
+    groups = groups_of(traffic, dry)
+    for g in groups:
+        need_file(os.path.join(HERE, "generators", f"{g['generator']}.py"),
+                  f"generator {g['generator']!r}")
+        need_file(os.path.join(HERE, "sessions", f"{g['session']}.py"),
+                  f"session kind {g['session']!r}")
     need_file(os.path.join(ROOT, config["reference"]), "plain reference")
     return {
-        "cell": cell, "config": config, "traffic": traffic,
+        "cell": cell, "config": config, "traffic": traffic, "groups": groups,
         "end_to_end": for_cell(manifest["end_to_end"], cell_name),
         "per_layer": [(m, load_reader(m["name"]))
                       for m in for_cell(manifest["per_layer"], cell_name)],
@@ -170,14 +234,75 @@ def check_device(dev: dict, config: dict, chips: int, dry: bool) -> None:
 
 
 def merge_counts(reports: list[dict], into: dict) -> None:
+    """Sum the counts of client processes' reports (a kind's lists are
+    joined); the first fault is kept."""
     for r in reports:
         if "error" in r:
             raise BenchFailure(f"a client process reported {r['error']}")
-        for k in ("sessions", "attempted", "failed", "compared",
-                  "bad_sessions", "ok_items", "ok_payload_bytes"):
-            into[k] = into.get(k, 0) + r[k]
-        if r["first_fault"] and not into.get("first_fault"):
-            into["first_fault"] = r["first_fault"]
+        for k, v in r.items():
+            if k == "first_fault":
+                if v and not into.get(k):
+                    into[k] = v
+            elif isinstance(v, list):
+                into[k] = into.get(k, []) + v
+            else:
+                into[k] = into.get(k, 0) + v
+
+
+def merge_timings(paths: list[str]) -> dict:
+    """The processes' timing arrays, each key joined over the processes
+    that wrote it (the kinds of a run's groups write different keys)."""
+    parts: dict = {}
+    for p in paths:
+        with np.load(p) as f:
+            for k in f.files:
+                parts.setdefault(k, []).append(f[k])
+    return {k: np.concatenate(v) for k, v in parts.items()}
+
+
+def settle(groups: list, procs: dict, report_of: dict) -> dict:
+    """Each group's counts over the window.  A group that reads another
+    is judged by its kind's `settle` once that group's counts are in."""
+    out: dict = {}
+    for g in groups:
+        merged: dict = {}
+        merge_counts([report_of[c] for c in procs[g["name"]]], merged)
+        if g["reads"]:
+            merged = load_session(g["session"]).settle(merged,
+                                                       out[g["reads"]])
+        out[g["name"]] = merged
+    return out
+
+
+def connect_groups(groups: list, procs: dict, port: int) -> None:
+    """Each group's clients open their connections, in the traffic
+    file's order: a group connects once the one before it is connected
+    and the sidecar has had its connections (so a fan-out writer's claim
+    on the source slot lands before any subscriber connects)."""
+    for k, g in enumerate(g for g in groups if g["connect_first"]):
+        if k:
+            time.sleep(CONNECT_SETTLE_S)
+        up = on_all(procs[g["name"]],
+                    lambda i: {"cmd": "connect", "port": port},
+                    CONNECT_LIMIT_S, f"group {g['name']}'s connections")
+        if any("error" in u for u in up):
+            raise BenchFailure(f"group {g['name']}: {up}")
+        say(f"connected: group {g['name']}, "
+            f"{sum(u['connected'] for u in up)} clients")
+
+
+def open_window(clients: list, port: int, seconds: float,
+                workdir: str) -> tuple:
+    """Every client process runs the window from one t0: (t0, t1, the
+    timing files, the tickets of their reports)."""
+    t0 = time.monotonic() + 0.5
+    t1 = t0 + seconds
+    outs = [os.path.join(workdir, f"timings{i}.npz")
+            for i in range(len(clients))]
+    tickets = [c.send({"cmd": "loop", "port": port, "t0": t0, "t1": t1,
+                       "out": outs[i]})
+               for i, c in enumerate(clients)]
+    return t0, t1, outs, tickets
 
 
 def on_all(clients: list, cmd_for, timeout: float, what: str) -> list:
@@ -217,21 +342,33 @@ def run(args, plan: dict, workdir: str) -> dict:
     env = dict(os.environ)
     if args.dry_run:
         env["JAX_PLATFORMS"] = "cpu"
-    n_proc = int(traffic["processes"])
-    n_clients = int(traffic["clients"])
+    groups = plan["groups"]
+    n_proc = sum(g["processes"] for g in groups)
+    n_clients = sum(g["clients"] for g in groups)
     say(f"cell {cell['name']} seed {args.seed} seconds {args.seconds} "
         f"trace {int(trace)} dry_run {int(args.dry_run)}; host cpus "
-        f"{os.cpu_count()}; {n_clients} clients over {n_proc} processes")
+        f"{os.cpu_count()}; {n_clients} clients over {n_proc} processes"
+        + ("" if len(groups) == 1 else "; groups in connect order "
+           + ", ".join(f"{g['name']} ({g['session']}: {g['clients']} "
+                       f"clients over {g['processes']} processes)"
+                       for g in groups)))
 
     sc = Sidecar(list(config["sidecar_flags"]), telemetry=trace, env=env)
     clients: list[Client] = []
+    procs: dict = {}        # group name -> its client processes
     counts: dict = {}
     try:
-        for i in range(n_proc):
-            clients.append(Client({
-                "generator": traffic["generator"], "traffic": traffic,
-                "seed": args.seed,
-                "clients": list(range(i, n_clients, n_proc))}, env))
+        for g in groups:
+            procs[g["name"]] = []
+            for i in range(g["processes"]):
+                spec = {"session": g["session"], "generator": g["generator"],
+                        "traffic": g["params"], "seed": args.seed,
+                        "clients": list(range(i, g["clients"],
+                                              g["processes"]))}
+                if g["reads"]:
+                    spec["source_client"] = 0
+                procs[g["name"]].append(Client(spec, env))
+            clients += procs[g["name"]]
         dev_line = sc.wait_listening(START_LIMIT_S)
         say(sc.device_line)
         check_device(dev_line, config, int(cell["chips"]), args.dry_run)
@@ -241,19 +378,20 @@ def run(args, plan: dict, workdir: str) -> dict:
 
         # -- warm-up: the cell's own shapes, every session compared ------
         t_warm = time.monotonic()
-        warm = traffic.get("warmup", {})
-        if warm.get("lone_sessions"):
-            t = clients[0].send({"cmd": "sessions", "port": port,
-                                 "counts": warm["lone_sessions"]})
-            merge_counts([clients[0].answer(t, WARMUP_LIMIT_S,
-                                            "the lone warm-up sessions")],
-                         counts)
-        if warm.get("loop_seconds"):
-            w0 = time.monotonic() + 0.2
-            merge_counts(on_all(
-                clients, lambda i: {"cmd": "loop", "port": port, "t0": w0,
-                                    "t1": w0 + warm["loop_seconds"]},
-                WARMUP_LIMIT_S, "the warm-up loop"), counts)
+        for g in groups:
+            warm, mine = g["warmup"], procs[g["name"]]
+            if warm.get("lone_sessions"):
+                t = mine[0].send({"cmd": "sessions", "port": port,
+                                  "counts": warm["lone_sessions"]})
+                merge_counts([mine[0].answer(t, WARMUP_LIMIT_S,
+                                             "the lone warm-up sessions")],
+                             counts)
+            if warm.get("loop_seconds"):
+                w0 = time.monotonic() + 0.2
+                merge_counts(on_all(
+                    mine, lambda i: {"cmd": "loop", "port": port, "t0": w0,
+                                     "t1": w0 + warm["loop_seconds"]},
+                    WARMUP_LIMIT_S, "the warm-up loop"), counts)
         if counts.get("failed"):
             raise BenchFailure(
                 f"warm-up: {counts['failed']} of {counts['attempted']} "
@@ -265,15 +403,13 @@ def run(args, plan: dict, workdir: str) -> dict:
             f"({counts.get('sessions', 0)} sessions, "
             f"{counts.get('compared', 0)} digests compared)")
 
+        # -- groups connect in order, then one t0 for all ----------------
+        connect_groups(groups, procs, port)
+
         # -- the window ---------------------------------------------------
-        t0 = time.monotonic() + 0.5
-        t1 = t0 + args.seconds
+        t0, t1, outs, tickets = open_window(clients, port, args.seconds,
+                                            workdir)
         setup_s = t0 - T_PROCESS
-        outs = [os.path.join(workdir, f"timings{i}.npz")
-                for i in range(n_proc)]
-        tickets = [c.send({"cmd": "loop", "port": port, "t0": t0, "t1": t1,
-                           "out": outs[i]})
-                   for i, c in enumerate(clients)]
         sampler = CpuSampler(
             {"sidecar": sc.proc.pid,
              **{f"client{i}": c.proc.pid for i, c in enumerate(clients)}},
@@ -301,9 +437,10 @@ def run(args, plan: dict, workdir: str) -> dict:
                             "the clients' report of the window")
                    for c, t in zip(clients, tickets)]
         sampler.join(10)
+        by_group = settle(groups, procs, dict(zip(clients, reports)))
         window: dict = {}
-        merge_counts(reports, window)
-        merge_counts(reports, counts)
+        merge_counts(list(by_group.values()), window)
+        merge_counts(list(by_group.values()), counts)
 
         # -- after the window ---------------------------------------------
         device = sc.ask({"cmd": "device"}, 60.0)
@@ -321,11 +458,9 @@ def run(args, plan: dict, workdir: str) -> dict:
             c.stop()
         sc.stop()
 
-    timings = {}
-    parts = [np.load(p) for p in outs]
-    for k in parts[0].files:
-        timings[k] = np.concatenate([p[k] for p in parts])
+    timings = merge_timings(outs)
     e2e, notes = arithmetic.end_to_end(timings, t0, t1)
+    notes.update(arithmetic.fanout(timings, t0, t1) or {})
     e2e["setup_s"] = setup_s
     cores = sampler.cores()
     reduced = None
@@ -336,25 +471,38 @@ def run(args, plan: dict, workdir: str) -> dict:
             "window": window, "e2e": e2e, "notes": notes, "cores": cores,
             "device": device, "device_line": dev_line, "snaps": snaps,
             "trace": reduced, "slice": slice_rep, "timings": timings,
-            "traffic": traffic, "config": config}
+            "traffic": traffic, "config": config, "groups": by_group,
+            "sidecar_tail": sc.tail(len(sc.stderr_tail))}
 
 
 def report(args, plan: dict, res: dict) -> int:
     """The earlier lines, then the JSON line, last."""
     counts, notes, cores = res["counts"], res["notes"], res["cores"]
+    groups = res["groups"]
     client_cores = [v for k, v in cores.items() if k != "sidecar"]
-    say(f"compared {counts['compared']} digests of {counts['sessions']} "
+    # a subscriber's items are the writer's frames it relayed
+    what = "digests" if len(groups) == 1 else "digests and frames"
+    say(f"compared {counts['compared']} {what} of {counts['sessions']} "
         f"sessions (warm-up included) with "
         f"{plan['config']['reference']}: {counts['failed']} of "
         f"{counts['attempted']} items failed, {counts['bad_sessions']} "
         f"sessions broke a guarantee"
         + (f", first: {counts['first_fault']}"
            if counts.get("first_fault") else ""))
+    if counts["bad_sessions"]:
+        # what the sidecar said of its sessions (a shed, a refusal)
+        for ln in res["sidecar_tail"].splitlines():
+            say("sidecar stderr:", ln)
+    if len(groups) > 1:
+        for name, g in groups.items():
+            say(f"group {name}: " + json.dumps(
+                {k: v for k, v in g.items() if k != "peers"}))
     say("window: " + json.dumps(
         {k: notes[k] for k in (
             "deliveries", "items", "first_s", "last_s", "span_s",
             "sessions_in_window",
-            "digest_lag_samples", "digest_lag_p50_ms", "session_p50_ms")}))
+            "digest_lag_samples", "digest_lag_p50_ms", "session_p50_ms",
+            *arithmetic.FANOUT_NOTES) if k in notes}))
     say(f"cpu in the window: sidecar {cores['sidecar']:.3f} cores; "
         f"{len(client_cores)} client processes "
         f"{sum(client_cores):.3f} cores together, the busiest "
@@ -363,15 +511,31 @@ def report(args, plan: dict, res: dict) -> int:
     for w in notes["warnings"]:
         say("warning:", w)
     say("end to end: " + json.dumps(res["e2e"]))
+    checks = {
+        "failed_items": {"value": counts["failed"], "limit": 0,
+                         "holds": "at most"},
+        "compared_items": {"value": counts["compared"],
+                           "limit": counts["attempted"], "holds": "equal"},
+        "attempted_items": {"value": counts["attempted"], "limit": 1,
+                            "holds": "at least"}}
     correct = (counts["failed"] == 0 and counts["attempted"] > 0
                and counts["compared"] == counts["attempted"])
     head = {"correct": correct, "attempted": counts["attempted"],
             "failed": counts["failed"]}
+    for name, c in checks.items():
+        print(f"bench: check {name} {c['value']} ({c['holds']} "
+              f"{c['limit']})", file=sys.stderr, flush=True)
     if args.dry_run:
+        extra = {}
+        if len(groups) > 1:
+            extra["groups"] = {name: {k: v for k, v in g.items()
+                                      if k != "peers"}
+                               for name, g in groups.items()}
         print(json.dumps({**head, "dry_run": True,
                           "workload": plan["cell"]["name"],
                           "sessions": counts["sessions"],
-                          "compared": counts["compared"]}), flush=True)
+                          "compared": counts["compared"], **extra,
+                          "checks": checks}), flush=True)
         return 0 if correct else 1
 
     dev = res["device"]
@@ -413,6 +577,7 @@ def report(args, plan: dict, res: dict) -> int:
         device["window_s"] = tr["window_s"]
         line["breakdown"] = {"device_ops": tr["device_ops"],
                              "idle_gaps": tr["idle_gaps"]}
+    line["checks"] = checks         # the numbers compared, last
     print(json.dumps(line), flush=True)
     return 0
 

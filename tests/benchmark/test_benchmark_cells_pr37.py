@@ -1,18 +1,21 @@
-"""What ISSUE 37 adds to the benchmark: SEVEN per-layer metrics that read
-the program's new readings — `digest.launch` split into child spans
-(three means, in ms a batch) and the second clock, a thread's CPU
-seconds beside a region's wall seconds (four off-CPU shares) — and one
-shared helper, `layer_metrics/_shares.py`.  Each reader on hand-made
+"""Per-layer metrics that read the program's readings of `digest.launch`
+split into child spans and of the second clock, a thread's CPU seconds
+beside a region's wall seconds, and one shared helper,
+`layer_metrics/_shares.py`.  Five readers: the launch's one child (the
+jit call, a mean in ms a batch) and the four off-CPU shares; the
+slice's and the lengths' readers went with the spans they read, which
+the one-chip launch no longer opens.  Each reader on hand-made
 snapshots: a value where both clocks moved (the program takes the CPU
-clock on one visit in a few, so a share compares the two MEANS); nothing
-(the metric is then left out of the line) where the histograms are
-absent, as in the PR's parent; nothing where the wall sum did not move
-or the CPU clock has too few observations; nothing on a dark run.
+clock on one visit in a few, so a share compares the two MEANS);
+nothing (the metric is then left out of the line) where the histograms
+are absent, as in a program older than the spans; nothing where the
+wall sum did not move or the CPU clock has too few observations;
+nothing on a dark run.
 
-The seven entries are in `BENCHMARK.json`, appended to `per_layer`.  The
-manifest case below finds them BY NAME, holds each one's list of cells
-as a prefix and pins no position, so that it holds on a manifest grown
-as a later PR grows it (`test_benchmark_cells_pr40.py`)."""
+The entries are in `BENCHMARK.json`, in `per_layer`.  The manifest case
+below finds them BY NAME, holds each one's list of cells as a prefix and
+pins no position, so that it holds on a manifest grown as a later PR
+grows it (`test_benchmark_cells_pr40.py`)."""
 
 import json
 import os
@@ -32,19 +35,15 @@ CELLS = ["plain.publish", "edgehub.feed", "edgehub.publish",
          "meshhub.publish"]
 
 
-ONE_CHIP = ["plain.publish", "edgehub.feed", "edgehub.publish"]
 EDGE = ["edgehub.feed", "edgehub.publish", "meshhub.publish"]
 # name -> (unit, layer, the cells it lists; None: no list, every cell).
-# A list where a reader finds nothing in some cell by design: the mesh
-# arm has no slice and no lengths transfer, and `plain.publish` has no
-# edge loop and 4-5 clocked batches a window (under
-# `_shares.MIN_CLOCKED`).  The shares read on every edge cell, the
+# A list where a reader finds nothing in some cell by design:
+# `plain.publish` has no edge loop and 4-5 clocked batches a window
+# (under `_shares.MIN_CLOCKED`).  The shares read on every edge cell, the
 # four-chip one among them; the receive's also on `plain.publish`, whose
 # read-ahead helper receives through the same `recv_fetch`
 WANT = {
     "launch_program_ms": ("ms", "kernels", None),
-    "launch_slice_ms": ("ms", "kernels", ONE_CHIP),
-    "launch_lengths_ms": ("ms", "kernels", ONE_CHIP),
     "launch_offcpu_share": ("%", "kernels", EDGE),
     "pack_offcpu_share": ("%", "staging", EDGE),
     "edge_read_offcpu_share": ("%", "host path", EDGE),
@@ -96,23 +95,8 @@ DARK = {"snaps": None}
     ("launch_program_ms",
      _ctx({"span.digest.launch.program.seconds": (0.5, 100)},
           {"span.digest.launch.program.seconds": (5.1, 2400)}), 2.0),
-    ("launch_slice_ms",
-     _ctx({"span.digest.launch.slice.seconds": (1.0, 100)},
-          {"span.digest.launch.slice.seconds": (10.2, 2400)}), 4.0),
-    # the first lit use fell inside the window: from zero
-    ("launch_lengths_ms",
-     _ctx({}, {"span.digest.launch.lengths.seconds": (1.2, 2400)}), 0.5),
-    # registered, no observation in the window (the mesh arm after a
-    # one-chip warm-up): nothing, not a division by zero
-    ("launch_slice_ms",
-     _ctx({"span.digest.launch.slice.seconds": (1.0, 100)},
-          {"span.digest.launch.slice.seconds": (1.0, 100)}), None),
     ("launch_program_ms", PARENT, None),
-    ("launch_slice_ms", PARENT, None),
-    ("launch_lengths_ms", PARENT, None),
     ("launch_program_ms", DARK, None),
-    ("launch_slice_ms", DARK, None),
-    ("launch_lengths_ms", DARK, None),
     # -- the four shares: 100 x (1 - mean cpu / mean wall); every visit
     # clocked, the means compare as the sums do
     ("launch_offcpu_share",
@@ -200,7 +184,7 @@ def test_the_seven_entries_by_name_in_the_manifest():
 
 
 def check_the_seven_entries(m: dict) -> None:
-    """Each of the seven once, found BY NAME, never by position: a pin
+    """Each of the five once, found BY NAME, never by position: a pin
     on the list's order would bar the next PR, as a pin on its last
     entry once barred these.  Each has the contract's form, its list of
     cells begins with the cells it was entered with, it names a layer

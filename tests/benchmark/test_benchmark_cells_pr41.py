@@ -36,10 +36,9 @@ ACCEPTED_CONFIGS = ["plain", "edgehub", "edgehub1g", "meshhub1g"]
 # the lists the cell is appended to: every reader it finds something for
 LISTED = pr33.STAGE_READERS + ["pump_busy", "pump_wait", "rx_offcpu_share",
                                "launch_offcpu_share", "pack_offcpu_share"]
-# one chip's launch children that the one-chip collect no longer opens;
-# and the bandwidth share that counts the host stream's blobs as staged
+# the bandwidth share that counts the host stream's blobs as staged
 # (`kernel_hbm_share` reads the cell instead)
-SILENT = ["launch_slice_ms", "launch_lengths_ms", "blake2b_hbm_share"]
+SILENT = ["blake2b_hbm_share"]
 NEW_METRICS = {
     "stream_busy": {"name": "stream_busy", "unit": "s/s", "better": "lower",
                     "source": "program_counter", "layer": "host path",

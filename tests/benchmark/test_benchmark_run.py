@@ -82,11 +82,12 @@ def test_the_parent_never_imports_jax():
 
 
 # -- the comparison: a wrong, missing, extra or torn reply is a failure --------
+# (the digest session kind, `sessions/digest.py`)
 
 sys.path.insert(0, BENCH)
-import client  # noqa: E402
 from generators import closed_loop  # noqa: E402
 from reference import digests as ref  # noqa: E402
+from sessions import digest  # noqa: E402
 
 sys.path.remove(BENCH)
 
@@ -101,7 +102,7 @@ class _Sock:
 
 
 def _conn(plan):
-    c = client.Conn(plan, _Sock())
+    c = digest.Conn(plan, _Sock())
     c.write_open = False      # the session has sent everything
     return c
 
