@@ -54,10 +54,10 @@ from ..obs.metrics import (
     gauge as _gauge,
     histogram as _histogram,
 )
-from ..obs.tracing import trace_span as _trace_span
 from ..obs.watermarks import WATERMARKS as _WATERMARKS
 from ..obs import wirecost as _wirecost
 from ..session import pump as _pump
+from ..utils.trace import span as _span
 from .log import BroadcastLog, SnapshotNeeded
 
 __all__ = ["FanoutServer", "FanoutPeer", "FanoutBusy", "PeerShed"]
@@ -282,17 +282,19 @@ class FanoutServer:
         per-peer allocation — peers are the dispatcher's business.
         The mark update is O(1) under the server lock (the dispatcher
         indexes the ring by absolute sequence; an unlocked evict would
-        shift its base mid-read)."""
-        self.log.append(data)
-        end = self.log.end
-        now = time.monotonic()
-        with self._lock:
-            if len(self._marks) == self._marks.maxlen:
-                self._mark_base += 1
-            self._marks.append((end, now))
-        if _OBS.on:
-            _WATERMARKS.mark(_WM_LINK, end)
-            self._lit_cost_published(len(data))
+        shift its base mid-read).  The stage span ``fanout.publish``
+        brackets it on the source's thread, once per tapped slab."""
+        with _span("fanout.publish", bytes=len(data)):
+            self.log.append(data)
+            end = self.log.end
+            now = time.monotonic()
+            with self._lock:
+                if len(self._marks) == self._marks.maxlen:
+                    self._mark_base += 1
+                self._marks.append((end, now))
+            if _OBS.on:
+                _WATERMARKS.mark(_WM_LINK, end)
+                self._lit_cost_published(len(data))
 
     # -- wire cost lit helpers (ISSUE 20) ------------------------------------
     # The fan-out choke points fork ONCE on `_OBS.on`; these helpers
@@ -562,7 +564,7 @@ class FanoutServer:
                     turn = self._compose_turn_locked()
                 progressed = 0
                 if turn:
-                    with _trace_span("fanout.dispatch", peers=len(turn)):
+                    with _span("fanout.dispatch", peers=len(turn)):
                         for st, want in turn:
                             progressed += self._serve_peer(st, want)
                     if _OBS.on:
